@@ -13,7 +13,11 @@ TARGET=${2:-5}
 BASE_PORT=${3:-27656}
 DIR=$(mktemp -d /tmp/tmtpu-localnet.XXXXXX)
 PY=${PYTHON:-python}
-export JAX_PLATFORMS=${JAX_PLATFORMS:-cpu}
+# N node processes, at most one chip: a chip belongs to ONE process, so
+# every node here is pinned to the CPU and verifies on the host. (A
+# shared device is the verifyd sidecar's job — one daemon owns the chip.)
+export JAX_PLATFORMS=cpu
+export TMTPU_DISABLE_TPU=1
 
 cleanup() {
   kill "${PIDS[@]}" 2>/dev/null || true

@@ -1220,6 +1220,13 @@ class XLNet:
                 pass
 
     async def _spawn_verifyd(self) -> None:
+        """The shared sidecar. Who owns the chip here: NOBODY. Workers
+        are CPU-pinned with the probe off (_worker_env); the daemon
+        inherits JAX_PLATFORMS=cpu and only drops TMTPU_DISABLE_TPU, so
+        its probe attaches the JAX-CPU backend (active kind "cpu") — the
+        rig exercises the socket protocol and cross-process packing, not
+        a device, and no child wants a chip the supervisor's process
+        might hold."""
         self.verifyd_sock = os.path.join(self.run_dir, "verifyd.sock")
         env = self._worker_env()
         env.pop("TMTPU_DISABLE_TPU", None)
@@ -1297,6 +1304,11 @@ class XLNet:
                     self.reports[msg.worker] = msg
         except (asyncio.IncompleteReadError, ConnectionError, OSError):
             pass
+        finally:
+            # a dead worker's EOF leaves the transport half-open until
+            # the writer is closed, and from Python 3.12
+            # Server.wait_closed() waits for every such transport
+            writer.close()
 
     async def _broadcast(self, msg, *, only: int | None = None) -> None:
         targets = (
@@ -1540,6 +1552,8 @@ class XLNet:
             await self._kill_verifyd()
             if self._server is not None:
                 self._server.close()
+                for w in list(self.conns.values()):
+                    w.close()
                 await self._server.wait_closed()
 
         agg = aggregate_reports(

@@ -178,6 +178,11 @@ def _get_kernel(m: int, npairs: int):
     with _kernel_lock:
         k = _kernel_cache.get(key)
         if k is None:
+            # a pairing bucket compiles for minutes: persist it in the
+            # same placeable cache as the verify kernels
+            from .verify import _ensure_compile_cache
+
+            _ensure_compile_cache()
             k = _kernel_cache[key] = _build_kernel(m, npairs)
         return k
 

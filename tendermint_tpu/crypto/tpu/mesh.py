@@ -1,8 +1,7 @@
 """Per-device health registry for the sharded verification mesh.
 
-MULTICHIP_r01–r05 showed 8 healthy devices that dispatch never touched;
-once dispatch DOES shard over them (crypto/tpu/verify.py), one sick chip
-must not take the whole mesh down. This module keeps one circuit breaker
+Dispatch shards over every visible device (crypto/tpu/verify.py), so one
+sick chip must not take the whole mesh down. This module keeps one circuit breaker
 per device (the libs/retry breaker every other degradation path in the
 repo uses):
 
@@ -93,7 +92,7 @@ def _enumerate() -> list:
 def _probe_device(dev, timeout_s: float | None = None) -> bool:
     """One tiny bounded computation pinned to `dev`. Runs on a daemon
     thread with a join timeout: a wedged chip must cost bounded time,
-    never hang the dispatch path (the rc=124 lesson)."""
+    never hang the dispatch path."""
     if dev.id in _forced_failures:
         return False
     res: dict = {}

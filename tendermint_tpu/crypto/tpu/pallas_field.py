@@ -151,9 +151,14 @@ def _pow22523_kernel(z_ref, o_ref):
     z = z_ref[:]
 
     def sq(x, k=1):
-        for _ in range(k):
-            x = _conv_mod(x, x)
-        return x
+        # long squaring runs are ROLLED: unrolled, the kernel body is 266
+        # convolutions of 32 unrolled steps each and Mosaic spends ~4
+        # minutes compiling it — per enclosing program that embeds it
+        if k < 5:
+            for _ in range(k):
+                x = _conv_mod(x, x)
+            return x
+        return jax.lax.fori_loop(0, k, lambda _, v: _conv_mod(v, v), x)
 
     t0 = sq(z)  # 2
     t1 = sq(t0, 2)  # 8

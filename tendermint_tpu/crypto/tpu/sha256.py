@@ -9,7 +9,7 @@ same batched-hash bet for Poseidon). Merkle work is naturally uniform —
 `0x01||left||right` inner nodes are 65 bytes (2 blocks) and leaf
 messages cluster by size — which is what makes fixed-shape buckets pay.
 
-Shape discipline (the BENCH_r01–r05 lesson, same as tpu/verify): a
+Shape discipline (same as tpu/verify): a
 kernel call is keyed by (block_bucket, batch_bucket) — messages are
 host-padded to a power-of-two block count and the batch to the bucket
 ladder, so the set of XLA compilations is small and rides the
@@ -111,34 +111,44 @@ def _make_kernel(t_bucket: int):
     import jax
     import jax.numpy as jnp
 
-    k_consts = tuple(np.uint32(k) for k in _K)
+    k_consts = jnp.asarray(_K, jnp.uint32)
 
     def rotr(x, n):
         return (x >> np.uint32(n)) | (x << np.uint32(32 - n))
 
     def compress(state, w16):
-        # message schedule, fully unrolled: w[j] has shape (batch,)
-        w = [w16[:, j] for j in range(16)]
-        for j in range(16, 64):
-            s0 = rotr(w[j - 15], 7) ^ rotr(w[j - 15], 18) ^ (w[j - 15] >> np.uint32(3))
-            s1 = rotr(w[j - 2], 17) ^ rotr(w[j - 2], 19) ^ (w[j - 2] >> np.uint32(10))
-            w.append(w[j - 16] + s0 + w[j - 7] + s1)
-        a, b, c, d, e, f, g, h = (state[:, i] for i in range(8))
-        for j in range(64):
+        # The 48 schedule steps and the 64 rounds are ROLLED loops, not
+        # unrolled Python: every round's eight words feed several later
+        # expressions, and as one straight-line graph XLA's fusion
+        # re-derives shared producers per consumer — on the CPU backend
+        # of JAX 0.9.0 the compiled program then runs effectively
+        # forever (the compile itself takes seconds). A loop carry is
+        # materialized once per iteration, which bounds the work.
+        batch = w16.shape[0]
+        w0 = jnp.zeros((64, batch), jnp.uint32).at[:16].set(w16.T)
+
+        def sched(j, w):
+            w15, w2 = w[j - 15], w[j - 2]
+            s0 = rotr(w15, 7) ^ rotr(w15, 18) ^ (w15 >> np.uint32(3))
+            s1 = rotr(w2, 17) ^ rotr(w2, 19) ^ (w2 >> np.uint32(10))
+            return w.at[j].set(w[j - 16] + s0 + w[j - 7] + s1)
+
+        w = jax.lax.fori_loop(16, 64, sched, w0)
+
+        def round_(j, v):
+            a, b, c, d, e, f, g, h = v
             s1 = rotr(e, 6) ^ rotr(e, 11) ^ rotr(e, 25)
             ch = (e & f) ^ (~e & g)
             t1 = h + s1 + ch + k_consts[j] + w[j]
             s0 = rotr(a, 2) ^ rotr(a, 13) ^ rotr(a, 22)
             maj = (a & b) ^ (a & c) ^ (b & c)
             t2 = s0 + maj
-            h, g, f, e, d, c, b, a = g, f, e, d + t1, c, b, a, t1 + t2
-        return jnp.stack(
-            [
-                a + state[:, 0], b + state[:, 1], c + state[:, 2], d + state[:, 3],
-                e + state[:, 4], f + state[:, 5], g + state[:, 6], h + state[:, 7],
-            ],
-            axis=1,
+            return (t1 + t2, a, b, c, d + t1, e, f, g)
+
+        v = jax.lax.fori_loop(
+            0, 64, round_, tuple(state[:, i] for i in range(8))
         )
+        return jnp.stack([v[i] + state[:, i] for i in range(8)], axis=1)
 
     def kernel(blocks, nblk):
         # blocks: (batch, t_bucket, 16) uint32; nblk: (batch,) uint32.

@@ -1,11 +1,9 @@
 """VerifyD — the cross-process verification sidecar.
 
-The perf trajectory (BENCH_r01–r05, ROADMAP "Make the TPU path the path
-the benchmark actually takes") shows device *availability* is the
-bottleneck: every node process pays its own cold backend attach
-(20–83 s of warmup+compile), so an N-process host runs N cold backends
-— or, worse, N JAX-CPU fallbacks — while one warm mesh could serve them
-all. This module is the production answer, the shared batched
+A chip belongs to one process at a time, and a cold device start
+(attach + probe + warmup compile) costs minutes: an N-process host
+cannot run N backends on one chip, and N JAX-CPU fallbacks waste it,
+while one warm mesh could serve them all. This module is the production answer, the shared batched
 verification service the committee-consensus (arXiv:2302.00418) and
 FPGA-ECDSA-engine (arXiv:2112.02229) measurements point at:
 
@@ -434,14 +432,19 @@ class VerifyDaemon:
         )
 
     async def stop(self) -> None:
+        # stop accepting, drop the live connections, THEN wait: from
+        # Python 3.12 Server.wait_closed() waits for every connection to
+        # end, so waiting before cancelling the handlers never returns
+        # while one client is still connected
         if self._server is not None:
             self._server.close()
-            await self._server.wait_closed()
-            self._server = None
         for t in list(self._conn_tasks):
             t.cancel()
         if self._conn_tasks:
             await asyncio.gather(*self._conn_tasks, return_exceptions=True)
+        if self._server is not None:
+            await self._server.wait_closed()
+            self._server = None
         try:
             os.unlink(self.sock_path)
         except OSError:

@@ -11,8 +11,7 @@ shape-bucketing: every host-prep call that feeds a verify kernel
 (`prepare_batch_eq` / `prepare_resolved` / `prepare_batch`) must pass
 ``pad_to=`` — an unpadded call hands XLA the raw batch length as a
 static shape, and every new length is an inline cold compile on the hot
-path (the BENCH_r01–r05 rounds lost 20–83 s to exactly this class of
-stall). The dispatch core additionally asserts the padded shape is a
+path (tens of seconds to minutes per shape on the device). The dispatch core additionally asserts the padded shape is a
 bucket-ladder shape at runtime (crypto/tpu/verify._is_warm_bucket).
 
 fs-discipline: storage-layer writes go through the injectable

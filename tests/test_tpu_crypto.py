@@ -362,10 +362,6 @@ def test_pallas_field_mul_matches_gemm():
         assert F.limbs_to_int(want[i]) == F.limbs_to_int(got[i])
 
 
-@pytest.mark.slow  # interpret-mode Pallas on CPU: a 254-multiply
-# chain per element — minutes-to-hours on small hosts, far past the
-# tier-1 budget. The on-device A/B probe cross-checks the same
-# kernels against the XLA formulation on real TPU at startup.
 def test_pallas_pow22523_matches_xla_chain():
     """The fused VMEM pow22523 kernel (interpret mode on CPU) agrees with
     the portable XLA addition chain — and with exact integer math."""
@@ -407,10 +403,6 @@ def test_verify_resolved_chunked(monkeypatch):
     assert not out[100] and out.sum() == 149
 
 
-@pytest.mark.slow  # interpret-mode Pallas on CPU: a 254-multiply
-# chain per element — minutes-to-hours on small hosts, far past the
-# tier-1 budget. The on-device A/B probe cross-checks the same
-# kernels against the XLA formulation on real TPU at startup.
 def test_pallas_scan_blocks_matches_xla_scan():
     """The fused within-block prefix-scan kernel (interpret mode on CPU)
     is limb-exact with the lax.scan of curve.add_cached it replaces."""
