@@ -1,0 +1,16 @@
+"""kernel_ms_per_ksig.light
+
+Device time of the jit__kernel_eq / jit__kernel programs in the traced
+stretch, over thousands of signatures dispatched in it.
+"""
+
+from benchmark import readers
+
+LAYER = "kernels"
+UNIT = "ms/ksig"
+SOURCE = "device_trace"
+MOVES = "light_headers_per_s"
+
+
+def read(r: readers.Readings):
+    return readers.kernel_ms_per_ksig(r)
