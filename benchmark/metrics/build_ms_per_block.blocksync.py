@@ -1,0 +1,16 @@
+"""build_ms_per_block.blocksync
+
+`blocksync.build` (part-set rebuild, block hashing, entries of one range)
+over blocks applied.
+"""
+
+from benchmark import program_spans
+
+LAYER = "entry"
+UNIT = "ms/block"
+SOURCE = "program_span"
+MOVES = "blocksync_blocks_per_s"
+
+
+def read(r):
+    return program_spans.ms_per_unit(r, "blocksync.build")

@@ -1,0 +1,16 @@
+"""link_ms_per_header.light
+
+`light.link` (the adjacent-link checks of one window: header hashing,
+validator-set hashes) over headers verified.
+"""
+
+from benchmark import program_spans
+
+LAYER = "entry"
+UNIT = "ms/header"
+SOURCE = "program_span"
+MOVES = "light_headers_per_s"
+
+
+def read(r):
+    return program_spans.ms_per_unit(r, "light.link")

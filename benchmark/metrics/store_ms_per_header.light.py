@@ -1,0 +1,16 @@
+"""store_ms_per_header.light
+
+`light.store` (saving a session's verified light blocks to the trusted
+store) over headers verified.
+"""
+
+from benchmark import program_spans
+
+LAYER = "entry"
+UNIT = "ms/header"
+SOURCE = "program_span"
+MOVES = "light_headers_per_s"
+
+
+def read(r):
+    return program_spans.ms_per_unit(r, "light.store")

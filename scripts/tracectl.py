@@ -8,15 +8,24 @@ accepted: ``{"spans": [...]}`` wrappers or a bare span list.
 
     python scripts/tracectl.py dump.json            # per-stage table
     curl -s localhost:26657/debug/traces | python scripts/tracectl.py -
-    python scripts/tracectl.py dump.json --trace 42 # one trace, in order
+    python scripts/tracectl.py dump.json --trace 42 # one trace, as a tree
     python scripts/tracectl.py dump.json --subsystem hub
     python scripts/tracectl.py dump.json --per-device  # mesh shard table
 
 The per-stage table answers the ROADMAP question ("where did this vote
-spend its time?") in aggregate: count, p50, p90, p99, max, and total
-time per (subsystem, name) stage. ``--trace`` prints one end-to-end
-trace's spans in start order so a single message's life is readable
-top to bottom.
+spend its time?") in aggregate: count, p50, p90, p99, max, total and
+SELF time (the stage minus what its children — by ``parent_id`` — cover)
+per (subsystem, name) stage. ``--trace`` prints one end-to-end trace as
+a tree by ``parent_id``, children under their parent in start order,
+each with its self time: a block-sync range reads
+``blocksync.range > build, verify > validation.* > hub.* > batch.route >
+tpu.*``, then one ``blocksync.apply > state.*`` per block. Dumps from
+before span ids existed fall back to start order.
+
+The same spans appear in any ``jax.profiler`` trace taken while the node
+runs (``jax.profiler.start_trace`` / the profiler server): events named
+``tm.<subsystem>.<name>`` on the host planes (``/host:CPU``, one line per
+thread), beside the device's operations on the profiler's clock.
 """
 
 from __future__ import annotations
@@ -47,12 +56,49 @@ def _pct(sorted_vals: list[float], q: float) -> float:
     return sorted_vals[i]
 
 
+def _union_ms(intervals: list[tuple[float, float]]) -> float:
+    total, end = 0.0, float("-inf")
+    for a, b in sorted(intervals):
+        if a > end:
+            total, end = total + (b - a), b
+        elif b > end:
+            total, end = total + (b - end), b
+    return total
+
+
+def self_times(spans: list[dict]) -> dict[int, float]:
+    """span_id -> ms of the span that none of its children (by
+    ``parent_id``) cover: its duration minus the union of theirs, clipped
+    to it. Spans without ids (older dumps) are all self."""
+    kids: dict[int, list[tuple[float, float]]] = {}
+    for s in spans:
+        if s.get("parent_id"):
+            a = s.get("start_s", 0.0) * 1e3
+            kids.setdefault(s["parent_id"], []).append((a, a + s.get("duration_ms", 0.0)))
+    out = {}
+    for s in spans:
+        sid = s.get("span_id")
+        if not sid:
+            continue
+        a = s.get("start_s", 0.0) * 1e3
+        b = a + s.get("duration_ms", 0.0)
+        covered = _union_ms(
+            [(max(a, x), min(b, y)) for x, y in kids.get(sid, ()) if y > a and x < b]
+        )
+        out[sid] = max(0.0, (b - a) - covered)
+    return out
+
+
 def summarize(spans: list[dict]) -> str:
     """Per-stage latency table (the shape the acceptance run reads)."""
     stages: dict[str, list[float]] = {}
+    selfs: dict[str, float] = {}
+    own = self_times(spans)
     for s in spans:
         key = f"{s.get('subsystem', '?')}.{s.get('name', '?')}"
-        stages.setdefault(key, []).append(float(s.get("duration_ms", 0.0)))
+        dur = float(s.get("duration_ms", 0.0))
+        stages.setdefault(key, []).append(dur)
+        selfs[key] = selfs.get(key, 0.0) + own.get(s.get("span_id"), dur)
     if not stages:
         return "no spans"
     rows = []
@@ -67,15 +113,19 @@ def summarize(spans: list[dict]) -> str:
                 _pct(vals, 0.99),
                 vals[-1],
                 sum(vals),
+                selfs[key],
             )
         )
     rows.sort(key=lambda r: -r[6])  # biggest total time first
-    header = f"{'stage':<28} {'count':>7} {'p50ms':>9} {'p90ms':>9} {'p99ms':>9} {'maxms':>9} {'totalms':>10}"
+    header = (
+        f"{'stage':<28} {'count':>7} {'p50ms':>9} {'p90ms':>9} {'p99ms':>9} "
+        f"{'maxms':>9} {'totalms':>10} {'selfms':>10}"
+    )
     lines = [header, "-" * len(header)]
-    for key, n, p50, p90, p99, mx, total in rows:
+    for key, n, p50, p90, p99, mx, total, own_ms in rows:
         lines.append(
             f"{key:<28} {n:>7} {p50:>9.3f} {p90:>9.3f} {p99:>9.3f} "
-            f"{mx:>9.3f} {total:>10.2f}"
+            f"{mx:>9.3f} {total:>10.2f} {own_ms:>10.2f}"
         )
     return "\n".join(lines)
 
@@ -112,21 +162,41 @@ def per_device(spans: list[dict]) -> str:
 
 
 def render_trace(spans: list[dict], trace_id: int) -> str:
-    """One trace's spans in start order — a message's life, top down."""
+    """One trace as a tree by ``parent_id`` — a message's (or a range's)
+    life, top down, children in start order under their parent, each
+    line with the span's duration and its self time."""
     mine = [s for s in spans if s.get("trace_id") == trace_id]
     if not mine:
         return f"no spans for trace {trace_id}"
     mine.sort(key=lambda s: (s.get("start_s", 0.0), -s.get("duration_ms", 0.0)))
     t0 = mine[0].get("start_s", 0.0)
-    lines = [f"trace {trace_id} ({len(mine)} spans):"]
+    own = self_times(mine)
+    ids = {s.get("span_id") for s in mine if s.get("span_id")}
+    children: dict[int, list[dict]] = {}
+    roots = []
     for s in mine:
+        parent = s.get("parent_id")
+        if parent and parent in ids:
+            children.setdefault(parent, []).append(s)
+        else:
+            roots.append(s)  # the root, or a row whose parent the ring dropped
+    lines = [f"trace {trace_id} ({len(mine)} spans):"]
+
+    def walk(s: dict, depth: int) -> None:
         at = (s.get("start_s", 0.0) - t0) * 1e3
         attrs = s.get("attrs") or {}
         extra = " ".join(f"{k}={v}" for k, v in attrs.items())
+        dur = s.get("duration_ms", 0.0)
+        label = "  " * depth + f"{s.get('subsystem', '?')}.{s.get('name', '?')}"
         lines.append(
-            f"  +{at:9.3f}ms {s.get('subsystem','?')}.{s.get('name','?'):<18} "
-            f"{s.get('duration_ms', 0.0):9.3f}ms  {extra}"
+            f"  +{at:9.3f}ms {label:<34} {dur:9.3f}ms "
+            f"self {own.get(s.get('span_id'), dur):9.3f}ms  {extra}"
         )
+        for c in children.get(s.get("span_id"), ()):
+            walk(c, depth + 1)
+
+    for s in roots:
+        walk(s, 0)
     return "\n".join(lines)
 
 
@@ -134,7 +204,7 @@ def main(argv: list[str] | None = None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("dump", help="dump file path, or - for stdin")
     ap.add_argument("--subsystem", help="only this subsystem's spans")
-    ap.add_argument("--trace", type=int, help="print one trace in start order")
+    ap.add_argument("--trace", type=int, help="print one trace as a tree, with self times")
     ap.add_argument(
         "--per-device",
         action="store_true",

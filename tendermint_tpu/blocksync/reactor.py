@@ -17,6 +17,7 @@ from __future__ import annotations
 import asyncio
 import logging
 
+from ..libs import trace
 from ..libs.clock import SYSTEM, Clock
 from ..libs.service import Service
 from ..p2p.peermanager import PeerStatus
@@ -194,11 +195,20 @@ class BlockSyncReactor(Service):
                     # we keep serving BlockRequests/status to other peers
                     self.synced.set()
                     return
-                await asyncio.sleep(SWITCH_CHECK_INTERVAL)
+                # fewer than two blocks in hand: waiting on the fetch
+                with trace.span("blocksync", "idle", clock=self.clock):
+                    await asyncio.sleep(SWITCH_CHECK_INTERVAL)
                 continue
-            await self._verify_and_apply(run)
+            # one range is one trace: build, verify (on a worker thread:
+            # to_thread carries the context, so the commit funnel's and
+            # the hub's spans join it) and one apply per block
+            with trace.span(
+                "blocksync", "range", root=True, clock=self.clock,
+                first=run[0][0].header.height, n=len(run) - 1,
+            ) as sp:
+                await self._verify_and_apply(run, sp)
 
-    async def _verify_and_apply(self, run) -> None:
+    async def _verify_and_apply(self, run, range_span=trace.NOP_SPAN) -> None:
         """Verify blocks run[0..-2] using each successor's LastCommit in
         ONE batched call, then apply them in order."""
         chain_id = self.state.chain_id
@@ -209,13 +219,16 @@ class BlockSyncReactor(Service):
         entries = []
         parts_list = []
         assumed_vals = self.state.validators
-        for i in range(len(run) - 1):
-            block, _provider = run[i]
-            next_block, _ = run[i + 1]
-            parts = block.make_part_set()
-            parts_list.append(parts)
-            block_id = BlockID(block.hash(), parts.header)
-            entries.append((assumed_vals, block_id, block.header.height, next_block.last_commit))
+        with trace.span("blocksync", "build", n=len(run) - 1):
+            for i in range(len(run) - 1):
+                block, _provider = run[i]
+                next_block, _ = run[i + 1]
+                parts = block.make_part_set()
+                parts_list.append(parts)
+                block_id = BlockID(block.hash(), parts.header)
+                entries.append(
+                    (assumed_vals, block_id, block.header.height, next_block.last_commit)
+                )
         first_height = run[0][0].header.height
 
         # Stage 2 (TPU): one batched verification for the whole range
@@ -223,24 +236,17 @@ class BlockSyncReactor(Service):
             n_sigs = sum(
                 sum(1 for s in e[3].signatures if s.is_commit()) for e in entries
             )
-            t0 = self.clock.monotonic()
-            await asyncio.to_thread(
-                verify_commit_range, chain_id, entries, lane="backfill"
-            )
-            dt = self.clock.monotonic() - t0
+            range_span.set(sigs=n_sigs)
+            with trace.span("blocksync", "verify", sigs=n_sigs):
+                await asyncio.to_thread(
+                    verify_commit_range, chain_id, entries, lane="backfill"
+                )
             self.metrics["ranges"] += 1
             self.metrics["sigs_verified"] += n_sigs
             # the batch proved the commits FOR first_height..first+len-1
             # (each block's successor LastCommit), all against assumed_vals
             self._record_commit_proof(
                 first_height, first_height + len(entries) - 1, assumed_vals.hash()
-            )
-            self.logger.debug(
-                "verified range h=%d..%d (%d sigs) in %.1fms",
-                first_height,
-                first_height + len(entries) - 1,
-                n_sigs,
-                dt * 1e3,
             )
         except InvalidCommitError as e:
             # NOT necessarily byzantine: the whole range was verified
@@ -375,14 +381,18 @@ class BlockSyncReactor(Service):
     async def _apply_one(self, block, block_id, parts, next_block, provider) -> bool:
         height = block.header.height
         try:
-            if self.block_store.height() < height:
-                self.block_store.save_block(block, parts, next_block.last_commit)
-            self.state, _ = await self.block_exec.apply_block(
-                self.state,
-                block_id,
-                block,
-                commit_verified=self._commit_preverified(height),
-            )
+            with trace.span("blocksync", "apply", height=height):
+                if self.block_store.height() < height:
+                    with trace.span("blocksync", "save_block"):
+                        self.block_store.save_block(
+                            block, parts, next_block.last_commit
+                        )
+                self.state, _ = await self.block_exec.apply_block(
+                    self.state,
+                    block_id,
+                    block,
+                    commit_verified=self._commit_preverified(height),
+                )
             self.metrics["blocks_applied"] += 1
         except Exception as e:
             self.logger.error("apply failed at height %d: %r", height, e)

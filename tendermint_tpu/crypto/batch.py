@@ -12,6 +12,7 @@ import logging
 import os
 import threading
 
+from ..libs import trace
 from ..libs.metrics import record_resilience
 from ..libs.retry import CircuitBreaker
 from . import BatchVerifier, PubKey
@@ -90,13 +91,23 @@ def _probe_tpu() -> None:
     host's actual rates, not a guess. Every phase is recorded into
     `backend_telemetry` (attach latency, per-shape compile durations,
     the active verifier kind) so the attach story is readable from
-    /metrics and trace dumps instead of log tails."""
+    /metrics and trace dumps instead of log tails. The flight recorder
+    gets the same story as one trace: `backend.probe` (root, to the
+    thread's end; `available_s` is when routing could use the device)
+    over `backend.attach`, `backend.warmup` [shape] — the first holds
+    `backend.pallas_ab` — and `backend.cutoff` [value]."""
+    with trace.span("backend", "probe", root=True) as sp:
+        _probe_tpu_traced(sp)
+
+
+def _probe_tpu_traced(probe_span) -> None:
     import time as _time
 
     global _tpu_available
     from . import backend_telemetry as bt
 
     attach_recorded = False
+    t_start = _time.monotonic()
     try:
         from ..libs.watchdog import BackendInitWatchdog
         from .tpu.verify import backend_ready, warmup
@@ -137,14 +148,18 @@ def _probe_tpu() -> None:
             # warms the grouped A-side at the bucket a realistic validator
             # set lands on (gb=255), not just the all-padding floor shape
             t0 = _time.monotonic()
-            warmup(groups=150, fallback=True)
+            with trace.span("backend", "warmup", shape="floor"):
+                warmup(groups=150, fallback=True)
             bt.record_compile("floor", _time.monotonic() - t0)
-            _measure_cutoff()
+            with trace.span("backend", "cutoff") as sp:
+                _measure_cutoff()
+                sp.set(value=MIN_TPU_BATCH)
         # the TPU is usable as soon as the floor shapes are warm — flip
         # availability BEFORE the optional big-bucket warm below, so
         # normal consensus batches aren't CPU-routed for the minutes a
         # cold 8192-shape compile can take
         _tpu_available = ok
+        probe_span.set(ok=ok, available_s=round(_time.monotonic() - t_start, 3))
         if not ok:
             bt.set_active("cpu")
         logger.info("TPU batch verifier %s", "ready" if ok else "unavailable")
@@ -160,7 +175,8 @@ def _probe_tpu() -> None:
 
             try:
                 t0 = _time.monotonic()
-                warmup(bucket=_MAX_BUCKET, groups=150, fallback=True)
+                with trace.span("backend", "warmup", shape="max"):
+                    warmup(bucket=_MAX_BUCKET, groups=150, fallback=True)
                 bt.record_compile("max", _time.monotonic() - t0)
             except Exception as e:  # noqa: BLE001
                 bt.record_probe_error("warmup-max", repr(e))
@@ -397,18 +413,28 @@ class AdaptiveBatchVerifier(BatchVerifier):
     def _verify_edwards(self, items) -> tuple[list[bool], str]:
         """The ed25519/sr25519 partition: shared-MSM TPU kernel when the
         batch clears the measured cutoff, host loop otherwise."""
-        if len(items) >= MIN_TPU_BATCH and tpu_verifier_available():
-            out = self._device_guarded(
-                lambda: self._run(self._make_tpu_verifier(), items), len(items)
-            )
-            if out is not None:
-                if out is not _DEVICE_FAILED:
-                    from .tpu.verify import last_dispatch_info
+        with trace.span("batch", "route", n=len(items), cutoff=MIN_TPU_BATCH) as sp:
+            results, route, why = self._route_edwards(items)
+            sp.set(route=route, **({"why": why} if why else {}))
+        return results, route
 
-                    self.last_dispatch = last_dispatch_info()
-                    return out[1], "tpu"
-                return self._run(CPUBatchVerifier(), items)[1], "cpu-fallback"
-        return self._run(CPUBatchVerifier(), items)[1], "cpu"
+    def _route_edwards(self, items) -> tuple[list[bool], str, str]:
+        """(verdicts, route, why the host served it — "" on the device)."""
+        if len(items) < MIN_TPU_BATCH:
+            why = "below-cutoff"
+        elif not tpu_verifier_available():
+            why = "no-device"
+        else:
+            out = self._device_guarded(lambda: self._run_device(items), len(items))
+            if out is _DEVICE_FAILED:
+                return self._run(CPUBatchVerifier(), items)[1], "cpu-fallback", "device-error"
+            if out is not None:
+                from .tpu.verify import last_dispatch_info
+
+                self.last_dispatch = last_dispatch_info()
+                return out[1], "tpu", ""
+            why = "breaker-open"
+        return self._run(CPUBatchVerifier(), items)[1], "cpu", why
 
     def _verify_bls(self, items) -> tuple[list[bool], str]:
         """The BLS partition: the batched pairing-product kernel when
@@ -466,6 +492,16 @@ class AdaptiveBatchVerifier(BatchVerifier):
     def _run(self, target: BatchVerifier, items=None) -> tuple[bool, list[bool]]:
         for pk, msg, sig in items if items is not None else self._items:
             target.add(pk, msg, sig)
+        return target.verify()
+
+    def _run_device(self, items) -> tuple[bool, list[bool]]:
+        """`_run` on the device verifier, which resolves as it adds
+        (SHA-512 per signature): one span around the loop, never one a
+        signature."""
+        target = self._make_tpu_verifier()
+        with trace.span("tpu", "resolve", n=len(items)):
+            for pk, msg, sig in items:
+                target.add(pk, msg, sig)
         return target.verify()
 
 

@@ -17,6 +17,7 @@ from __future__ import annotations
 from functools import partial
 from typing import NamedTuple
 
+import jax
 import numpy as np
 import jax.numpy as jnp
 
@@ -156,6 +157,7 @@ def mul_by_cofactor(p: Point) -> Point:
     return point_double(point_double(point_double(p)))
 
 
+@jax.named_scope("decompress")  # metadata for a device trace only
 def decompress(y_bytes: jnp.ndarray) -> tuple[Point, jnp.ndarray]:
     """ZIP-215 point decompression.
 

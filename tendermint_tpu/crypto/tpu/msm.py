@@ -207,19 +207,22 @@ def msm(points: Point, digit_rows: jnp.ndarray) -> Point:
     scalars, window w of point i at digit_rows[w, i]. Returns one Point
     with scalar batch shape ().
     """
-    window_sums = jax.vmap(_window_sum, in_axes=(None, 0))(points, digit_rows)
+    # named scopes: metadata for a device trace, the program is the same
+    with jax.named_scope("buckets"):
+        window_sums = jax.vmap(_window_sum, in_axes=(None, 0))(points, digit_rows)
 
     # Horner over windows, most-significant first: acc ← 256·acc + W_w
-    rev = Point(*(c[::-1] for c in window_sums))
-    top = Point(*(c[0] for c in rev))
-    rest = Point(*(c[1:] for c in rev))
+    with jax.named_scope("fold"):
+        rev = Point(*(c[::-1] for c in window_sums))
+        top = Point(*(c[0] for c in rev))
+        rest = Point(*(c[1:] for c in rev))
 
-    def step(acc: Point, w: Point):
-        for _ in range(WINDOW_BITS):
-            acc = curve.point_double(acc)
-        return curve.point_add(acc, w), None
+        def step(acc: Point, w: Point):
+            for _ in range(WINDOW_BITS):
+                acc = curve.point_double(acc)
+            return curve.point_add(acc, w), None
 
-    acc, _ = jax.lax.scan(step, top, rest)
+        acc, _ = jax.lax.scan(step, top, rest)
     return acc
 
 

@@ -28,6 +28,7 @@ from math import gcd as _gcd
 
 import numpy as np
 
+from ...libs import trace
 from .. import BatchVerifier, PubKey
 
 L = 2**252 + 27742317777372353535851937790883648493
@@ -35,13 +36,27 @@ L = 2**252 + 27742317777372353535851937790883648493
 _MIN_BUCKET = 64
 
 
+def _install_trace_annotator() -> None:
+    """Put the flight recorder's spans on the profiler's clock
+    (libs/trace.set_annotator): from here on an entered span also
+    enters a `jax.profiler.TraceAnnotation` called
+    `tm.<subsystem>.<name>`. Called where this module has loaded jax
+    anyway — libs/trace itself never imports it."""
+    if not trace.annotator_installed():
+        import jax
+
+        trace.set_annotator(jax.profiler.TraceAnnotation)
+
+
 def backend_ready() -> bool:
     try:
         import jax
 
-        return len(jax.devices()) > 0
+        ready = len(jax.devices()) > 0
     except Exception:
         return False
+    _install_trace_annotator()
+    return ready
 
 
 def _kernel(a_bytes, r_bytes, s_digits, h_digits, s_valid):
@@ -53,19 +68,24 @@ def _kernel(a_bytes, r_bytes, s_digits, h_digits, s_valid):
     root is a ~254-multiply dependency chain, so halving the number of
     decompress instances both shrinks the graph and doubles the SIMD
     width through the longest serial section."""
+    import jax
     import jax.numpy as jnp
 
     from . import curve
 
+    # named scopes are metadata on the compiled program's operations (a
+    # device trace reads the kernel by phase); the program is the same
     stacked, ok = curve.decompress(jnp.concatenate([a_bytes, r_bytes], axis=0))
     n = a_bytes.shape[0]
     A = curve.Point(*(c[:n] for c in stacked))
     R = curve.Point(*(c[n:] for c in stacked))
     a_ok, r_ok = ok[:n], ok[n:]
-    v = curve.scalar_mul_double(s_digits, h_digits, curve.point_neg(A))  # sB - kA
-    w = curve.point_add(v, curve.point_neg(R))  # sB - kA - R
-    eq_ok = curve.is_identity(curve.mul_by_cofactor(w))
-    return a_ok & r_ok & eq_ok & s_valid
+    with jax.named_scope("scalar_mul"):
+        v = curve.scalar_mul_double(s_digits, h_digits, curve.point_neg(A))  # sB - kA
+    with jax.named_scope("finish"):
+        w = curve.point_add(v, curve.point_neg(R))  # sB - kA - R
+        eq_ok = curve.is_identity(curve.mul_by_cofactor(w))
+        return a_ok & r_ok & eq_ok & s_valid
 
 
 def _kernel_eq(ua_bytes, r_bytes, ga_digits, r_digits, zs_digits, s_valid, gidx):
@@ -101,6 +121,7 @@ def _kernel_eq(ua_bytes, r_bytes, ga_digits, r_digits, zs_digits, s_valid, gidx)
     per-signature kernel for attribution (historical block-sync batches
     are ~always all-valid, so the one-MSM happy path dominates).
     """
+    import jax
     import jax.numpy as jnp
 
     from . import curve, msm
@@ -132,8 +153,15 @@ def _kernel_eq(ua_bytes, r_bytes, ga_digits, r_digits, zs_digits, s_valid, gidx)
     )
     ga_digits = jnp.concatenate([ga_digits, zs_digits], axis=1)
 
-    acc = curve.point_add(msm.msm(ga, ga_digits), msm.msm(Rm, r_digits))
-    eq_ok = curve.is_identity(curve.mul_by_cofactor(acc))
+    # the kernel's phases by name (decompress is named in curve.py): a
+    # device trace lays its time to them; metadata only, same program
+    with jax.named_scope("msm_keys"):
+        keys_sum = msm.msm(ga, ga_digits)
+    with jax.named_scope("msm_sigs"):
+        sigs_sum = msm.msm(Rm, r_digits)
+    with jax.named_scope("finish"):
+        acc = curve.point_add(keys_sum, sigs_sum)
+        eq_ok = curve.is_identity(curve.mul_by_cofactor(acc))
     return ok_bitmap, eq_ok
 
 
@@ -174,6 +202,7 @@ def _ensure_compile_cache() -> None:
         return
     import jax
 
+    _install_trace_annotator()
     if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         os.makedirs(COMPILE_CACHE_DIR, exist_ok=True)
         jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
@@ -232,14 +261,16 @@ def _maybe_enable_pallas() -> None:
 
     if jax.default_backend() != "tpu":
         return
-    try:
-        _probe_mul_and_pow()
-    except Exception as e:  # noqa: BLE001 — surfaced by _probe_failed
-        _probe_failed("error", e)
-    try:
-        _probe_scan()
-    except Exception as e:  # noqa: BLE001 — surfaced by _probe_failed
-        _probe_failed("scan_error", e)
+    with trace.span("backend", "pallas_ab") as sp:
+        try:
+            _probe_mul_and_pow()
+        except Exception as e:  # noqa: BLE001 — surfaced by _probe_failed
+            _probe_failed("error", e)
+        try:
+            _probe_scan()
+        except Exception as e:  # noqa: BLE001 — surfaced by _probe_failed
+            _probe_failed("scan_error", e)
+        sp.set(**{k: v for k, v in field_mul_probe.items() if k.endswith("chosen")})
 
 
 def _limbs_equal(want, got) -> bool:
@@ -523,7 +554,8 @@ def make_sharded_kernel_eq(mesh, axis: str = "data"):
         n = r_bytes.shape[0]
         r_use = r_ok & s_valid
         Rm = curve.point_select(r_use, curve.point_neg(R), curve.identity((n,)))
-        part = jnp.stack(list(msm.msm(Rm, r_digits)))  # (4, 32)
+        with jax.named_scope("msm_sigs"):
+            part = jnp.stack(list(msm.msm(Rm, r_digits)))  # (4, 32)
         parts = jax.lax.all_gather(part, axis)  # (n_dev, 4, 32) everywhere
         total = _reduce_partials(Point(*(parts[:, i] for i in range(4))))
         # replicated epilogue: unique-key decompression + grouped A MSM
@@ -538,9 +570,13 @@ def make_sharded_kernel_eq(mesh, axis: str = "data"):
             *(jnp.concatenate([c, b[None]], axis=0) for c, b in zip(Am, bpt))
         )
         gd = jnp.concatenate([ga_digits, zs_digits], axis=1)
-        acc = curve.point_add(total, msm.msm(ga, gd))
+        with jax.named_scope("msm_keys"):
+            keys_sum = msm.msm(ga, gd)
+        with jax.named_scope("finish"):
+            acc = curve.point_add(total, keys_sum)
+            eq_ok = curve.is_identity(curve.mul_by_cofactor(acc))
         ok_bitmap = jnp.take(a_ok, gidx) & r_use
-        return ok_bitmap, curve.is_identity(curve.mul_by_cofactor(acc))
+        return ok_bitmap, eq_ok
 
     rep, rows = P(), P(axis)
     # check_vma=False: Pallas out_shapes carry no varying-axes annotation
@@ -894,7 +930,15 @@ def _dispatch_and_collect(n: int, get_entries, pad_multiple: int) -> np.ndarray:
     for i in range(0, n, _MAX_BUCKET):
         chunk = get_entries(i, min(i + _MAX_BUCKET, n))
         try:
-            res = sel.kernel_eq(*prepare_batch_eq(chunk, pad_to=sel.bucket))
+            # the host's share of a dispatch, and the jitted call itself
+            # (transfer + enqueue: it returns before the device is done)
+            # tmtlint: allow[span-per-item] -- per chunk of <= _MAX_BUCKET signatures
+            with trace.span("tpu", "prep", n=len(chunk), bucket=sel.bucket) as sp:
+                args = prepare_batch_eq(chunk, pad_to=sel.bucket)
+                sp.set(groups=int(args[0].shape[0]))
+            # tmtlint: allow[span-per-item] -- per chunk
+            with trace.span("tpu", "dispatch", bucket=sel.bucket):
+                res = sel.kernel_eq(*args)
         except Exception as e:  # noqa: BLE001 — settled at collect time
             res = e
         in_flight.append((chunk, res))
@@ -907,12 +951,20 @@ def _dispatch_and_collect(n: int, get_entries, pad_multiple: int) -> np.ndarray:
             if isinstance(res, Exception):
                 raise res
             bitmap, eq_ok = res
-            if bool(eq_ok):
-                out = np.asarray(bitmap)[: len(chunk)]
-            else:
-                out = np.asarray(
-                    sel.kernel_sig(*prepare_resolved(chunk, pad_to=sel.bucket))
-                )[: len(chunk)]
+            # the host blocked on the device: reading eq_ok waits for
+            # the chunk's program to end
+            # tmtlint: allow[span-per-item] -- per chunk
+            with trace.span("tpu", "collect", bucket=sel.bucket) as sp:
+                eq_ok = bool(eq_ok)
+                sp.set(eq_ok=eq_ok)
+                if eq_ok:
+                    out = np.asarray(bitmap)[: len(chunk)]
+            if not eq_ok:
+                # tmtlint: allow[span-per-item] -- per chunk whose equation failed
+                with trace.span("tpu", "attribute", n=len(chunk), bucket=sel.bucket):
+                    out = np.asarray(
+                        sel.kernel_sig(*prepare_resolved(chunk, pad_to=sel.bucket))
+                    )[: len(chunk)]
             if ids:
                 from .. import backend_telemetry as bt
 
@@ -967,11 +1019,11 @@ def verify_batch_eq(
     """(pubkey32, msg, sig64) ed25519 triples -> bool bitmap. Resolution
     (the SHA-512 per signature) happens per chunk inside the dispatch
     loop, so for multi-chunk batches it overlaps device execution."""
-    return _dispatch_and_collect(
-        len(items),
-        lambda i, j: [resolve_ed25519(*it) for it in items[i:j]],
-        pad_multiple,
-    )
+    def resolved(i: int, j: int) -> list:
+        with trace.span("tpu", "resolve", n=j - i):
+            return [resolve_ed25519(*it) for it in items[i:j]]
+
+    return _dispatch_and_collect(len(items), resolved, pad_multiple)
 
 
 def _bucket(n: int, multiple: int = 1) -> int:

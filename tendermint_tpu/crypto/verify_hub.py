@@ -105,8 +105,8 @@ LATENCY_BUCKETS = (
 class _Pending:
     """One unique (pubkey, msg, sig) triple awaiting a verdict. Duplicate
     submissions while it is queued/in flight append their futures here
-    (and their trace contexts — a coalesced gossip duplicate still gets
-    hub.queue/hub.execute spans on its own trace)."""
+    (and their trace contexts — a coalesced gossip duplicate's trace
+    still gets the dispatch's hub.queue/hub.execute spans)."""
 
     __slots__ = (
         "key", "pub_key", "msg", "sig", "futures", "enqueued_at", "lane", "traces",
@@ -273,6 +273,13 @@ class VerifyHub:
             # multi-tenant packing (the verifyd daemon's hub): dispatches
             # whose batch mixed signatures from >1 client connection
             "cross_tenant_dispatches": 0.0,
+            # where a request's latency goes before its batch runs:
+            # summed submit-to-pack wait of every packed request (the
+            # queue-latency histogram's sum, as a counter a reader can
+            # take deltas of), and the dispatcher's wait for a free
+            # in-flight slot (both runners busy)
+            "queue_wait_s": 0.0,
+            "slot_wait_s": 0.0,
         }
 
     # -- lifecycle -------------------------------------------------------
@@ -322,6 +329,7 @@ class VerifyHub:
         lane: str = LANE_LIVE,
         trace_ctx=None,
         tenant=None,
+        bulk: bool = False,
     ) -> Future:
         """Enqueue one verification; returns a concurrent Future[bool].
 
@@ -331,7 +339,10 @@ class VerifyHub:
         `lane` picks the scheduler lane: live consensus is packed ahead
         of backfill in every dispatch. `trace_ctx` (libs/trace.TraceCtx)
         joins the request to an end-to-end trace: the hub records
-        hub.queue and hub.execute spans on it."""
+        hub.queue and hub.execute spans on it, one per (dispatch, trace).
+        `bulk` marks one of a group submitted in a loop (`verify_many`):
+        its cache hit is counted by the caller, once for the group,
+        instead of leaving a hub.cache_hit row per signature."""
         if lane not in self._queues:
             # a typo'd lane at a new call site must fail loudly — a
             # silent fall-through to "live" would hand bulk catch-up
@@ -346,7 +357,7 @@ class VerifyHub:
             if verdict is not None:
                 self._cache.move_to_end(key)
                 self._stats["cache_hits"] += 1
-                if trace_ctx is not None:
+                if trace_ctx is not None and not bulk:
                     # zero-width marker anchored on the TRACE clock: the
                     # trace may time on an injected chaos clock, and a
                     # SYSTEM timestamp would land at a wrong offset in
@@ -450,11 +461,19 @@ class VerifyHub:
         all verdicts. The group is flushed as one urgent dispatch — plus
         whatever else is queued, so concurrent commit verifications from
         different subsystems share kernel launches."""
-        futs = [
-            self.submit_nowait(pk, msg, sig, lane=lane) for pk, msg, sig in items
-        ]
-        self.flush()
-        return [f.result(timeout) for f in futs]
+        # the caller's open span (the commit funnel's validation.verify):
+        # the whole group joins its trace ONCE — the hub records queue /
+        # execute per (dispatch, trace), never per signature
+        ctx = trace.current()
+        with trace.span("hub", "submit", n=len(items)) as sp:
+            futs = [
+                self.submit_nowait(pk, msg, sig, lane=lane, trace_ctx=ctx, bulk=True)
+                for pk, msg, sig in items
+            ]
+            self.flush()
+            sp.set(answered=sum(1 for f in futs if f.done()))
+        with trace.span("hub", "wait", n=len(items)):
+            return [f.result(timeout) for f in futs]
 
     def flush(self) -> None:
         """Dispatch everything currently queued without waiting out the
@@ -600,28 +619,45 @@ class VerifyHub:
             # batch — one whole batch less of tail latency. Only this
             # thread pops the queues, so the batch cannot vanish between
             # the window wait and the pack.
+            t_slot = time.monotonic()
             self._slots.acquire()
             with self._cv:
                 batch = self._pack_batch()
                 if not self._queued():
                     self._urgent = False
                 now = time.monotonic()
+                self._stats["slot_wait_s"] += now - t_slot
+                waited = 0.0
+                # trace id -> [ctx, earliest join, requests, lane]: queue
+                # and execute spans go out once per (dispatch, trace) — a
+                # single vote's trace holds one request, a block-sync
+                # range's thousands
+                joined: dict[int, list] = {}
                 for p in batch:
-                    self.latency_hist.observe(now - p.enqueued_at)
+                    wait = now - p.enqueued_at
+                    waited += wait
+                    self.latency_hist.observe(wait)
                     if p.traces:
-                        # queue span: submit-to-pack wait, per joined
-                        # trace. enqueued_at is SYSTEM-domain; the trace
-                        # may time on an injected chaos clock, so measure
-                        # the wait in SYSTEM and anchor it ending at the
-                        # trace clock's now (the reactor does the same
-                        # for p2p.receive)
-                        for ctx, joined in p.traces:
-                            tc_now = ctx.clock.monotonic()
-                            trace.record(
-                                ctx, "hub", "queue",
-                                tc_now - max(0.0, now - joined), tc_now,
-                                lane=p.lane,
-                            )
+                        for ctx, at in p.traces:
+                            seen = joined.get(ctx.trace_id)
+                            if seen is None:
+                                joined[ctx.trace_id] = [ctx, at, 1, p.lane]
+                            else:
+                                seen[1] = min(seen[1], at)
+                                seen[2] += 1
+                self._stats["queue_wait_s"] += waited
+                # queue span: submit-to-pack wait, per joined trace.
+                # enqueued_at is SYSTEM-domain; the trace may time on an
+                # injected chaos clock, so measure the wait in SYSTEM and
+                # anchor it ending at the trace clock's now (the reactor
+                # does the same for p2p.receive)
+                for ctx, at, n, lane in joined.values():
+                    tc_now = ctx.clock.monotonic()
+                    # tmtlint: allow[span-per-item] -- one row per (dispatch, trace), not per request
+                    trace.record(
+                        ctx, "hub", "queue",
+                        tc_now - max(0.0, now - at), tc_now, lane=lane, n=n,
+                    )
                 self._stats["dispatches"] += 1
                 self._stats["dispatched_sigs"] += len(batch)
                 tenants: set = set()
@@ -638,7 +674,7 @@ class VerifyHub:
                 )
             # hand off outside the lock; the runner's done-callback
             # frees the slot
-            fut = self._runner.submit(self._run_batch, batch)
+            fut = self._runner.submit(self._run_batch, batch, joined)
             fut.add_done_callback(lambda _f: self._slots.release())
 
     def _pack_batch(self) -> list[_Pending]:
@@ -656,23 +692,38 @@ class VerifyHub:
                 self._stats[f"lane_{lane}_dispatched"] += 1
         return batch
 
-    def _run_batch(self, batch: list[_Pending]) -> None:
+    def _run_batch(self, batch: list[_Pending], joined: dict | None = None) -> None:
+        """Verify one packed batch on a runner thread and settle its
+        futures. `joined` is the dispatcher's map of the traces the
+        batch's requests belong to. This thread inherits no context:
+        the hub.dispatch span joins the batch's trace when there is
+        exactly one (a block-sync range: always), else stands alone,
+        and lists every joined trace id."""
         self._worker_ids.add(threading.get_ident())
+        joined = joined or {}
+        only = next(iter(joined.values()))[0] if len(joined) == 1 else None
         t0 = time.monotonic()
-        try:
-            results = self._verify_batch(batch)
-        except Exception as e:  # noqa: BLE001 — fail the batch, not the hub
-            with self._cv:
-                self._stats["verify_errors"] += 1
-            logger.warning("batch of %d failed to verify: %r", len(batch), e)
-            with self._cv:
-                for p in batch:
-                    self._inflight.pop(p.key, None)
-            for p in batch:
-                for f in p.futures:
-                    if not f.done():
-                        f.set_exception(e)
-            return
+        with trace.span("hub", "dispatch", ctx=only, sigs=len(batch)) as sp:
+            try:
+                results = self._verify_batch(batch)
+            except Exception as e:  # noqa: BLE001 — fail the batch, not the hub
+                sp.set(error=repr(e))
+                self._fail_batch(batch, e)
+                return
+            # where THIS batch actually ran. _verify_batch stashed the
+            # route in a thread-local: the process-global batch.LAST_ROUTE
+            # can be overwritten by concurrent verifiers elsewhere (the
+            # validation funnel builds its own)
+            route = getattr(self._route_local, "route", "cpu")
+            disp = getattr(self._route_local, "dispatch", None)
+            sp.set(route=route)
+            if joined:
+                sp.set(traces=list(joined))
+            if disp:
+                # sharded dispatches carry per-device occupancy: device
+                # ids + real signatures per shard (tracectl --per-device)
+                sp.set(devices=disp["devices"], shards=disp["shards"])
+        t1 = time.monotonic()
         with self._cv:
             for p, ok in zip(batch, results):
                 self._inflight.pop(p.key, None)
@@ -681,42 +732,31 @@ class VerifyHub:
                     self._cache.move_to_end(p.key)
                     while len(self._cache) > self.cache_size:
                         self._cache.popitem(last=False)
-        if trace.is_enabled():
-            # per-batch dispatch span + per-trace execute spans, stamped
-            # with where THIS batch actually ran. _verify_batch stashed
-            # the route in a thread-local: the process-global
-            # batch.LAST_ROUTE can be overwritten by concurrent
-            # verifiers elsewhere (the validation funnel builds its own)
-            route = getattr(self._route_local, "route", "cpu")
-            disp = getattr(self._route_local, "dispatch", None)
-            t1 = time.monotonic()
-            trace.emit(
-                "hub", "dispatch",
-                duration_s=t1 - t0, sigs=len(batch), route=route,
-                # sharded dispatches carry per-device occupancy: device
-                # ids + real signatures per shard (tracectl --per-device)
-                **(
-                    {"devices": disp["devices"], "shards": disp["shards"]}
-                    if disp
-                    else {}
-                ),
+        # t0/t1 are SYSTEM-domain; anchor each trace's execute span
+        # ending at the trace clock's now so it sits correctly among the
+        # trace's other spans under an injected chaos clock
+        for ctx, _at, n, _lane in joined.values():
+            tc_now = ctx.clock.monotonic()
+            # tmtlint: allow[span-per-item] -- one row per (dispatch, trace), not per request
+            trace.record(
+                ctx, "hub", "execute", tc_now - (t1 - t0), tc_now,
+                batch=len(batch), n=n, route=route,
             )
-            for p in batch:
-                if p.traces:
-                    for ctx, _ in p.traces:
-                        # t0/t1 are SYSTEM-domain; anchor the execute
-                        # span ending at the trace clock's now so it
-                        # sits correctly among the trace's other spans
-                        # under an injected chaos clock
-                        tc_now = ctx.clock.monotonic()
-                        trace.record(
-                            ctx, "hub", "execute", tc_now - (t1 - t0), tc_now,
-                            batch=len(batch), route=route,
-                        )
         for p, ok in zip(batch, results):
             for f in p.futures:
                 if not f.done():
                     f.set_result(ok)
+
+    def _fail_batch(self, batch: list[_Pending], e: Exception) -> None:
+        with self._cv:
+            self._stats["verify_errors"] += 1
+            for p in batch:
+                self._inflight.pop(p.key, None)
+        logger.warning("batch of %d failed to verify: %r", len(batch), e)
+        for p in batch:
+            for f in p.futures:
+                if not f.done():
+                    f.set_exception(e)
 
     def _remote(self, purpose: str = "batch"):
         """The verifyd sidecar client for this hub's configured socket,

@@ -27,6 +27,25 @@ Design constraints (all load-bearing):
     `span()` returns one shared no-op singleton, `record()`/`emit()`
     return before touching the ring — near-zero overhead.
 
+Cause and identity. Every ring row carries a ``span_id`` and the
+``parent_id`` of the span that was open around it (counter ids, like
+``trace_id``). The *current* span lives in a ``contextvars.ContextVar``:
+``with span(...)`` with no ``ctx`` inherits trace id, clock and parent
+from its caller — across ``await`` and across ``asyncio.to_thread``
+(which copies the context); a plain thread (the hub's dispatcher and
+runners) starts with none. ``span(..., root=True)`` opens a new trace.
+
+One clock with the device trace. While an annotator is installed
+(`set_annotator`; `crypto/tpu/verify.py` installs
+``jax.profiler.TraceAnnotation`` once jax is loaded — this module never
+imports jax) every entered `Span` also enters an annotation named
+``tm.<subsystem>.<name>``. With no profiler session that is a flag
+test; inside one — ``jax.profiler.start_trace`` / the profiler server,
+an operator's or a benchmark's — the program's spans sit on the host
+planes of the trace (``/host:CPU``, one line per thread) beside the
+device's operations, on the profiler's clock. No knob: it follows the
+recorder's own switch.
+
 Two recording APIs:
 
   * ``with span("hub", "dispatch", attrs...) as sp:`` — context-manager
@@ -41,12 +60,16 @@ The ring dumps on demand (`/debug/traces`, `scripts/tracectl.py`) and
 automatically on wedge/breaker-trip via `auto_dump(reason)` (wired from
 `libs/watchdog.LoopWatchdog` and the TPU breaker in `crypto/batch.py`).
 
-Env knobs: TMTPU_TRACE=0 disables, TMTPU_TRACE_RING sizes the ring,
-TMTPU_TRACE_DIR points auto-dumps at a directory.
+Env knobs: TMTPU_TRACE=0 disables, TMTPU_TRACE_RING sizes the ring
+(default 32,768 rows: a 64-block block-sync window leaves about 6k
+rows and its warm-up 3k more, and a reader of a window must find all of
+it; a full ring is 6-7 MB), TMTPU_TRACE_DIR points auto-dumps at a
+directory.
 """
 
 from __future__ import annotations
 
+import contextvars
 import itertools
 import json
 import logging
@@ -58,12 +81,40 @@ from .clock import SYSTEM, Clock
 
 logger = logging.getLogger("libs.trace")
 
-DEFAULT_RING = 4096
+DEFAULT_RING = 32768
 
-#: process-wide id source — a counter, not uuid/random/time: trace ids
-#: never enter protocol output, and a counter keeps seeded paths clean
-#: for the nondeterminism analyzer
+#: process-wide id sources — counters, not uuid/random/time: trace and
+#: span ids never enter protocol output, and a counter keeps seeded
+#: paths clean for the nondeterminism analyzer
 _ids = itertools.count(1)
+_span_ids = itertools.count(1)
+
+#: the innermost open Span of this context (task / to_thread worker)
+_current: contextvars.ContextVar = contextvars.ContextVar("tm_trace_span", default=None)
+
+#: name -> context manager on the profiler's clock, or None (see
+#: set_annotator)
+_annotator = None
+
+
+def set_annotator(factory) -> None:
+    """Install (or, with None, remove) the factory that puts entered
+    spans on the profiler's clock: `factory("tm.<subsystem>.<name>")`
+    returns a context manager. The caller owns the import of whatever
+    backs it (crypto/tpu/verify.py: jax.profiler.TraceAnnotation)."""
+    global _annotator
+    _annotator = factory
+
+
+def annotator_installed() -> bool:
+    return _annotator is not None
+
+
+def current():
+    """The innermost open Span of the calling context, or None: what a
+    bulk caller hands to another thread so its rows join this trace
+    (has `.trace_id`, `.span_id`, `.clock`, as a TraceCtx does)."""
+    return _current.get()
 
 
 class TraceCtx:
@@ -72,10 +123,13 @@ class TraceCtx:
     small `marks` dict for boundary timestamps shared across pipeline
     stages (so stage durations sum EXACTLY to the end-to-end span)."""
 
-    __slots__ = ("trace_id", "t0", "clock", "marks")
+    __slots__ = ("trace_id", "span_id", "t0", "clock", "marks")
 
     def __init__(self, trace_id: int, t0: float, clock: Clock):
         self.trace_id = trace_id
+        # the root span's id (`finish` records it); every `record` on
+        # this ctx is its child
+        self.span_id = next(_span_ids)
         self.t0 = t0
         self.clock = clock
         self.marks: dict[str, float] = {}
@@ -83,27 +137,49 @@ class TraceCtx:
 
 class Span:
     """One in-progress span (context-manager use only — see the
-    span-discipline lint rule). `set(k=v)` attaches attrs mid-flight."""
+    span-discipline lint rule). `set(k=v)` attaches attrs mid-flight.
+    While entered it is the context's current span: spans opened inside
+    it (same task, or a to_thread worker) become its children."""
 
-    __slots__ = ("_rec", "trace_id", "subsystem", "name", "_clock", "_t0", "attrs")
+    __slots__ = (
+        "_rec", "trace_id", "span_id", "parent_id", "subsystem", "name", "clock",
+        "_t0", "attrs", "_token", "_ann",
+    )
 
-    def __init__(self, rec, trace_id, subsystem, name, clock, attrs):
+    def __init__(self, rec, trace_id, parent_id, subsystem, name, clock, attrs):
         self._rec = rec
         self.trace_id = trace_id
+        self.span_id = next(_span_ids)
+        self.parent_id = parent_id
         self.subsystem = subsystem
         self.name = name
-        self._clock = clock
+        self.clock = clock
         self._t0 = 0.0
         self.attrs = attrs
+        self._token = None
+        self._ann = None
 
     def set(self, **attrs) -> None:
         self.attrs.update(attrs)
 
     def __enter__(self) -> "Span":
-        self._t0 = self._clock.monotonic()
+        self._token = _current.set(self)
+        if _annotator is not None:
+            self._ann = _annotator(f"tm.{self.subsystem}.{self.name}")
+            self._ann.__enter__()
+        self._t0 = self.clock.monotonic()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
+        dur = self.clock.monotonic() - self._t0
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+        try:
+            _current.reset(self._token)
+        except ValueError:
+            # closed in another context than it was opened in (a
+            # generator resumed elsewhere): that context never saw it
+            pass
         if exc_type is not None:
             self.attrs["error"] = repr(exc)
         self._rec._append(
@@ -111,8 +187,10 @@ class Span:
             self.subsystem,
             self.name,
             self._t0,
-            self._clock.monotonic() - self._t0,
+            dur,
             self.attrs or None,
+            self.span_id,
+            self.parent_id,
         )
 
 
@@ -150,7 +228,8 @@ class FlightRecorder:
         self.enabled = enabled
         self.ring_size = max(1, ring_size)
         self.out_dir = out_dir
-        # (trace_id, subsystem, name, start_s, duration_s, attrs|None)
+        # (trace_id, subsystem, name, start_s, duration_s, attrs|None,
+        #  span_id, parent_id); rows land in the order spans END
         self._ring: deque[tuple] = deque(maxlen=self.ring_size)
         self.recorded = 0  # total appended; dropped = recorded - len(ring)
         # auto_dump records (reason + path); bounded — /debug/flight?dump=
@@ -161,10 +240,15 @@ class FlightRecorder:
 
     # -- recording -------------------------------------------------------
 
-    def _append(self, trace_id, subsystem, name, start_s, dur_s, attrs) -> None:
+    def _append(
+        self, trace_id, subsystem, name, start_s, dur_s, attrs, span_id=0, parent_id=0
+    ) -> None:
         # deque.append with maxlen evicts the oldest atomically under the
         # GIL — safe from both the event loop and the hub's threads
-        self._ring.append((trace_id, subsystem, name, start_s, dur_s, attrs))
+        self._ring.append(
+            (trace_id, subsystem, name, start_s, dur_s, attrs,
+             span_id or next(_span_ids), parent_id)
+        )
         self.recorded += 1
 
     def start(self, clock: Clock | None = None) -> TraceCtx | None:
@@ -189,7 +273,8 @@ class FlightRecorder:
         if ctx is None or not self.enabled:
             return
         self._append(
-            ctx.trace_id, subsystem, name, start_s, end_s - start_s, attrs or None
+            ctx.trace_id, subsystem, name, start_s, end_s - start_s, attrs or None,
+            0, ctx.span_id,
         )
 
     def finish(self, ctx: TraceCtx | None, subsystem: str, name: str, **attrs) -> None:
@@ -197,25 +282,38 @@ class FlightRecorder:
         if ctx is None or not self.enabled:
             return
         now = ctx.clock.monotonic()
-        self._append(ctx.trace_id, subsystem, name, ctx.t0, now - ctx.t0, attrs or None)
+        self._append(
+            ctx.trace_id, subsystem, name, ctx.t0, now - ctx.t0, attrs or None,
+            ctx.span_id, 0,
+        )
 
     def span(
         self,
         subsystem: str,
         name: str,
         *,
-        ctx: TraceCtx | None = None,
+        ctx=None,
         clock: Clock | None = None,
+        root: bool = False,
         **attrs,
     ) -> Span | _NopSpan:
-        """Context-manager span for a code block. With a ctx the span
-        joins that trace (and times on its clock); without one it is a
-        standalone event on `clock` (default SYSTEM)."""
+        """Context-manager span for a code block. With a `ctx` (a
+        TraceCtx, or another context's Span from `current()`) the span
+        joins that trace as its child, on its clock. Without one it
+        inherits trace, parent and clock from the calling context's
+        current span; with none open — or `root=True`, which also opens
+        a new trace — it stands alone on `clock` (default SYSTEM)."""
         if not self.enabled:
             return NOP_SPAN
-        if ctx is not None:
-            return Span(self, ctx.trace_id, subsystem, name, ctx.clock, attrs)
-        return Span(self, 0, subsystem, name, clock or SYSTEM, attrs)
+        if root:
+            return Span(self, next(_ids), 0, subsystem, name, clock or SYSTEM, attrs)
+        if ctx is None:
+            ctx = _current.get()
+            if ctx is None:
+                return Span(self, 0, 0, subsystem, name, clock or SYSTEM, attrs)
+        return Span(
+            self, ctx.trace_id, ctx.span_id, subsystem, name, clock or ctx.clock, attrs
+        )
 
     def emit(
         self,
@@ -227,11 +325,17 @@ class FlightRecorder:
         **attrs,
     ) -> None:
         """Point-in-time event (attach attempt, breaker trip): a span of
-        the given duration ending now."""
+        the given duration ending now, a child of the calling context's
+        current span where one is open."""
         if not self.enabled:
             return
         now = (clock or SYSTEM).monotonic()
-        self._append(0, subsystem, name, now - duration_s, duration_s, attrs or None)
+        cur = _current.get()
+        trace_id, parent_id = (cur.trace_id, cur.span_id) if cur is not None else (0, 0)
+        self._append(
+            trace_id, subsystem, name, now - duration_s, duration_s, attrs or None,
+            0, parent_id,
+        )
 
     # -- introspection ---------------------------------------------------
 
@@ -250,13 +354,15 @@ class FlightRecorder:
         filtered by subsystem or trace id."""
         spans = list(self._ring)
         out = []
-        for tid, sub, name, start, dur, attrs in spans:
+        for tid, sub, name, start, dur, attrs, sid, pid in spans:
             if subsystem is not None and sub != subsystem:
                 continue
             if trace_id is not None and tid != trace_id:
                 continue
             d = {
                 "trace_id": tid,
+                "span_id": sid,
+                "parent_id": pid,
                 "subsystem": sub,
                 "name": name,
                 "start_s": round(start, 6),
@@ -411,8 +517,8 @@ def finish(ctx, subsystem, name, **attrs) -> None:
     RECORDER.finish(ctx, subsystem, name, **attrs)
 
 
-def span(subsystem, name, *, ctx=None, clock=None, **attrs):
-    return RECORDER.span(subsystem, name, ctx=ctx, clock=clock, **attrs)
+def span(subsystem, name, *, ctx=None, clock=None, root=False, **attrs):
+    return RECORDER.span(subsystem, name, ctx=ctx, clock=clock, root=root, **attrs)
 
 
 def emit(subsystem, name, *, duration_s=0.0, clock=None, **attrs) -> None:

@@ -16,6 +16,7 @@ import time
 from dataclasses import dataclass
 from fractions import Fraction
 
+from ..libs import trace
 from ..store.db import DB, MemDB
 from . import verifier
 from .provider import LightBlockNotFoundError, Provider, ProviderError
@@ -192,10 +193,12 @@ class LightClient:
             verified = await self._verify_sequential(latest, target, now_ns, pending)
         else:
             verified = await self._verify_skipping(latest, target, now_ns, pending)
-        await self._detect_divergence(verified, now_ns, trust_anchor=latest)
-        for lb in pending:
-            self.store.save(lb)
-        self.store.save(verified)
+        with trace.span("light", "detect_divergence", height=verified.height):
+            await self._detect_divergence(verified, now_ns, trust_anchor=latest)
+        with trace.span("light", "store", n=len(pending) + 1):
+            for lb in pending:
+                self.store.save(lb)
+            self.store.save(verified)
         return verified
 
     async def update(self, now_ns: int | None = None) -> LightBlock:
@@ -220,24 +223,28 @@ class LightClient:
         h = trusted.height + 1
         while h <= target.height:
             top = min(h + window - 1, target.height)
-            # fetches are independent (verification is deferred to the
-            # end of the window), so issue them concurrently — over a
-            # real provider the serial RPC round-trips dominate, not the
-            # signature math. Concurrency is semaphore-bounded and a
-            # failed fetch cancels its in-flight siblings.
-            chain = await _gather_cancelling(
-                [
-                    (
-                        _as_ready(target)
-                        if hh == target.height
-                        else self.primary.light_block(hh)
+            # one window is one trace: fetch, then the link checks and the
+            # range verify (light.link / light.verify, in the verifier)
+            with trace.span("light", "window", root=True, first=h, n=top - h + 1):
+                # fetches are independent (verification is deferred to the
+                # end of the window), so issue them concurrently — over a
+                # real provider the serial RPC round-trips dominate, not the
+                # signature math. Concurrency is semaphore-bounded and a
+                # failed fetch cancels its in-flight siblings.
+                with trace.span("light", "fetch", n=top - h + 1):
+                    chain = await _gather_cancelling(
+                        [
+                            (
+                                _as_ready(target)
+                                if hh == target.height
+                                else self.primary.light_block(hh)
+                            )
+                            for hh in range(h, top + 1)
+                        ]
                     )
-                    for hh in range(h, top + 1)
-                ]
-            )
-            trusted = verifier.verify_adjacent_chain(
-                self.chain_id, trusted, chain, self.trust_options.period_ns, now_ns
-            )
+                trusted = verifier.verify_adjacent_chain(
+                    self.chain_id, trusted, chain, self.trust_options.period_ns, now_ns
+                )
             pending.extend(chain)
             h = top + 1
         return trusted

@@ -20,6 +20,7 @@ import time
 from fractions import Fraction
 
 from ..crypto import hash_hub
+from ..libs import trace
 from ..types.validation import (
     InvalidCommitError,
     verify_commit_light,
@@ -145,21 +146,23 @@ def verify_adjacent_chain(
     with hash_hub.lane_ctx(hash_hub.LANE_LIGHT):
         entries = []
         prev = trusted
-        for lb in chain:
-            _check_adjacent_link(
-                chain_id, prev, lb, trusting_period_ns, now_ns, max_clock_drift_ns
-            )
-            entries.append(
-                (
-                    lb.validators,
-                    lb.signed_header.commit.block_id,
-                    lb.height,
-                    lb.signed_header.commit,
+        with trace.span("light", "link", n=len(chain)):
+            for lb in chain:
+                _check_adjacent_link(
+                    chain_id, prev, lb, trusting_period_ns, now_ns, max_clock_drift_ns
                 )
-            )
-            prev = lb
+                entries.append(
+                    (
+                        lb.validators,
+                        lb.signed_header.commit.block_id,
+                        lb.height,
+                        lb.signed_header.commit,
+                    )
+                )
+                prev = lb
         try:
-            verify_commit_range(chain_id, entries, lane="backfill")
+            with trace.span("light", "verify", n=len(chain)):
+                verify_commit_range(chain_id, entries, lane="backfill")
         except InvalidCommitError as e:
             idx = getattr(e, "failed_index", None)
             at = f" at height {chain[idx].height}" if idx is not None else ""

@@ -10,7 +10,7 @@ import logging
 
 from .. import crypto
 from ..abci import types as abci
-from ..libs import fail
+from ..libs import fail, trace
 from ..abci.client import Client
 from ..evidence import EvidencePoolI, NopEvidencePool
 from ..mempool import Mempool, NopMempool
@@ -178,12 +178,18 @@ class BlockExecutor:
         (reference execution.go:151). Returns (new_state, retain_height).
         commit_verified: the caller proved LastCommit's signatures already
         (block-sync range batches; see state/validation.py)."""
-        self.validate_block(state, block, commit_verified=commit_verified)
+        # the flight recorder's view of one apply (children of the
+        # caller's span — block-sync's blocksync.apply, consensus's):
+        # validate, exec, save_responses, commit, save
+        with trace.span("state", "validate"):
+            self.validate_block(state, block, commit_verified=commit_verified)
 
-        responses = await self._exec_block(state, block)
+        with trace.span("state", "exec", txs=len(block.txs)):
+            responses = await self._exec_block(state, block)
         # crash points 4-5 mirror execution.go:170-217's fail.Fail sites
         fail.fail_point(4)  # block executed, before persisting responses
-        self.state_store.save_abci_responses(block.header.height, responses)
+        with trace.span("state", "save_responses"):
+            self.state_store.save_abci_responses(block.header.height, responses)
         fail.fail_point(5)  # responses saved, before app Commit
 
         # validator + params updates requested by the app
@@ -193,15 +199,17 @@ class BlockExecutor:
         new_state = self._update_state(state, block_id, block, responses, val_updates)
 
         # commit app state under the mempool lock (execution.go:245)
-        async with self.mempool.lock():
-            res_commit = await self.app.commit()
-            await self.mempool.update(
-                block.header.height,
-                list(block.txs),
-                list(responses.deliver_txs),
-            )
+        with trace.span("state", "commit"):
+            async with self.mempool.lock():
+                res_commit = await self.app.commit()
+                await self.mempool.update(
+                    block.header.height,
+                    list(block.txs),
+                    list(responses.deliver_txs),
+                )
         new_state = new_state.copy(app_hash=res_commit.data)
-        self.state_store.save(new_state)
+        with trace.span("state", "save"):
+            self.state_store.save(new_state)
 
         self.evidence_pool.update(new_state, block.evidence)
 

@@ -16,6 +16,14 @@ Two invariants guard the tracing layer (libs/trace.py):
     refactor could leak it into seeded paths. (`time.monotonic` is the
     duration domain and stays legal — `libs/clock.Clock.monotonic` is
     built on it.)
+
+  * **No row per item on the verify funnel** (`span-per-item`). In
+    `crypto/` and `types/validation.py` the loops run per signature —
+    thousands a block-sync range. A `trace.span`/`record`/`emit` inside
+    a `for`/`while` there is one ring row (and one profiler annotation)
+    per iteration: the span goes AROUND the loop, with `n=` on it. A
+    loop that is bounded by something else (chunks, dispatches, the
+    traces a batch joined) says so in a pragma.
 """
 
 from __future__ import annotations
@@ -117,4 +125,50 @@ class SpanDiscipline(Rule):
             )
 
 
-RULES = (SpanDiscipline(),)
+class SpanPerItem(Rule):
+    id = "span-per-item"
+    doc = (
+        "no trace.span/record/emit inside a loop in crypto/ or "
+        "types/validation.py: per-signature work is one span around the "
+        "loop with n= on it"
+    )
+    scope = ("tendermint_tpu/crypto/", "tendermint_tpu/types/validation.py")
+
+    RECORDING = ("span", "record", "emit", "finish")
+    LOOPS = (
+        ast.For, ast.AsyncFor, ast.While,
+        ast.ListComp, ast.SetComp, ast.DictComp, ast.GeneratorExp,
+    )
+
+    def _is_recording_call(self, ctx: FileContext, node: ast.Call) -> bool:
+        name = ctx.resolve_call(node)
+        if name is None:
+            return False
+        head, _, attr = name.rpartition(".")
+        return attr in self.RECORDING and (
+            head == "trace" or head.endswith(".trace") or head.endswith("RECORDER")
+        )
+
+    def check(self, ctx: FileContext) -> Iterable[Finding]:
+        for node in ast.walk(ctx.tree):
+            if not isinstance(node, ast.Call) or not self._is_recording_call(ctx, node):
+                continue
+            cur = ctx.parents.get(node)
+            while cur is not None and not isinstance(
+                cur, (ast.FunctionDef, ast.AsyncFunctionDef, ast.Lambda)
+            ):
+                if isinstance(cur, self.LOOPS):
+                    yield ctx.finding(
+                        self.id,
+                        node,
+                        "flight-recorder row inside a loop on the verify "
+                        "funnel: loops here run per signature, and a row "
+                        "each floods the ring (thousands a range) — put ONE "
+                        "span around the loop with n= on it, or say what "
+                        "bounds this loop in a pragma",
+                    )
+                    break
+                cur = ctx.parents.get(cur)
+
+
+RULES = (SpanDiscipline(), SpanPerItem())

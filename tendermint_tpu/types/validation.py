@@ -26,6 +26,7 @@ from dataclasses import dataclass
 from fractions import Fraction
 
 from ..crypto import batch as crypto_batch
+from ..libs import trace
 from .block import BlockID, Commit
 from .validator_set import ValidatorSet
 
@@ -50,6 +51,8 @@ class _CommitVerifier:
         self._pub_key = pub_key
         self._lane = lane
         self._items: list[tuple] = []
+        #: where the last verify() went: "hub" or "local"
+        self.via = "local"
 
     def add(self, pub_key, msg: bytes, sig: bytes) -> None:
         self._items.append((pub_key, msg, sig))
@@ -61,6 +64,7 @@ class _CommitVerifier:
         if hub is not None:
             try:
                 results = hub.verify_many(self._items, lane=self._lane)
+                self.via = "hub"
                 return all(results) and bool(results), results
             except Exception as e:  # noqa: BLE001 — stall/shutdown races
                 # same contract as verify_one: a wedged hub costs
@@ -337,50 +341,59 @@ def verify_commit_range(
     # validators[0] crashed whenever address ordering put a secp256k1
     # key first (seen as a restarted node's block-sync dying mid-e2e)
     bv = None
-    added_any = False
-    for ei, (vals, block_id, height, commit) in enumerate(entries):
-        try:
-            _basic_commit_checks(vals, block_id, height, commit)
-            if commit.is_aggregate() or not _should_batch_verify(vals, commit):
-                # aggregate commits are one indivisible pairing product
-                # (verdict-cached in the hub); mixed/secp256k1 sets
-                # verify individually
-                verify_commit_light(
-                    chain_id, vals, block_id, height, commit, lane=lane
-                )
-                continue
-            if bv is None:
-                bv = _CommitVerifier(vals.validators[0].pub_key, lane=lane)
-            voting_power_needed = vals.total_voting_power() * 2 // 3
-            tallied = 0
-            for idx, cs, val in _iter_entries(vals, commit, lookup_by_index=True):
-                if not cs.is_commit():
+    added = 0
+    # the whole collect loop is ONE span (basic checks, sign-bytes, tally,
+    # add): thousands of signatures a range, never a row each
+    with trace.span("validation", "collect", commits=len(entries)) as sp:
+        for ei, (vals, block_id, height, commit) in enumerate(entries):
+            try:
+                _basic_commit_checks(vals, block_id, height, commit)
+                if commit.is_aggregate() or not _should_batch_verify(vals, commit):
+                    # aggregate commits are one indivisible pairing product
+                    # (verdict-cached in the hub); mixed/secp256k1 sets
+                    # verify individually
+                    verify_commit_light(
+                        chain_id, vals, block_id, height, commit, lane=lane
+                    )
                     continue
-                bv.add(val.pub_key, commit.vote_sign_bytes(chain_id, idx), cs.signature)
-                added_any = True
-                tallied += val.voting_power
-                if tallied > voting_power_needed:
-                    break
-            if tallied <= voting_power_needed:
-                raise InvalidCommitError(
-                    f"insufficient voting power at height {height}: "
-                    f"got {tallied}, need > {voting_power_needed}"
-                )
-        except InvalidCommitError as e:
-            e.failed_index = ei
-            raise
-    if not added_any:
+                if bv is None:
+                    bv = _CommitVerifier(vals.validators[0].pub_key, lane=lane)
+                voting_power_needed = vals.total_voting_power() * 2 // 3
+                tallied = 0
+                for idx, cs, val in _iter_entries(vals, commit, lookup_by_index=True):
+                    if not cs.is_commit():
+                        continue
+                    bv.add(
+                        val.pub_key, commit.vote_sign_bytes(chain_id, idx), cs.signature
+                    )
+                    added += 1
+                    tallied += val.voting_power
+                    if tallied > voting_power_needed:
+                        break
+                if tallied <= voting_power_needed:
+                    raise InvalidCommitError(
+                        f"insufficient voting power at height {height}: "
+                        f"got {tallied}, need > {voting_power_needed}"
+                    )
+            except InvalidCommitError as e:
+                e.failed_index = ei
+                raise
+        sp.set(sigs=added)
+    if not added:
         return
-    ok, _bitmap = bv.verify()
+    with trace.span("validation", "verify", sigs=added) as sp:
+        ok, _bitmap = bv.verify()
+        sp.set(via=bv.via)
     if ok:
         return
     # locate the offending commit: per-commit fallback
-    for ei, (vals, block_id, height, commit) in enumerate(entries):
-        try:
-            verify_commit_light(chain_id, vals, block_id, height, commit, lane=lane)
-        except InvalidCommitError as e:
-            e.failed_index = ei
-            raise
+    with trace.span("validation", "locate", commits=len(entries)):
+        for ei, (vals, block_id, height, commit) in enumerate(entries):
+            try:
+                verify_commit_light(chain_id, vals, block_id, height, commit, lane=lane)
+            except InvalidCommitError as e:
+                e.failed_index = ei
+                raise
     raise InvalidCommitError("range batch failed but all commits verify singly")
 
 

@@ -15,6 +15,8 @@ import subprocess
 import sys
 import textwrap
 
+import pytest
+
 from tendermint_tpu.tools.lint import (
     ALL_RULES,
     BAD_PRAGMA,
@@ -951,6 +953,68 @@ def test_watchdog_wall_clock_allowlisted():
         )
         == []
     )
+
+
+# ---------------------------------------------------------------------------
+# span-per-item
+
+
+@pytest.mark.parametrize("call", [
+    'with trace.span("tpu", "resolve"):\n                pass',
+    'trace.record(ctx, "hub", "queue", 0.0, 1.0)',
+    'trace.emit("hub", "cache_hit")',
+    'trace.RECORDER.emit("hub", "cache_hit")',
+])
+def test_span_per_item_row_inside_a_loop_flagged(call):
+    src = f"""
+    from tendermint_tpu.libs import trace
+    def f(items, ctx):
+        for it in items:
+            {call}
+    """
+    for rel in ("tendermint_tpu/crypto/batch.py", "tendermint_tpu/types/validation.py"):
+        assert len(run(src, "span-per-item", rel=rel)) == 1, rel
+    # outside the verify funnel a row per iteration is not this rule's business
+    assert run(src, "span-per-item", rel="tendermint_tpu/blocksync/reactor.py") == []
+
+
+def test_span_per_item_around_the_loop_clean():
+    src = """
+    from tendermint_tpu.libs import trace
+    def f(items, target):
+        with trace.span("tpu", "resolve", n=len(items)):
+            for it in items:
+                target.add(*it)
+        return [trace.current() for _ in items]
+    """
+    assert run(src, "span-per-item", rel="tendermint_tpu/crypto/batch.py") == []
+
+
+def test_span_per_item_comprehension_and_while_flagged_nested_def_not():
+    src = """
+    from tendermint_tpu.libs import trace
+    def f(items):
+        out = [trace.emit("a", "b") for _ in items]
+        while items:
+            trace.emit("a", "c")
+            items.pop()
+        for it in items:
+            def later():
+                trace.emit("a", "d")
+    """
+    assert {f.line for f in run(src, "span-per-item", rel="tendermint_tpu/crypto/x.py")} == {4, 6}
+
+
+def test_span_per_item_pragma_names_the_bound():
+    src = """
+    from tendermint_tpu.libs import trace
+    def f(chunks):
+        for chunk in chunks:
+            # tmtlint: allow[span-per-item] -- per chunk of 8192 signatures
+            with trace.span("tpu", "prep", n=len(chunk)):
+                pass
+    """
+    assert run(src, "span-per-item", rel="tendermint_tpu/crypto/tpu/verify.py") == []
 
 
 # ---------------------------------------------------------------------------
