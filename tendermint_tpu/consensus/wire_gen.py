@@ -7,7 +7,7 @@
 # `scripts/tmtlint` (the wiregen-drift rule re-renders this module
 # in memory and fails the gate on any byte difference). Disable at
 # runtime with TMTPU_WIREGEN=0 (interpreted protoenc fallback).
-# schema-hash: sha256:e0adc5c7a3924b7e9f8dd794b85780551db1cff1b7b8f4b85e0fe149f59cfda4
+# schema-hash: sha256:5b52fec663b9cc88d77ea13574ad2a4ddca8d60bfaf7577c642923375cde9b25
 # tmtlint: allow-file[*] -- machine-generated codec; wiregen-drift pins it byte-identical to a fresh regen from the wire-schema lockfile
 """Generated hot-path consensus codec (see header; do not edit).
 

@@ -12,6 +12,8 @@ from __future__ import annotations
 
 import abc
 
+from ..libs import protoenc as pe
+
 
 class PubKey(abc.ABC):
     TYPE: str = ""
@@ -110,14 +112,10 @@ _PUBKEY_PROTO_TYPE = {v: k for k, v in PUBKEY_PROTO_FIELD.items()}
 def pubkey_to_proto(pub: PubKey) -> bytes:
     """Serialize as the reference's PublicKey oneof message — byte-exact
     (frozen against the reference's MBT vectors, tests/test_light_mbt.py)."""
-    from ..libs import protoenc as pe
-
     return pe.bytes_field(PUBKEY_PROTO_FIELD[pub.TYPE], pub.bytes())
 
 
 def pubkey_from_proto(data: bytes) -> PubKey:
-    from ..libs import protoenc as pe
-
     r = pe.Reader(data)
     f, wt = r.read_tag()
     try:

@@ -22,19 +22,44 @@ WIRE_BYTES = 2
 WIRE_FIXED32 = 5
 
 
+def _uvarint_loop(value: int) -> bytes:
+    out = bytearray()
+    while value > 0x7F:
+        out.append((value & 0x7F) | 0x80)
+        value >>= 7
+    out.append(value)
+    return bytes(out)
+
+
+# Every constant of the wire format is made once, here: the one-byte
+# varints (lengths under 128, flags, small counts) and the tags of field
+# numbers 0..31 under all wire types. A 150-validator commit asks for
+# the same few hundreds of times over.
+_B1 = tuple(bytes((i,)) for i in range(0x80))
+_TAGS = tuple(_uvarint_loop(i) for i in range(32 << 3))
+_pack_Q = struct.Struct("<Q").pack
+_U64 = (1 << 64) - 1
+
+
 def uvarint(value: int) -> bytes:
     """Encode an unsigned integer as a protobuf base-128 varint."""
+    if 0 <= value <= 0x7F:
+        return _B1[value]
     if value < 0:
         raise ValueError("uvarint requires a non-negative value")
-    out = bytearray()
-    while True:
-        b = value & 0x7F
-        value >>= 7
-        if value:
-            out.append(b | 0x80)
-        else:
-            out.append(b)
-            return bytes(out)
+    return _uvarint_loop(value)
+
+
+def varint(value: int) -> bytes:
+    """An int64 as a varint value: a negative number is its 10-byte two's
+    complement (what proto does with a negative int64)."""
+    if 0 <= value <= 0x7F:
+        return _B1[value]
+    return _uvarint_loop(value & _U64 if value < 0 else value)
+
+
+#: an sfixed64 value (what sfixed64_field writes after its tag)
+sfixed64 = struct.Struct("<q").pack
 
 
 def svarint(value: int) -> bytes:
@@ -43,17 +68,36 @@ def svarint(value: int) -> bytes:
 
 
 def tag(field_number: int, wire_type: int) -> bytes:
-    return uvarint((field_number << 3) | wire_type)
+    key = (field_number << 3) | wire_type
+    if 0 <= key < len(_TAGS):
+        return _TAGS[key]
+    return uvarint(key)
+
+
+_KIND_WIRE_TYPE = {
+    "varint": WIRE_VARINT,
+    "sfixed64": WIRE_FIXED64,
+    "fixed64": WIRE_FIXED64,
+    "bytes": WIRE_BYTES,
+    "message": WIRE_BYTES,
+}
+
+
+def field_tag(field_number: int, kind: str) -> bytes:
+    """The tag of a field, for an encoder of many elements that makes it
+    once, as a module constant, and writes `TAG + value` itself. `kind`
+    is the name the wire-schema lockfile gives the field's helper
+    ("varint", "sfixed64", "fixed64", "bytes", "message"): tmtlint reads
+    the constant's definition and locks the encoder that uses it like one
+    that calls the helper."""
+    return tag(field_number, _KIND_WIRE_TYPE[kind])
 
 
 def varint_field(field_number: int, value: int) -> bytes:
     """Varint field; 0 is omitted (proto3 default-elision)."""
     if value == 0:
         return b""
-    if value < 0:
-        # proto encodes negative int64 as 10-byte two's complement varint
-        value &= (1 << 64) - 1
-    return tag(field_number, WIRE_VARINT) + uvarint(value)
+    return tag(field_number, WIRE_VARINT) + varint(value)
 
 
 def bool_field(field_number: int, value: bool) -> bytes:
@@ -63,13 +107,13 @@ def bool_field(field_number: int, value: bool) -> bytes:
 def sfixed64_field(field_number: int, value: int) -> bytes:
     if value == 0:
         return b""
-    return tag(field_number, WIRE_FIXED64) + struct.pack("<q", value)
+    return tag(field_number, WIRE_FIXED64) + sfixed64(value)
 
 
 def fixed64_field(field_number: int, value: int) -> bytes:
     if value == 0:
         return b""
-    return tag(field_number, WIRE_FIXED64) + struct.pack("<Q", value)
+    return tag(field_number, WIRE_FIXED64) + _pack_Q(value)
 
 
 def bytes_field(field_number: int, value: bytes) -> bytes:

@@ -69,6 +69,12 @@ FIELD_HELPERS = {
     "tag": "tag",
 }
 
+#: `NAME = pe.field_tag(N, "kind")` at module level: a tag made once at
+#: import. The encoder that loads NAME emits field N of that kind there,
+#: and is locked exactly as if it had called the kind's helper.
+TAG_HELPER = "field_tag"
+_TAG_KINDS = frozenset(FIELD_HELPERS.values()) - {"tag"}
+
 _MAX_NAME = re.compile(r"^_?MAX_[A-Z0-9_]+$|^[A-Z0-9_]+_MAX$")
 _CHANNEL_NAME = re.compile(r"^[A-Z0-9_]*_CHANNEL$")
 
@@ -141,7 +147,7 @@ def _field_repr(pctx: ProjectContext, rel: str, node: ast.expr) -> tuple[str, fl
 
 
 def _pe_helper(
-    pctx: ProjectContext, rel: str, node: ast.Call
+    pctx: ProjectContext, rel: str, node: ast.Call, names=FIELD_HELPERS
 ) -> str | None:
     """The protoenc encode helper a call resolves to, if any: matches
     `pe.varint_field(...)` through a module alias bound to
@@ -151,14 +157,34 @@ def _pe_helper(
     f = node.func
     if isinstance(f, ast.Attribute) and isinstance(f.value, ast.Name):
         target = imports.get(f.value.id, "")
-        if target.endswith(_PROTOENC) and f.attr in FIELD_HELPERS:
+        if target.endswith(_PROTOENC) and f.attr in names:
             return f.attr
     elif isinstance(f, ast.Name):
         target = imports.get(f.id, "")
         head, _, helper = target.rpartition(".")
-        if head.endswith(_PROTOENC) and helper in FIELD_HELPERS:
+        if head.endswith(_PROTOENC) and helper in names:
             return helper
     return None
+
+
+def _folded_tags(pctx: ProjectContext, rel: str) -> dict[str, str]:
+    """Module-level tag constants of `rel`: NAME -> "field:kind"."""
+    out: dict[str, str] = {}
+    for stmt in pctx.files[rel].tree.body:
+        if not (
+            isinstance(stmt, ast.Assign)
+            and len(stmt.targets) == 1
+            and isinstance(stmt.targets[0], ast.Name)
+            and isinstance(stmt.value, ast.Call)
+            and len(stmt.value.args) == 2
+            and _pe_helper(pctx, rel, stmt.value, (TAG_HELPER,))
+        ):
+            continue
+        number, kind = stmt.value.args
+        if isinstance(kind, ast.Constant) and kind.value in _TAG_KINDS:
+            repr_, _sort = _field_repr(pctx, rel, number)
+            out[stmt.targets[0].id] = f"{repr_}:{kind.value}"
+    return out
 
 
 def file_uses_protoenc(pctx: ProjectContext, rel: str) -> bool:
@@ -224,6 +250,19 @@ def extract_file_wire(pctx: ProjectContext, rel: str) -> _FileWire | None:
         wire.encoders.setdefault(qn, []).append(
             (node.lineno, node.col_offset, f"{repr_}:{FIELD_HELPERS[helper]}")
         )
+
+    folded = _folded_tags(pctx, rel)
+    if folded:
+        for node in ast.walk(ctx.tree):
+            if (
+                isinstance(node, ast.Name)
+                and isinstance(node.ctx, ast.Load)
+                and node.id in folded
+                and ctx.enclosing_function(node) is not None
+            ):
+                wire.encoders.setdefault(_qualname(ctx, node), []).append(
+                    (node.lineno, node.col_offset, folded[node.id])
+                )
 
     # -- decode side ----------------------------------------------------
     funcs: list[tuple[str, list[ast.AST]]] = []

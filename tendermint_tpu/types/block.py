@@ -13,7 +13,7 @@ from dataclasses import dataclass, field, replace
 from ..crypto.hashes import HASH_SIZE
 from ..crypto import merkle
 from ..libs import protoenc as pe
-from .canonical import vote_sign_bytes, encode_timestamp
+from .canonical import encode_timestamp, vote_sign_template
 from .keys import (
     BLOCK_ID_FLAG_ABSENT,
     BLOCK_ID_FLAG_COMMIT,
@@ -109,6 +109,17 @@ class BlockID:
 
 NIL_BLOCK_ID = BlockID()
 
+_varint = pe.varint
+_uvarint = pe.uvarint
+
+# tags of the messages a commit repeats once per validator, made once
+_SIG_FLAG = pe.field_tag(1, "varint")
+_SIG_ADDRESS = pe.field_tag(2, "bytes")
+_SIG_TIMESTAMP = pe.field_tag(3, "message")
+_SIG_SIGNATURE = pe.field_tag(4, "bytes")
+_COMMIT_SIG = pe.field_tag(4, "message")
+_BLOCK_TX = pe.field_tag(2, "message")
+
 
 @dataclass(frozen=True)
 class CommitSig:
@@ -145,10 +156,16 @@ class CommitSig:
         return commit_block_id if self.flag == BLOCK_ID_FLAG_COMMIT else NIL_BLOCK_ID
 
     def encode(self) -> bytes:
-        out = pe.varint_field(1, self.flag)
-        out += pe.bytes_field(2, self.validator_address)
-        out += pe.message_field(3, encode_timestamp(self.timestamp_ns))
-        out += pe.bytes_field(4, self.signature)
+        flag = self.flag
+        out = _SIG_FLAG + _varint(flag) if flag else b""
+        addr = self.validator_address
+        if addr:
+            out += _SIG_ADDRESS + _uvarint(len(addr)) + addr
+        ts = encode_timestamp(self.timestamp_ns)
+        out += _SIG_TIMESTAMP + _uvarint(len(ts)) + ts
+        sig = self.signature
+        if sig:
+            out += _SIG_SIGNATURE + _uvarint(len(sig)) + sig
         return out
 
     @classmethod
@@ -232,19 +249,16 @@ class Commit:
     def is_aggregate(self) -> bool:
         return bool(self.agg_sig)
 
+    def sign_bytes(self, chain_id: str) -> "CommitSignBytes":
+        """The canonical sign-bytes of this commit's precommits, by
+        index: what a loop over the signatures asks for."""
+        return CommitSignBytes(self, chain_id)
+
     def vote_sign_bytes(self, chain_id: str, idx: int) -> bytes:
         """Rebuild the canonical sign-bytes of validator idx's precommit
         (reference types/block.go:816 → vote.go:93). This is host-side work
         feeding the TPU batch verifier."""
-        cs = self.signatures[idx]
-        return vote_sign_bytes(
-            chain_id,
-            SignedMsgType.PRECOMMIT,
-            self.height,
-            self.round,
-            cs.block_id(self.block_id),
-            cs.timestamp_ns,
-        )
+        return self.sign_bytes(chain_id)(idx)
 
     def hash(self) -> bytes:
         leaves = [cs.encode() for cs in self.signatures]
@@ -258,14 +272,18 @@ class Commit:
         return len(self.signatures)
 
     def encode(self) -> bytes:
-        out = pe.sfixed64_field(1, self.height)
-        out += pe.sfixed64_field(2, self.round)
-        out += pe.message_field(3, self.block_id.encode())
+        parts = [
+            pe.sfixed64_field(1, self.height),
+            pe.sfixed64_field(2, self.round),
+            pe.message_field(3, self.block_id.encode()),
+        ]
+        add = parts.append
         for cs in self.signatures:
-            out += pe.message_field(4, cs.encode())
+            e = cs.encode()
+            add(_COMMIT_SIG + _uvarint(len(e)) + e)
         if self.agg_sig:
-            out += pe.bytes_field(5, self.agg_sig)
-        return out
+            add(pe.bytes_field(5, self.agg_sig))
+        return b"".join(parts)
 
     @classmethod
     def decode(cls, data: bytes) -> "Commit":
@@ -312,6 +330,43 @@ class Commit:
                     participating += 1
             if aggregate and participating == 0:
                 raise ValueError("aggregate commit with no participating signers")
+
+
+class CommitSignBytes:
+    """`sign_bytes(idx)` for the signatures of ONE commit under one chain
+    ID. Type, height, round, block ID and chain ID are the same for every
+    signature of a commit, so they are encoded once per flag
+    (canonical.vote_sign_template): block votes share the template over
+    the commit's block ID, nil votes the one with no block ID, each made
+    when first asked for. Lives as long as the loop that made it; the
+    commit keeps nothing."""
+
+    __slots__ = ("_commit", "_chain_id", "_templates")
+
+    def __init__(self, commit: Commit, chain_id: str):
+        self._commit = commit
+        self._chain_id = chain_id
+        self._templates = [None, None]  # [nil votes, block votes]
+
+    @property
+    def templates(self) -> int:
+        """How many templates were built so far (0, 1 or 2)."""
+        return sum(t is not None for t in self._templates)
+
+    def __call__(self, idx: int) -> bytes:
+        commit = self._commit
+        cs = commit.signatures[idx]
+        for_block = cs.flag == BLOCK_ID_FLAG_COMMIT
+        template = self._templates[for_block]
+        if template is None:
+            template = self._templates[for_block] = vote_sign_template(
+                self._chain_id,
+                SignedMsgType.PRECOMMIT,
+                commit.height,
+                commit.round,
+                commit.block_id if for_block else NIL_BLOCK_ID,
+            )
+        return template(cs.timestamp_ns)
 
 
 def aggregate_commit(commit: Commit, vals) -> Commit:
@@ -512,14 +567,15 @@ class Block:
         return PartSet.from_data(self.encode(), part_size or BLOCK_PART_SIZE)
 
     def encode(self) -> bytes:
-        out = pe.message_field(1, self.header.encode())
+        parts = [pe.message_field(1, self.header.encode())]
+        add = parts.append
         for tx in self.txs:
-            out += pe.message_field(2, tx)
+            add(_BLOCK_TX + _uvarint(len(tx)) + tx)
         if self.last_commit is not None:
-            out += pe.message_field(3, self.last_commit.encode())
+            add(pe.message_field(3, self.last_commit.encode()))
         for ev in self.evidence:
-            out += pe.message_field(4, ev.encode())
-        return out
+            add(pe.message_field(4, ev.encode()))
+        return b"".join(parts)
 
     @classmethod
     def decode(cls, data: bytes) -> "Block":

@@ -32,15 +32,24 @@ exactly the same signer statements.
 
 from __future__ import annotations
 
+from collections.abc import Callable
+
 from ..libs import protoenc as pe
 from .keys import SignedMsgType
 
 NANOS = 1_000_000_000
 
+_TS_SECONDS = pe.field_tag(1, "varint")
+_TS_NANOS = pe.field_tag(2, "varint")
+_VOTE_TIMESTAMP = pe.field_tag(5, "message")
+
 
 def encode_timestamp(ns: int) -> bytes:
     seconds, nanos = divmod(ns, NANOS)
-    return pe.varint_field(1, seconds) + pe.varint_field(2, nanos)
+    out = _TS_SECONDS + pe.varint(seconds) if seconds else b""
+    if nanos:
+        out += _TS_NANOS + pe.uvarint(nanos)
+    return out
 
 
 def encode_canonical_part_set_header(total: int, hash_: bytes) -> bytes:
@@ -59,6 +68,39 @@ def encode_canonical_block_id(block_id) -> bytes | None:
     )
 
 
+def vote_sign_template(
+    chain_id: str,
+    msg_type: SignedMsgType,
+    height: int,
+    round_: int,
+    block_id,
+) -> Callable[[int], bytes]:
+    """The canonical vote split into what a whole commit fixes — type,
+    height, round, canonical block ID (omitted for nil) and chain ID —
+    and what one signature adds: its timestamp field and the outer
+    length prefix. The fixed part is encoded here, once; the function
+    returned gives the sign-bytes of one timestamp. A 150-validator
+    commit shares one template for its block votes and one for its nil
+    votes; nothing is kept beyond the returned function."""
+    head = pe.varint_field(1, int(msg_type))
+    head += pe.sfixed64_field(2, height)
+    head += pe.sfixed64_field(3, round_)
+    cbid = encode_canonical_block_id(block_id)
+    if cbid is not None:
+        head += pe.message_field(4, cbid)
+    head += _VOTE_TIMESTAMP  # a message field: emitted even when empty
+    tail = pe.string_field(6, chain_id)
+    fixed = len(head) + 1 + len(tail)
+    uvarint = pe.uvarint
+
+    def sign_bytes(timestamp_ns: int) -> bytes:
+        ts = encode_timestamp(timestamp_ns)
+        n = len(ts)
+        return b"".join((uvarint(fixed + n), head, uvarint(n), ts, tail))
+
+    return sign_bytes
+
+
 def vote_sign_bytes(
     chain_id: str,
     msg_type: SignedMsgType,
@@ -67,15 +109,9 @@ def vote_sign_bytes(
     block_id,
     timestamp_ns: int,
 ) -> bytes:
-    out = pe.varint_field(1, int(msg_type))
-    out += pe.sfixed64_field(2, height)
-    out += pe.sfixed64_field(3, round_)
-    cbid = encode_canonical_block_id(block_id)
-    if cbid is not None:
-        out += pe.message_field(4, cbid)
-    out += pe.message_field(5, encode_timestamp(timestamp_ns))
-    out += pe.string_field(6, chain_id)
-    return pe.len_prefixed(out)
+    return vote_sign_template(chain_id, msg_type, height, round_, block_id)(
+        timestamp_ns
+    )
 
 
 def strip_timestamp(sign_bytes: bytes, field: int = 5) -> tuple[bytes, int]:

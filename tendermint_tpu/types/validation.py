@@ -238,6 +238,7 @@ def _verify_aggregate(
     tallied = 0
     pubs = []
     msgs = []
+    sign_bytes = commit.sign_bytes(chain_id)
     seen: set[bytes] = set()
     for idx, cs in enumerate(commit.signatures):
         if cs.is_absent():
@@ -270,7 +271,7 @@ def _verify_aggregate(
                 f"index {idx}"
             )
         pubs.append(val.pub_key)
-        msgs.append(commit.vote_sign_bytes(chain_id, idx))
+        msgs.append(sign_bytes(idx))
         if cs.is_commit():
             tallied += val.voting_power
     if tallied <= voting_power_needed:
@@ -288,13 +289,14 @@ def _verify_batch(
     lookup_by_index, lane="live",
 ) -> None:
     bv = _CommitVerifier(vals.validators[0].pub_key, lane=lane)
+    sign_bytes = commit.sign_bytes(chain_id)
     tallied = 0
     added = 0
     entries = []
     for idx, cs, val in _iter_entries(vals, commit, lookup_by_index):
         if not count_all_signatures and not cs.is_commit():
             continue
-        bv.add(val.pub_key, commit.vote_sign_bytes(chain_id, idx), cs.signature)
+        bv.add(val.pub_key, sign_bytes(idx), cs.signature)
         added += 1
         entries.append((idx, cs, val))
         if cs.is_commit():
@@ -342,6 +344,7 @@ def verify_commit_range(
     # key first (seen as a restarted node's block-sync dying mid-e2e)
     bv = None
     added = 0
+    templates = 0
     # the whole collect loop is ONE span (basic checks, sign-bytes, tally,
     # add): thousands of signatures a range, never a row each
     with trace.span("validation", "collect", commits=len(entries)) as sp:
@@ -359,17 +362,17 @@ def verify_commit_range(
                 if bv is None:
                     bv = _CommitVerifier(vals.validators[0].pub_key, lane=lane)
                 voting_power_needed = vals.total_voting_power() * 2 // 3
+                sign_bytes = commit.sign_bytes(chain_id)
                 tallied = 0
                 for idx, cs, val in _iter_entries(vals, commit, lookup_by_index=True):
                     if not cs.is_commit():
                         continue
-                    bv.add(
-                        val.pub_key, commit.vote_sign_bytes(chain_id, idx), cs.signature
-                    )
+                    bv.add(val.pub_key, sign_bytes(idx), cs.signature)
                     added += 1
                     tallied += val.voting_power
                     if tallied > voting_power_needed:
                         break
+                templates += sign_bytes.templates
                 if tallied <= voting_power_needed:
                     raise InvalidCommitError(
                         f"insufficient voting power at height {height}: "
@@ -378,7 +381,7 @@ def verify_commit_range(
             except InvalidCommitError as e:
                 e.failed_index = ei
                 raise
-        sp.set(sigs=added)
+        sp.set(sigs=added, templates=templates)
     if not added:
         return
     with trace.span("validation", "verify", sigs=added) as sp:
@@ -403,12 +406,13 @@ def _verify_single(
 ) -> None:
     from ..crypto.verify_hub import verify_one
 
+    sign_bytes = commit.sign_bytes(chain_id)
     tallied = 0
     for idx, cs, val in _iter_entries(vals, commit, lookup_by_index):
         if not count_all_signatures and not cs.is_commit():
             continue
         if not verify_one(
-            val.pub_key, commit.vote_sign_bytes(chain_id, idx), cs.signature,
+            val.pub_key, sign_bytes(idx), cs.signature,
             lane=lane,
         ):
             raise InvalidCommitError(f"invalid signature at index {idx}")

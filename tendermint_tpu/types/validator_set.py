@@ -12,7 +12,7 @@ from __future__ import annotations
 
 from dataclasses import dataclass, replace
 
-from ..crypto import PubKey
+from ..crypto import PubKey, pubkey_to_proto
 from ..crypto.merkle import hash_from_byte_slices
 from ..libs import protoenc as pe
 from .keys import MAX_TOTAL_VOTING_POWER, PRIORITY_WINDOW_SIZE_FACTOR
@@ -22,6 +22,16 @@ from .keys import MAX_TOTAL_VOTING_POWER, PRIORITY_WINDOW_SIZE_FACTOR
 # raise at decode, never allocate (tmtlint wire-bounds). Real
 # committees are ≤ a few hundred validators.
 MAX_WIRE_VALIDATORS = 1 << 16
+
+
+_varint = pe.varint
+_uvarint = pe.uvarint
+
+# tags of the messages a validator set repeats once per validator, made once
+_VAL_PUB_KEY = pe.field_tag(1, "message")
+_VAL_POWER = pe.field_tag(2, "varint")
+_VAL_PRIORITY = pe.field_tag(3, "sfixed64")
+_SET_VALIDATOR = pe.field_tag(1, "message")
 
 
 def _div_trunc(a: int, b: int) -> int:
@@ -49,15 +59,18 @@ class Validator:
         (reference types/validator.go Bytes(): SimpleValidator{PubKey,
         VotingPower}) — byte-exact with the reference; frozen against its
         MBT vectors in tests/test_light_mbt.py."""
-        from ..crypto import pubkey_to_proto
-
-        out = pe.message_field(1, pubkey_to_proto(self.pub_key))
-        out += pe.varint_field(2, self.voting_power)
+        pk = pubkey_to_proto(self.pub_key)
+        out = _VAL_PUB_KEY + _uvarint(len(pk)) + pk
+        power = self.voting_power
+        if power:
+            out += _VAL_POWER + _varint(power)
         return out
 
     def encode(self) -> bytes:
         out = self.simple_encode()
-        out += pe.sfixed64_field(3, self.proposer_priority)
+        priority = self.proposer_priority
+        if priority:
+            out += _VAL_PRIORITY + pe.sfixed64(priority)
         return out
 
     @classmethod
@@ -242,12 +255,14 @@ class ValidatorSet:
         return self._hash
 
     def encode(self) -> bytes:
-        out = b""
+        parts = []
+        add = parts.append
         for v in self.validators:
-            out += pe.message_field(1, v.encode())
+            e = v.encode()
+            add(_SET_VALIDATOR + _uvarint(len(e)) + e)
         if self._proposer is not None:
-            out += pe.bytes_field(2, self._proposer.address)
-        return out
+            add(pe.bytes_field(2, self._proposer.address))
+        return b"".join(parts)
 
     @classmethod
     def decode(cls, data: bytes) -> "ValidatorSet":

@@ -693,6 +693,45 @@ def test_renumbered_field_fails_with_old_and_new_numbers():
     assert "encode_ping" in fs[0].message
 
 
+FOLDED_PING = """
+    from ..libs import protoenc as pe
+
+    T_PING = 1
+    MAX_ITEMS = 64
+    PROTO1_CHANNEL = 0x70
+    _SEQ = pe.field_tag(1, "varint")
+    _PAYLOAD = pe.field_tag(2, "bytes")
+
+    def encode_ping(seq, payload):
+        body = _SEQ + pe.varint(seq) + _PAYLOAD + pe.uvarint(len(payload)) + payload
+        return pe.message_field(T_PING, body)
+    """
+
+
+def test_a_tag_made_once_locks_like_the_helper_call():
+    """`NAME = pe.field_tag(N, kind)` at module level, loaded in an
+    encoder, is that encoder's field N — the same lockfile entry as the
+    helper call it folds, and a renumbered constant is the same finding."""
+    lock = wire_lock(WIRE_TREE)
+    locked = lock["files"]["tendermint_tpu/proto1/messages.py"]["encoders"]
+    assert locked["encode_ping"] == ["1:varint", "2:bytes", "T_PING=1:message"]
+    folded = {"tendermint_tpu/proto1/messages.py": FOLDED_PING}
+    got = wire_lock(dedent_tree(folded))["files"]["tendermint_tpu/proto1/messages.py"]
+    assert got["encoders"] == locked  # nothing at "<module>", nothing lost
+    assert [f for f in run_wire(folded, lock) if "encode_ping" in f.message] == []
+
+    renumbered = {"tendermint_tpu/proto1/messages.py": FOLDED_PING.replace(
+        'pe.field_tag(1, "varint")', 'pe.field_tag(6, "varint")')}
+    fs = [f for f in run_wire(renumbered, lock) if "encode_ping" in f.message]
+    assert len(fs) == 1
+    assert "1:varint" in fs[0].message and "6:varint" in fs[0].message
+
+    retyped = {"tendermint_tpu/proto1/messages.py": FOLDED_PING.replace(
+        'pe.field_tag(2, "bytes")', 'pe.field_tag(2, "message")')}
+    fs = [f for f in run_wire(retyped, lock) if "encode_ping" in f.message]
+    assert len(fs) == 1 and "2:bytes" in fs[0].message and "2:message" in fs[0].message
+
+
 def test_widened_wire_type_fails():
     lock = wire_lock(WIRE_TREE)
     mutated = {

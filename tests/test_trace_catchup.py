@@ -279,7 +279,10 @@ class TestBlockSyncTree:
             assert sum(1 for x in mine if _key(x) == k) == 1, k
         collect = next(x for x in mine if _key(x) == "validation.collect")
         needed = N_VALS * 2 // 3 + 1  # equal powers: the quorum's early cut-off
-        assert collect["attrs"] == {"commits": n, "sigs": n * needed}
+        # one sign-bytes template a commit, applied once per signature
+        assert collect["attrs"] == {
+            "commits": n, "sigs": n * needed, "templates": n,
+        }
         assert root["attrs"]["sigs"] == n * N_VALS
         assert next(x for x in mine if _key(x) == "validation.verify")["attrs"]["via"] == "hub"
         # range = build + verify + sum(apply) to within its own self time
