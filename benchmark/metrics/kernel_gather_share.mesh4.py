@@ -1,0 +1,16 @@
+"""kernel_gather_share.mesh4
+
+Device time under the named scope `gather` (the one all-gather of the
+partial points and their fold), over jit__kernel_eq_sharded's, first chip.
+"""
+
+from benchmark import mesh_readers
+
+LAYER = "kernels"
+UNIT = "%"
+SOURCE = "device_trace"
+MOVES = "light_headers_per_s"
+
+
+def read(r):
+    return mesh_readers.phase_share(r, "gather")

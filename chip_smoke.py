@@ -495,6 +495,12 @@ def phase_mesh(seed: int, n_chips: int) -> dict:
     )
     say(f"mesh: selector picked devices {[d.id for d in sel.devices]}, "
         f"bucket {sel.bucket}")
+    # one plan a padded shape: a tail that pads to the chunk's rung takes the
+    # chunk's plan, and under the measured gate a dispatch stays on one chip
+    tail = tpuv._select_kernels(tpuv._MAX_BUCKET // 2 + 1, 1)
+    small = tpuv._select_kernels(tpuv._SHARD_MIN_ROWS // 2, 1)
+    assert (tail.bucket, tail.devices) == (sel.bucket, sel.devices), (tail.bucket, tail.devices)
+    assert small.devices is None, f"a batch under the gate was sharded: {small.devices}"
 
     # where the sharded kernel's result actually lives: _shard_fill is
     # arithmetic, so ask the output array itself

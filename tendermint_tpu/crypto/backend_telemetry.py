@@ -180,11 +180,15 @@ def record_degrade(from_n: int, to_n: int, reason: str) -> None:
 
 def record_shard_dispatch(device_ids, shard_fill) -> None:
     """One sharded dispatch landed: per-device real-signature counts
-    (padding rows excluded) keyed by device id."""
+    (padding rows excluded) keyed by device id — into SHARD_SIGS, and
+    into the flight recorder as one `tpu.shard.<id>` [n] event a device,
+    so a reader of a window sees which chip carried what inside it."""
     for dev_id, n in zip(device_ids, shard_fill):
         key = str(dev_id)
         SHARD_SIGS[key] = SHARD_SIGS.get(key, 0.0) + float(n)
         SHARD_DISPATCHES[key] = SHARD_DISPATCHES.get(key, 0.0) + 1.0
+        # tmtlint: allow[span-per-item] -- per device of a mesh, once a chunk
+        trace.emit("tpu", f"shard.{key}", n=int(n))
 
 
 def record_route(route: str, n_sigs: int) -> None:

@@ -455,10 +455,10 @@ def warmup(
     bad batches)."""
     g = groups or 1
     n = max(bucket or _MIN_BUCKET, _bucket(g))  # ≥1 signature per key
-    # warm the kernels production will SELECT for this size — on a
-    # multi-device host a big bucket routes to the sharded kernels, and
-    # warming the single-device jit would leave the real first batch to
-    # compile inline anyway
+    # warm the plan dispatch SELECTS for every batch that pads to this
+    # rung (_plan_shape: one plan per padded shape) — on a multi-device
+    # host a big rung is the sharded programs', and warming the
+    # single-device jit would leave the real first batch to compile inline
     sel = _select_kernels(n, 1)
     # distinct dummy keys pin the unique-key count; they need not
     # decompress (shape is what compiles), but must be format-valid
@@ -485,13 +485,19 @@ def make_sharded_kernel(mesh, axis: str = "data"):
     from jax.sharding import PartitionSpec as P
 
     _ensure_compile_cache()
+
+    # a name of its own: a device trace shows `jit__kernel_sharded`
+    # beside the single-device `jit__kernel`
+    def _kernel_sharded(a_bytes, r_bytes, s_digits, h_digits, s_valid):
+        return _kernel(a_bytes, r_bytes, s_digits, h_digits, s_valid)
+
     return jax.jit(
         # check_vma=False: the scalar-mult scan seeds its carry with
         # mesh-invariant constants and makes it row-varying in the body,
         # and the Pallas formulations carry no varying-axes annotation
         shard_map(
-            _kernel, mesh=mesh, in_specs=(P(axis),) * 5, out_specs=P(axis),
-            check_vma=False,
+            _kernel_sharded, mesh=mesh, in_specs=(P(axis),) * 5,
+            out_specs=P(axis), check_vma=False,
         )
     )
 
@@ -511,6 +517,28 @@ def _reduce_partials(partial_pts):
     for k in range(1, n_dev):
         total = curve.point_add(total, Point(*(c[k] for c in partial_pts)))
     return total
+
+
+def _sigs_partial(r_bytes, r_digits, s_valid):
+    """The R-side of the batch equation over the rows given: the point
+    −Σ zᵢ·Rᵢ stacked as (4, 32) limbs, and the rows that entered it
+    (decompressed and well-formed). On a mesh each device calls this on
+    its own shard; the partials of all shards, folded
+    (`_reduce_partials`), are the single-device sum over all rows."""
+    import jax
+    import jax.numpy as jnp
+
+    from . import curve, msm
+
+    r_bytes = r_bytes.astype(jnp.int32)
+    r_digits = r_digits.astype(jnp.int32)
+    R, r_ok = curve.decompress(r_bytes)
+    r_use = r_ok & s_valid
+    Rm = curve.point_select(
+        r_use, curve.point_neg(R), curve.identity((r_bytes.shape[0],))
+    )
+    with jax.named_scope("msm_sigs"):
+        return jnp.stack(list(msm.msm(Rm, r_digits))), r_use
 
 
 def make_sharded_kernel_eq(mesh, axis: str = "data"):
@@ -546,36 +574,38 @@ def make_sharded_kernel_eq(mesh, axis: str = "data"):
 
     _ensure_compile_cache()
 
-    def local(ua_bytes, r_bytes, ga_digits, r_digits, zs_digits, s_valid, gidx):
-        # this device's signature shard -> one partial point
-        r_bytes = r_bytes.astype(jnp.int32)
-        r_digits = r_digits.astype(jnp.int32)
-        R, r_ok = curve.decompress(r_bytes)
-        n = r_bytes.shape[0]
-        r_use = r_ok & s_valid
-        Rm = curve.point_select(r_use, curve.point_neg(R), curve.identity((n,)))
-        with jax.named_scope("msm_sigs"):
-            part = jnp.stack(list(msm.msm(Rm, r_digits)))  # (4, 32)
-        parts = jax.lax.all_gather(part, axis)  # (n_dev, 4, 32) everywhere
-        total = _reduce_partials(Point(*(parts[:, i] for i in range(4))))
-        # replicated epilogue: unique-key decompression + grouped A MSM
-        ua_bytes = ua_bytes.astype(jnp.int32)
-        ga_digits = ga_digits.astype(jnp.int32)
-        zs_digits = zs_digits.astype(jnp.int32)
-        g = ua_bytes.shape[0]
-        A, a_ok = curve.decompress(ua_bytes)
-        Am = curve.point_select(a_ok, curve.point_neg(A), curve.identity((g,)))
-        bpt = curve.base_point(())
-        ga = Point(
-            *(jnp.concatenate([c, b[None]], axis=0) for c, b in zip(Am, bpt))
-        )
-        gd = jnp.concatenate([ga_digits, zs_digits], axis=1)
-        with jax.named_scope("msm_keys"):
-            keys_sum = msm.msm(ga, gd)
-        with jax.named_scope("finish"):
-            acc = curve.point_add(total, keys_sum)
-            eq_ok = curve.is_identity(curve.mul_by_cofactor(acc))
-        ok_bitmap = jnp.take(a_ok, gidx) & r_use
+    # the name the program carries in a device trace
+    # (`jit__kernel_eq_sharded`), and three phases by named scope: `shard`
+    # (this chip's rows), `gather` (the one collective and the fold of the
+    # partials), `epilogue` (what every chip repeats). Metadata only.
+    def _kernel_eq_sharded(
+        ua_bytes, r_bytes, ga_digits, r_digits, zs_digits, s_valid, gidx
+    ):
+        with jax.named_scope("shard"):
+            # this device's signature shard -> one partial point (4, 32)
+            part, r_use = _sigs_partial(r_bytes, r_digits, s_valid)
+        with jax.named_scope("gather"):
+            parts = jax.lax.all_gather(part, axis)  # (n_dev, 4, 32) everywhere
+            total = _reduce_partials(Point(*(parts[:, i] for i in range(4))))
+        with jax.named_scope("epilogue"):
+            # replicated: unique-key decompression + grouped A MSM
+            ua_bytes = ua_bytes.astype(jnp.int32)
+            ga_digits = ga_digits.astype(jnp.int32)
+            zs_digits = zs_digits.astype(jnp.int32)
+            g = ua_bytes.shape[0]
+            A, a_ok = curve.decompress(ua_bytes)
+            Am = curve.point_select(a_ok, curve.point_neg(A), curve.identity((g,)))
+            bpt = curve.base_point(())
+            ga = Point(
+                *(jnp.concatenate([c, b[None]], axis=0) for c, b in zip(Am, bpt))
+            )
+            gd = jnp.concatenate([ga_digits, zs_digits], axis=1)
+            with jax.named_scope("msm_keys"):
+                keys_sum = msm.msm(ga, gd)
+            with jax.named_scope("finish"):
+                acc = curve.point_add(total, keys_sum)
+                eq_ok = curve.is_identity(curve.mul_by_cofactor(acc))
+            ok_bitmap = jnp.take(a_ok, gidx) & r_use
         return ok_bitmap, eq_ok
 
     rep, rows = P(), P(axis)
@@ -585,7 +615,7 @@ def make_sharded_kernel_eq(mesh, axis: str = "data"):
     # computes it from the gathered partials and replicated operands)
     return jax.jit(
         shard_map(
-            local,
+            _kernel_eq_sharded,
             mesh=mesh,
             in_specs=(rep, rows, rep, P(None, axis), rep, rows, rows),
             out_specs=(rows, rep),
@@ -814,23 +844,39 @@ class _Selection:
         self.devices = devices
 
 
+#: Padded rows from which a dispatch is sharded over the mesh. Timed on a
+#: v5e 2x2 host (PERF.md §6, PR 27), wall ms a dispatch, one chip / four:
+#: 256 rows 14.5 / 17.1, 512: 15.0 / 17.3, 2,048: 17.1 / 18.0, 8,192:
+#: 24.0 / 20.0. Under the gate the kernel is the MSMs' serial parts (fold,
+#: buckets), which chips do not divide, and the four-way transfer and the
+#: collective cost more than the rows they spread.
+_SHARD_MIN_ROWS = 8192
+
+
+def _plan_shape(n: int, pad_multiple: int, n_dev: int) -> tuple[bool, int, int]:
+    """(sharded, bucket, pad multiple) for an n-entry chunk on `n_dev`
+    active devices — a function of the PADDED shape alone: every raw
+    count that pads to one rung of the ladder gets one plan, and that
+    rung itself (what `warmup` is given) gets the same one. So a shape
+    start-up warmed is a shape dispatch selects, and the reverse."""
+    rung = _bucket(n)
+    sharded = n_dev > 1 and (
+        os.environ.get("TMTPU_FORCE_SHARDED") == "1" or rung >= _SHARD_MIN_ROWS
+    )
+    mult = pad_multiple * n_dev // _gcd(pad_multiple, n_dev) if sharded else pad_multiple
+    return sharded, _bucket(rung, mult), mult
+
+
 def _select_kernels(n: int, pad_multiple: int) -> _Selection:
-    """Dispatch plan for an n-entry chunk: sharded over the active mesh
-    when the batch is big enough that every shard still fills a floor
-    bucket, single-device otherwise."""
+    """THE dispatch plan for an n-entry chunk, for `warmup` and
+    `_dispatch_and_collect` alike (and so for the cut-off measurement,
+    which goes through dispatch): `_plan_shape` on the active mesh."""
     devices = _shard_devices()
-    n_dev = len(devices)
-    use_sharded = n_dev > 1 and (
-        os.environ.get("TMTPU_FORCE_SHARDED") == "1" or n >= _MIN_BUCKET * n_dev
-    )
-    if use_sharded:
-        mult = pad_multiple * n_dev // _gcd(pad_multiple, n_dev)
+    sharded, bucket, mult = _plan_shape(n, pad_multiple, len(devices))
+    if sharded:
         kernel_eq, kernel_sig = _get_sharded(devices)
-        return _Selection(kernel_eq, kernel_sig, _bucket(n, mult), mult, devices)
-    return _Selection(
-        _get_kernel_eq(), _get_kernel(), _bucket(n, pad_multiple),
-        pad_multiple, None,
-    )
+        return _Selection(kernel_eq, kernel_sig, bucket, mult, devices)
+    return _Selection(_get_kernel_eq(), _get_kernel(), bucket, mult, None)
 
 
 def _is_warm_bucket(m: int, multiple: int = 1) -> bool:
@@ -884,10 +930,10 @@ def verify_resolved(
     the rare bad batch).
 
     Multi-device: when more than one accelerator is visible and the batch
-    is large enough that every shard still fills a floor bucket, the MSM
-    runs sharded over a 1-D mesh (one partial point gathered per device —
-    the only collective); padding rounds the batch up to a mesh-divisible
-    bucket. TMTPU_FORCE_SHARDED=1 drops the size gate (tests);
+    pads to at least _SHARD_MIN_ROWS rows, the MSM runs sharded over a
+    1-D mesh (one partial point gathered per device — the only
+    collective); padding rounds the batch up to a mesh-divisible bucket.
+    TMTPU_FORCE_SHARDED=1 drops the size gate (tests);
     TMTPU_NO_SHARDED=1 disables sharding. One interface regardless of
     topology — the reference's crypto/crypto.go:46-54 contract."""
     return _dispatch_and_collect(
@@ -918,7 +964,9 @@ def _dispatch_and_collect(n: int, get_entries, pad_multiple: int) -> np.ndarray:
     mesh cannot make progress at all."""
     if n == 0:
         return np.zeros(0, bool)
-    sel = _select_kernels(_MAX_BUCKET if n > _MAX_BUCKET else n, pad_multiple)
+    sel = _select_kernels(min(n, _MAX_BUCKET), pad_multiple)
+    ids = [d.id for d in sel.devices] if sel.devices is not None else None
+    n_dev = len(ids) if ids else 1
     # hot-path shape discipline (see _is_warm_bucket): a non-bucket pad
     # here would compile a cold one-off XLA shape inline
     assert _is_warm_bucket(sel.bucket, sel.multiple), (
@@ -933,17 +981,18 @@ def _dispatch_and_collect(n: int, get_entries, pad_multiple: int) -> np.ndarray:
             # the host's share of a dispatch, and the jitted call itself
             # (transfer + enqueue: it returns before the device is done)
             # tmtlint: allow[span-per-item] -- per chunk of <= _MAX_BUCKET signatures
-            with trace.span("tpu", "prep", n=len(chunk), bucket=sel.bucket) as sp:
+            with trace.span(
+                "tpu", "prep", n=len(chunk), bucket=sel.bucket, devices=n_dev
+            ) as sp:
                 args = prepare_batch_eq(chunk, pad_to=sel.bucket)
                 sp.set(groups=int(args[0].shape[0]))
             # tmtlint: allow[span-per-item] -- per chunk
-            with trace.span("tpu", "dispatch", bucket=sel.bucket):
+            with trace.span("tpu", "dispatch", bucket=sel.bucket, devices=n_dev):
                 res = sel.kernel_eq(*args)
         except Exception as e:  # noqa: BLE001 — settled at collect time
             res = e
         in_flight.append((chunk, res))
     outs = []
-    ids = [d.id for d in sel.devices] if sel.devices is not None else None
     shards_total = [0] * len(ids) if ids else None
     retried = False
     for chunk, res in in_flight:
@@ -954,7 +1003,7 @@ def _dispatch_and_collect(n: int, get_entries, pad_multiple: int) -> np.ndarray:
             # the host blocked on the device: reading eq_ok waits for
             # the chunk's program to end
             # tmtlint: allow[span-per-item] -- per chunk
-            with trace.span("tpu", "collect", bucket=sel.bucket) as sp:
+            with trace.span("tpu", "collect", bucket=sel.bucket, devices=n_dev) as sp:
                 eq_ok = bool(eq_ok)
                 sp.set(eq_ok=eq_ok)
                 if eq_ok:

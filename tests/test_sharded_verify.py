@@ -53,19 +53,33 @@ def test_mesh_is_multi_device():
 
 
 def test_sharded_selected_for_large_batches(monkeypatch):
-    """The production selector picks the sharded path for range-batch
-    sized workloads without any env override."""
+    """The production selector picks the sharded path from the measured
+    gate (`_SHARD_MIN_ROWS` padded rows) without any env override, for
+    every raw count that pads to the gate's rung — and the single-device
+    path one rung below it. The batch itself runs with the gate scaled
+    to 512 rows (8,192 rows on the CPU's virtual devices is minutes)."""
     from tendermint_tpu.crypto.tpu import verify as V
 
     monkeypatch.delenv("TMTPU_FORCE_SHARDED", raising=False)
     monkeypatch.delenv("TMTPU_NO_SHARDED", raising=False)
     n_dev = V._shard_device_count()
     assert n_dev == 8
-    items = _signed_items(V._MIN_BUCKET * n_dev, b"big")
+    gate = V._SHARD_MIN_ROWS
+    assert V._plan_shape(gate // 2, 1, n_dev) == (False, gate // 2, 1)
+    assert V._plan_shape(gate // 2 + 1, 1, n_dev) == (True, gate, n_dev)
+    assert V._select_kernels(gate // 2, 1).devices is None
+    assert len(V._select_kernels(gate // 2 + 1, 1).devices) == n_dev
+
+    monkeypatch.setattr(V, "_SHARD_MIN_ROWS", 512)
+    # a raw count UNDER the gate that pads to its rung: sharded too
+    items = _signed_items(257, b"big")
     out = V.verify_batch_eq(items)
     assert out.all() and len(out) == len(items)
     # the production cache was used, keyed by the exact device set
     assert any(len(key) == n_dev for key in V._sharded_kernels)
+    info = V.last_dispatch_info()
+    assert info is not None and sum(info["shards"]) == len(items)
+    assert V.verify_batch_eq(items[:256]).all() and V.last_dispatch_info() is None
 
 
 def test_sharded_all_valid_non_divisible(force_sharded):
