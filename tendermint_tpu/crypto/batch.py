@@ -11,6 +11,7 @@ from __future__ import annotations
 import logging
 import os
 import threading
+from operator import attrgetter, itemgetter
 
 from ..libs import trace
 from ..libs.metrics import record_resilience
@@ -27,6 +28,10 @@ _EDWARDS = (ED25519, SR25519)
 #: AdaptiveBatchVerifier partitions by scheme so mixed validator sets
 #: still funnel through one verifier object
 _BATCHABLE = (ED25519, SR25519, BLS12381)
+#: the key of a (pub_key, msg, sig) item and a key's scheme, for C-level
+#: passes (`map`) over a handed-over list
+_KEY_OF = itemgetter(0)
+_SCHEME_OF = attrgetter("TYPE")
 
 logger = logging.getLogger("crypto.batch")
 
@@ -361,6 +366,9 @@ class AdaptiveBatchVerifier(BatchVerifier):
 
     def __init__(self):
         self._items: list[tuple[PubKey, bytes, bytes]] = []
+        #: key types seen so far: says whether there is anything to
+        #: partition without a pass over the items
+        self._schemes: set[str] = set()
         #: where the last verify() ran ("tpu"/"cpu"/"cpu-fallback", or
         #: "mixed" when scheme partitions took different routes) —
         #: per-instance, unlike the process-global LAST_ROUTE, so
@@ -372,48 +380,69 @@ class AdaptiveBatchVerifier(BatchVerifier):
         self.last_dispatch = None
 
     def add(self, pub_key: PubKey, msg: bytes, sig: bytes) -> None:
-        if pub_key.TYPE not in _BATCHABLE:
+        scheme = pub_key.TYPE
+        if scheme not in _BATCHABLE:
+            raise ValueError(
+                f"adaptive batch verifier supports {_BATCHABLE}, got {scheme!r}"
+            )
+        self._schemes.add(scheme)
+        self._items.append((pub_key, msg, sig))
+
+    def add_many(self, items: list[tuple[PubKey, bytes, bytes]]) -> None:
+        """Bulk hand-over beside the reference's `add`: a whole list of
+        (pub_key, msg, sig) in one step — one C-level pass for the key
+        types, one list extend — instead of a call a signature. Same
+        refusal as `add`, before anything is kept."""
+        schemes = set(map(_SCHEME_OF, map(_KEY_OF, items)))
+        if not schemes.issubset(_BATCHABLE):
             raise ValueError(
                 f"adaptive batch verifier supports {_BATCHABLE}, got "
-                f"{pub_key.TYPE!r}"
+                f"{sorted(schemes.difference(_BATCHABLE))!r}"
             )
-        self._items.append((pub_key, msg, sig))
+        self._schemes |= schemes
+        self._items.extend(items)
 
     def verify(self) -> tuple[bool, list[bool]]:
         global LAST_ROUTE
         from . import backend_telemetry as bt
 
         items = self._items
+        self.last_dispatch = None
+        if BLS12381 not in self._schemes:
+            # every key is an Edwards key (every commit of an ed25519
+            # chain): nothing to partition — the list goes on as it is,
+            # and the route's verdicts are the answer
+            results, route = [], "cpu"
+            if items:
+                results, route = self._verify_edwards(items, partitions=1)
+                bt.record_route(route, len(items))
+            LAST_ROUTE = self.last_route = route
+            return all(results) and bool(results), results
         results = [False] * len(items)
         edwards = [i for i, it in enumerate(items) if it[0].TYPE in _EDWARDS]
         bls = [i for i, it in enumerate(items) if it[0].TYPE == BLS12381]
-        routes = []
-        self.last_dispatch = None
-        if bls:
-            bres, broute = self._verify_bls([items[i] for i in bls])
-            for i, ok in zip(bls, bres):
-                results[i] = ok
-            routes.append(broute)
-            bt.record_route(broute, len(bls))
+        bres, route = self._verify_bls([items[i] for i in bls])
+        for i, ok in zip(bls, bres):
+            results[i] = ok
+        bt.record_route(route, len(bls))
         if edwards:
-            eres, eroute = self._verify_edwards([items[i] for i in edwards])
+            eres, eroute = self._verify_edwards([items[i] for i in edwards], partitions=2)
             for i, ok in zip(edwards, eres):
                 results[i] = ok
-            routes.append(eroute)
             bt.record_route(eroute, len(edwards))
-        if not routes:
-            route = "cpu"
-        elif len(set(routes)) == 1:
-            route = routes[0]
-        else:
-            route = "mixed"
+            if eroute != route:
+                route = "mixed"
         LAST_ROUTE = self.last_route = route
         return all(results) and bool(results), results
 
-    def _verify_edwards(self, items) -> tuple[list[bool], str]:
+    def _verify_edwards(self, items, partitions: int) -> tuple[list[bool], str]:
         """The ed25519/sr25519 partition: shared-MSM TPU kernel when the
-        batch clears the measured cutoff, host loop otherwise."""
-        with trace.span("batch", "route", n=len(items), cutoff=MIN_TPU_BATCH) as sp:
+        batch clears the measured cutoff, host loop otherwise.
+        `partitions` goes on the span: 1 = the verifier's list went
+        through whole, 2 = it was split by scheme beside a BLS part."""
+        with trace.span(
+            "batch", "route", n=len(items), cutoff=MIN_TPU_BATCH, partitions=partitions
+        ) as sp:
             results, route, why = self._route_edwards(items)
             sp.set(route=route, **({"why": why} if why else {}))
         return results, route
@@ -495,13 +524,16 @@ class AdaptiveBatchVerifier(BatchVerifier):
         return target.verify()
 
     def _run_device(self, items) -> tuple[bool, list[bool]]:
-        """`_run` on the device verifier, which resolves as it adds
-        (SHA-512 per signature): one span around the loop, never one a
-        signature."""
+        """`_run` on the device verifier: the list is handed over in one
+        step where the verifier takes one (`add_many`); resolving — the
+        SHA-512 per signature — is the verifier's own, chunk by chunk
+        inside its dispatch loop (`tpu.resolve` spans are recorded
+        there)."""
         target = self._make_tpu_verifier()
-        with trace.span("tpu", "resolve", n=len(items)):
-            for pk, msg, sig in items:
-                target.add(pk, msg, sig)
+        add_many = getattr(target, "add_many", None)
+        if add_many is None:
+            return self._run(target, items)
+        add_many(items)
         return target.verify()
 
 

@@ -6,7 +6,10 @@ types/validation.go:152 — sign-bytes stay host-side, group math is the
 kernel):
 
   host:   parse signatures, canonical-range-check s < L, hash
-          k = SHA-512(R ‖ A ‖ msg) mod L, unpack scalars to radix-16 digits
+          k = SHA-512(R ‖ A ‖ msg) (one Python pass a signature,
+          `resolve_rows`), fold the batch equation's scalars and unpack
+          them to digits (`prepare_batch_eq`, column-wise) — a chunk at a
+          time, inside the dispatch loop
   device: decompress A and R, joint double-scalar mult s·B - k·A,
           cofactored identity check  [8](s·B - k·A - R) == O
   host:   per-signature validity bitmap (the `[]bool` of the reference's
@@ -25,6 +28,7 @@ import os
 import threading
 from functools import partial
 from math import gcd as _gcd
+from typing import NamedTuple
 
 import numpy as np
 
@@ -624,32 +628,66 @@ def make_sharded_kernel_eq(mesh, axis: str = "data"):
     )
 
 
-class ResolvedSig:
+class ResolvedSig(NamedTuple):
     """A signature reduced to the Edwards-form check
     [8](s·B − k·A − R) == O — the common shape both key types share.
-    ed25519: k = SHA-512(R ‖ A ‖ msg) mod L; sr25519: k is the Merlin
-    transcript challenge and A/R are the ristretto coset representatives
-    re-encoded in ed25519 compressed form."""
+    ed25519: k = SHA-512(R ‖ A ‖ msg), as `resolve_rows` leaves it: the
+    512-bit integer, NOT yet reduced mod L (the batch equation reduces
+    once per key group, the per-signature kernel's prep when it runs);
+    sr25519: k is the Merlin transcript challenge and A/R are the
+    ristretto coset representatives re-encoded in ed25519 compressed
+    form. The hot loop builds plain `(a, r, s, k)` tuples; this is the
+    name tests and the sr25519 path construct them by."""
 
-    __slots__ = ("a", "r", "s", "k")
+    a: bytes
+    r: bytes
+    s: int
+    k: int
 
-    def __init__(self, a: bytes, r: bytes, s: int, k: int):
-        self.a = a
-        self.r = r
-        self.s = s
-        self.k = k
+
+def resolve_rows(items, host_rows: list | None = None, base: int = 0) -> list:
+    """The hashing half of the host's work on a signature (the folding
+    half is `prepare_batch_eq`'s loop; nothing else touches a signature
+    between a caller's `add` and the kernel's operands): (key, msg, sig)
+    triples -> rows `(a, r, s, k)`, None for a malformed one (wrong
+    sizes, non-canonical s ≥ L) — an inert row whose verdict is False.
+    Per ed25519 signature only what no array operation can do: one
+    SHA-512, two `int.from_bytes`, the s < L test; no reduction of k
+    (see ResolvedSig), no object, no call but the hash's. A key is a
+    PubKey object (dispatch on TYPE: sr25519 goes through `resolve`) or —
+    `verify_batch_eq`'s raw triples — the 32 key bytes of an ed25519 key.
+    Any other key type is None here, and its row index (`base` + position)
+    is appended to `host_rows` for the caller to verify on the host."""
+    rows: list = []
+    add = rows.append
+    sha512, from_bytes = hashlib.sha512, int.from_bytes
+    for pk, msg, sig in items:
+        kind = getattr(pk, "TYPE", None)
+        if kind == "ed25519":
+            pub = pk.bytes()
+        elif kind is None:
+            pub = pk
+        else:
+            if kind != "sr25519" and host_rows is not None:
+                host_rows.append(base + len(rows))
+            add(resolve(pk, msg, sig))
+            continue
+        if len(sig) != 64 or len(pub) != 32:
+            add(None)
+            continue
+        s = from_bytes(sig[32:], "little")
+        if s >= L:
+            add(None)
+            continue
+        r = sig[:32]
+        add((pub, r, s, from_bytes(sha512(r + pub + msg).digest(), "little")))
+    return rows
 
 
 def resolve_ed25519(pub: bytes, msg: bytes, sig: bytes) -> ResolvedSig | None:
     """None = malformed (wrong sizes or non-canonical s ≥ L)."""
-    if len(pub) != 32 or len(sig) != 64:
-        return None
-    r, s = sig[:32], sig[32:]
-    s_int = int.from_bytes(s, "little")
-    if s_int >= L:
-        return None
-    k = int.from_bytes(hashlib.sha512(r + pub + msg).digest(), "little") % L
-    return ResolvedSig(pub, r, s_int, k)
+    row = resolve_rows(((pub, msg, sig),))[0]
+    return None if row is None else ResolvedSig(*row)
 
 
 def resolve_sr25519(pub: bytes, msg: bytes, sig: bytes) -> ResolvedSig | None:
@@ -668,7 +706,9 @@ def resolve_sr25519(pub: bytes, msg: bytes, sig: bytes) -> ResolvedSig | None:
 
 
 def resolve(pub_key, msg: bytes, sig: bytes) -> ResolvedSig | None:
-    """Dispatch on the PubKey object's TYPE."""
+    """Dispatch on the PubKey object's TYPE, one signature: what
+    `resolve_rows` calls for every key that is not ed25519 (and what a
+    caller with a single triple may)."""
     if pub_key.TYPE == "ed25519":
         return resolve_ed25519(pub_key.bytes(), msg, sig)
     if pub_key.TYPE == "sr25519":
@@ -681,30 +721,37 @@ def prepare_batch(items: list[tuple[bytes, bytes, bytes]], pad_to: int = 0):
     msg, sig64) ed25519 triples; pad_to pads to the bucket shape (inert
     rows). Returns numpy arrays
     (a_bytes, r_bytes, s_digits, h_digits, s_valid)."""
-    return prepare_resolved(
-        [resolve_ed25519(pub, msg, sig) for pub, msg, sig in items],
-        pad_to=pad_to,
-    )
+    return prepare_resolved(resolve_rows(items), pad_to=pad_to)
+
+
+def _rows_u8(chunks: list, n: int, m: int, width: int) -> np.ndarray:
+    """n byte strings of `width` bytes -> an (m, width) uint8 array, the
+    rows past n zero: ONE frombuffer over the joined bytes."""
+    out = np.zeros((m, width), np.uint8)
+    if n:
+        out[:n] = np.frombuffer(b"".join(chunks), np.uint8).reshape(n, width)
+    return out
 
 
 def prepare_resolved(entries: list[ResolvedSig | None], pad_to: int = 0):
-    """ResolvedSig list -> per-signature kernel inputs (None entries and
-    padding rows stay invalid)."""
+    """Resolved rows -> per-signature kernel inputs (None entries and
+    padding rows stay invalid). The rare path (a failed batch equation,
+    a warm-up): k is reduced mod L HERE, per signature."""
     n = len(entries)
     m = max(pad_to, n)
-    a_np = np.zeros((m, 32), np.uint8)
-    r_np = np.zeros((m, 32), np.uint8)
-    s_np = np.zeros((m, 32), np.uint8)
-    h_np = np.zeros((m, 32), np.uint8)
+    zero = bytes(32)
+    a_col, r_col, s_col, h_col = [], [], [], []
     s_valid = np.zeros(m, bool)
+    s_valid[:n] = True
     for i, e in enumerate(entries):
         if e is None:
-            continue
-        s_valid[i] = True
-        a_np[i] = np.frombuffer(e.a, np.uint8)
-        r_np[i] = np.frombuffer(e.r, np.uint8)
-        s_np[i] = np.frombuffer(e.s.to_bytes(32, "little"), np.uint8)
-        h_np[i] = np.frombuffer(e.k.to_bytes(32, "little"), np.uint8)
+            s_valid[i] = False
+            e = (zero, zero, 0, 0)
+        a, r, s, k = e
+        a_col.append(a)
+        r_col.append(r)
+        s_col.append(s.to_bytes(32, "little"))
+        h_col.append((k % L).to_bytes(32, "little"))
 
     def to_digits(b: np.ndarray) -> np.ndarray:
         """(N,32) bytes -> (N,64) radix-16 little-endian digits."""
@@ -714,10 +761,10 @@ def prepare_resolved(entries: list[ResolvedSig | None], pad_to: int = 0):
         return d
 
     return (
-        a_np.astype(np.int32),
-        r_np.astype(np.int32),
-        to_digits(s_np),
-        to_digits(h_np),
+        _rows_u8(a_col, n, m, 32).astype(np.int32),
+        _rows_u8(r_col, n, m, 32).astype(np.int32),
+        to_digits(_rows_u8(s_col, n, m, 32)),
+        to_digits(_rows_u8(h_col, n, m, 32)),
         s_valid,
     )
 
@@ -737,51 +784,62 @@ def prepare_batch_eq(entries: list[ResolvedSig | None], pad_to: int = 0):
     pads the signature axis with inert rows (digits 0, s_valid False);
     the unique-key axis is padded to a group bucket. Returns (ua_bytes,
     r_bytes, ga_digits, r_digits, zs_digits, s_valid, gidx) numpy arrays
-    shaped for `_kernel_eq`."""
-    import os as _os
+    shaped for `_kernel_eq`.
 
+    Per row the loop does what no array operation can — a dict probe for
+    the key group and one bigint multiply-add each into that group's
+    Σ z·k and into Σ z·s; every array is built column-wise from joined
+    bytes. The coefficients z are the 16·n bytes of ONE os.urandom call
+    with bit 0 set (z ∈ [1, 2^128): a zero coefficient would drop the
+    signature from the equation entirely): the array the kernel gets IS
+    the randomness, and the loop's z is read from the same bytes."""
     n = len(entries)
     m = max(pad_to, n)
-    r_np = np.zeros((m, 32), np.uint8)
-    r_sc = np.zeros((m, 16), np.uint8)  # z bytes
-    s_valid = np.zeros(m, bool)
-    gidx = np.zeros(m, np.int32)
+    z_np = np.zeros((m, 16), np.uint8)
+    z_np[:n] = np.frombuffer(os.urandom(16 * n), np.uint8).reshape(n, 16)
+    z_np[:n, 0] |= 1
+    zero = bytes(32)
     group_of: dict[bytes, int] = {}
-    ua: list[bytes] = []
-    coeffs: list[int] = []  # per-group Σ z·k mod L
+    coeffs: list[int] = []  # per-group Σ z·k, reduced mod L once below
     zs = 0
-    rnd = _os.urandom(16 * n)
-    for i, e in enumerate(entries):
+    g_col, r_col, bad = [], [], []
+    lo_col, hi_col = z_np[:n].view("<u8").T.tolist()  # z's two 64-bit halves
+    for e, lo, hi in zip(entries, lo_col, hi_col):
         if e is None:
+            bad.append(len(g_col))
+            g_col.append(0)
+            r_col.append(zero)
             continue
-        gi = group_of.get(e.a)
-        if gi is None:
-            gi = group_of[e.a] = len(ua)
-            ua.append(e.a)
+        a, r, s, k = e
+        try:
+            gi = group_of[a]
+        except KeyError:
+            gi = group_of[a] = len(coeffs)
             coeffs.append(0)
-        gidx[i] = gi
-        s_valid[i] = True
-        r_np[i] = np.frombuffer(e.r, np.uint8)
-        # z ∈ [1, 2^128): |1 excludes zero (a zero coefficient would drop
-        # the signature from the equation entirely)
-        z = int.from_bytes(rnd[16 * i : 16 * i + 16], "little") | 1
-        r_sc[i] = np.frombuffer(z.to_bytes(16, "little"), np.uint8)
-        # accumulate WITHOUT reducing: one mod per group at the end beats
-        # a 384-bit modular reduction per signature
-        coeffs[gi] += z * e.k
-        zs += z * e.s
-    gb = _group_bucket(len(ua))
-    ua_np = np.zeros((gb, 32), np.uint8)
-    ga_sc = np.zeros((gb, 32), np.uint8)
-    for gi, (key, c) in enumerate(zip(ua, coeffs)):
-        ua_np[gi] = np.frombuffer(key, np.uint8)
-        ga_sc[gi] = np.frombuffer((c % L).to_bytes(32, "little"), np.uint8)
+        g_col.append(gi)
+        r_col.append(r)
+        # accumulate WITHOUT reducing — neither z·k (k itself may be the
+        # unreduced 512-bit hash) nor the sums: one mod per group at the
+        # end beats a modular reduction per signature
+        z = hi << 64 | lo
+        coeffs[gi] += z * k
+        zs += z * s
+    s_valid = np.zeros(m, bool)
+    s_valid[:n] = True
+    gidx = np.zeros(m, np.int32)
+    gidx[:n] = g_col
+    if bad:
+        s_valid[bad] = False
+        z_np[bad] = 0
+    g = len(coeffs)
+    gb = _group_bucket(g)
+    ga_sc = _rows_u8([(c % L).to_bytes(32, "little") for c in coeffs], g, gb, 32)
     zs_digits = np.frombuffer((zs % L).to_bytes(32, "little"), np.uint8).reshape(32, 1)
     return (
-        ua_np,  # uint8 throughout: the kernel casts on-device, the
-        r_np,  # host->device copy moves 4x fewer bytes
+        _rows_u8(list(group_of), g, gb, 32),  # uint8 throughout: the kernel
+        _rows_u8(r_col, n, m, 32),  # casts on-device, the copy moves 4x fewer bytes
         np.ascontiguousarray(ga_sc.T),  # (32, gb)
-        np.ascontiguousarray(r_sc.T),  # (16, m)
+        np.ascontiguousarray(z_np.T),  # (16, m)
         zs_digits,
         s_valid,
         gidx,
@@ -944,15 +1002,19 @@ def verify_resolved(
 
 
 def _dispatch_and_collect(n: int, get_entries, pad_multiple: int) -> np.ndarray:
-    """Chunked dispatch core: get_entries(i, j) materializes (resolves)
-    the entries of one chunk, CALLED AS THE LOOP RUNS — so with multiple
-    chunks, chunk k+1's host work (SHA-512 resolve + bigint prep)
-    overlaps chunk k's device execution via async dispatch. Every chunk
-    of a multi-chunk batch shares ONE compile shape (tail padded to the
-    full chunk size): stable shapes beat saving padding rows at the cost
-    of an inline XLA compile of a one-off tail bucket. Bitmaps are only
-    synced after every chunk is in flight; a failed equation falls back
-    to the per-signature kernel for that chunk alone.
+    """Chunked dispatch core: get_entries(i, j) materializes the resolved
+    rows of one ≤ _MAX_BUCKET chunk, CALLED AS THE LOOP RUNS. Every
+    caller that starts from signatures (`verify_batch_eq`, and through it
+    the verifier objects of the served path) resolves there, so chunk
+    k+1's host work (SHA-512 resolve + bigint prep) runs while chunk k
+    executes on the device (async dispatch), and nothing is resolved
+    before the first chunk is out; only `verify_resolved`'s callers hand
+    in rows resolved beforehand. Every chunk of a multi-chunk batch
+    shares ONE compile shape (tail padded to the full chunk size):
+    stable shapes beat saving padding rows at the cost of an inline XLA
+    compile of a one-off tail bucket. Bitmaps are only synced after
+    every chunk is in flight; a failed equation falls back to the
+    per-signature kernel for that chunk alone.
 
     Mesh degradation: a sharded chunk that raises (a chip died mid-MSM)
     hands the error to mesh.on_dispatch_failure, which probes every
@@ -1063,14 +1125,23 @@ def _degrade_and_retry(
 
 
 def verify_batch_eq(
-    items: list[tuple[bytes, bytes, bytes]], pad_multiple: int = 1
+    items: list, pad_multiple: int = 1, host_rows: list | None = None
 ) -> np.ndarray:
-    """(pubkey32, msg, sig64) ed25519 triples -> bool bitmap. Resolution
-    (the SHA-512 per signature) happens per chunk inside the dispatch
-    loop, so for multi-chunk batches it overlaps device execution."""
+    """(key, msg, sig) triples -> bool bitmap: THE resolve-and-prepare
+    entrance, for raw ed25519 triples (key = the 32 key bytes: the
+    cut-off probe, tools) and for the verifier objects of the served path
+    (key = a PubKey: `TPUBatchVerifier.verify`) alike. Nothing is
+    resolved up front: each ≤ _MAX_BUCKET chunk is resolved
+    (`resolve_rows`, under one `tpu.resolve` [n, chunk] span) inside the
+    dispatch loop, right before its prep and its jitted call, so chunk
+    k+1's SHA-512s and bigint sums run while chunk k is on the device —
+    and a malformed signature in a later chunk is found after the
+    earlier ones were dispatched: the bitmap is the same. `host_rows`
+    collects the rows of key types the kernel does not take (see
+    `resolve_rows`)."""
     def resolved(i: int, j: int) -> list:
-        with trace.span("tpu", "resolve", n=j - i):
-            return [resolve_ed25519(*it) for it in items[i:j]]
+        with trace.span("tpu", "resolve", n=j - i, chunk=i // _MAX_BUCKET):
+            return resolve_rows(items[i:j], host_rows, i)
 
     return _dispatch_and_collect(len(items), resolved, pad_multiple)
 
@@ -1103,25 +1174,27 @@ class TPUBatchVerifier(BatchVerifier):
     reference's interface, crypto/crypto.go:46-54). ed25519 AND sr25519
     share the kernel — both reduce to [8](s·B − k·A − R) == O on the same
     curve (see ResolvedSig). Other key types (secp256k1) degrade to host
-    verification so mixed validator sets still produce a complete bitmap."""
+    verification so mixed validator sets still produce a complete bitmap.
+
+    `add` only keeps the triple: resolving is `verify_batch_eq`'s, chunk
+    by chunk inside the dispatch loop. `add_many` is the bulk hand-over
+    beside the reference's two calls (one list extend, no call a
+    signature)."""
 
     def __init__(self):
-        self._entries: list[ResolvedSig | None] = []
-        self._host_items: list[tuple[int, PubKey, bytes, bytes]] = []
+        self._items: list[tuple[PubKey, bytes, bytes]] = []
 
     def add(self, pub_key: PubKey, msg: bytes, sig: bytes) -> None:
-        if pub_key.TYPE in ("ed25519", "sr25519"):
-            self._entries.append(resolve(pub_key, msg, sig))
-        else:
-            self._host_items.append((len(self._entries), pub_key, msg, sig))
-            self._entries.append(None)
+        self._items.append((pub_key, msg, sig))
+
+    def add_many(self, items) -> None:
+        self._items.extend(items)
 
     def verify(self) -> tuple[bool, list[bool]]:
-        results = [False] * len(self._entries)
-        if any(e is not None for e in self._entries):
-            bitmap = verify_resolved(self._entries)
-            for i, ok in enumerate(bitmap):
-                results[i] = bool(ok)
-        for i, pk, msg, sig in self._host_items:
+        items = self._items
+        host_rows: list[int] = []
+        results = verify_batch_eq(items, host_rows=host_rows).tolist()
+        for i in host_rows:
+            pk, msg, sig = items[i]
             results[i] = pk.verify_signature(msg, sig)
         return all(results) and bool(results), results

@@ -75,8 +75,7 @@ class _CommitVerifier:
                     len(self._items),
                 )
         bv = crypto_batch.create_batch_verifier(self._pub_key)
-        for pk, msg, sig in self._items:
-            bv.add(pk, msg, sig)
+        bv.add_many(self._items)
         return bv.verify()
 
 
