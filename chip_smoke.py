@@ -8,7 +8,7 @@ One process, through the entry points a node uses, at the size users run
 (BASELINE configs 1 and 3: 150 ed25519 validators, kvstore app):
 
   attach     crypto.batch's own start (tpu_verifier_available -> _probe_tpu:
-             watchdogged attach, Pallas A/B probe, floor + 8192 warmup,
+             watchdogged attach, Pallas self-test, floor + 8192 warmup,
              measured CPU/TPU cutoff). Anything but a TPU is refused.
   range      108 commits x 150 validators = 16,200 signatures (two 8192
              chunks) through types.validation.verify_commit_range, the
@@ -131,7 +131,7 @@ def attach(want_chips: int) -> dict:
     if not cb.tpu_wait_available():
         raise RuntimeError(f"device probe failed: {bt.snapshot()}")
     say(f"device available after {time.monotonic() - t0:.1f}s "
-        f"(attach + Pallas A/B + floor warm-up + cutoff; MIN_TPU_BATCH={cb.MIN_TPU_BATCH})")
+        f"(attach + Pallas self-test + floor warm-up + cutoff; MIN_TPU_BATCH={cb.MIN_TPU_BATCH})")
     # a node starts serving here while the probe thread still warms the
     # 8192 range shapes; the smoke waits for it, so that the phases' seconds
     # are not mixed with a background compile

@@ -5,8 +5,7 @@ A `Scenario` names one fault shape — steady per-link rates
 `Event` script (partitions forming and healing, a peer going gray, a
 node crashing mid-consensus and restarting) — independent of committee
 size and seed, so the SAME scenario runs as a 4-validator tier-1 smoke,
-a 50-validator sweep, and a 150-validator soak (tests/test_routernet.py)
-and as the `bench.py chaos_soak` config.
+a 50-validator sweep, and a 150-validator soak (tests/test_routernet.py).
 
 `run_scenario` drives it: build a RouterNet over real routers +
 ChaosTransport, play the event script, and watch liveness — every node

@@ -7,7 +7,7 @@ caller's bitmap alone cannot tell a healthy chip from a dead one. This
 module makes every attach-path event a first-class signal: attach
 attempts (latency + outcome), XLA compile/warmup durations per shape
 bucket, which backend served each batch, every host re-verify after a
-device error, mesh degrade-and-retry events, Pallas probe failures and
+device error, mesh degrade-and-retry events, Pallas self-test failures and
 circuit-breaker state changes all land
 
   * in the module-level stores below (folded into `/metrics` at render
@@ -32,7 +32,7 @@ record with a flight dump, not a timeout with no artifact.
 
 Writers: `crypto/batch.py` (probe — attach runs behind
 `libs/watchdog.BackendInitWatchdog` — warmup, routes, breaker,
-fallback), `crypto/tpu/verify.py` (Pallas probe, degrade-and-retry).
+fallback), `crypto/tpu/verify.py` (Pallas self-test, degrade-and-retry).
 Readers besides /metrics: `chip_smoke.py` asserts on these stores that
 the device, not a fallback, served its batches.
 """

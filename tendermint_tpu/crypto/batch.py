@@ -100,7 +100,8 @@ def _probe_tpu() -> None:
     gets the same story as one trace: `backend.probe` (root, to the
     thread's end; `available_s` is when routing could use the device)
     over `backend.attach`, `backend.warmup` [shape] — the first holds
-    `backend.pallas_ab` — and `backend.cutoff` [value]."""
+    `backend.pallas_ab`, the Pallas self-test — and `backend.cutoff`
+    [value]."""
     with trace.span("backend", "probe", root=True) as sp:
         _probe_tpu_traced(sp)
 

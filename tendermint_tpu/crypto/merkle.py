@@ -7,8 +7,8 @@ append-friendly.
 
 Tree construction is LEVEL-ORDER through the HashHub: each level of the
 tree is ONE `hash_hub.sha256_many` batch instead of O(n) recursive
-Python frames with list slicing — the hot-loop win `bench.py merkle`
-measures, and the shape the opt-in device kernel wants (a level of
+Python frames with list slicing — the hot-loop win, and the shape the
+opt-in device kernel wants (a level of
 65-byte inner nodes is one uniform bucket). The level-order pass pairs
 nodes left-to-right and PROMOTES an odd last node unhashed; that
 produces bit-identical roots and proofs to the recursive

@@ -100,22 +100,19 @@ for _i in range(LIMBS):
         _S_CONV[_i * LIMBS + _j, _i + _j] = 1.0
 
 
-# When True, `mul` routes to the Pallas VMEM-resident convolution kernel
-# (pallas_field.py) instead of the portable GEMM formulation; separately,
-# _USE_PALLAS_POW routes pow22523 to the fused VMEM exponentiation chain.
-# The two are probed independently (verify._maybe_enable_pallas): a lone
-# Pallas mul pays transposes at every kernel boundary and can LOSE to the
-# GEMM inside big fused graphs, while the pow chain amortizes one
-# boundary over 254 multiplies and ~always wins. Must be set BEFORE
-# kernels are traced.
+# The ONE formulation switch of the kernels. On: `mul` is the Pallas
+# VMEM-resident convolution (pallas_field.mul), `pow22523` the fused VMEM
+# exponentiation chain, and msm._boundary_prefixes scans its blocks in one
+# fused kernel. Off: the portable XLA formulations, which are the CPU path
+# and the tests' oracle. verify._choose_formulation sets it once, from the
+# platform, BEFORE any kernel is traced; a test substitutes through the
+# setter (AOT compile for a described TPU from a CPU host).
 _USE_PALLAS = False
-_USE_PALLAS_POW = False
 
 
-def set_pallas(on: bool, *, pow_chain: bool | None = None) -> None:
-    global _USE_PALLAS, _USE_PALLAS_POW
+def set_pallas(on: bool) -> None:
+    global _USE_PALLAS
     _USE_PALLAS = bool(on)
-    _USE_PALLAS_POW = bool(on if pow_chain is None else pow_chain)
 
 
 def mul(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
@@ -130,8 +127,7 @@ def mul(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
 
 
 def _mul_gemm(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
-    """The portable MXU GEMM formulation, reachable directly (bypassing
-    the _USE_PALLAS switch) so A/B probes can time both paths."""
+    """The portable XLA formulation: the convolution as one MXU GEMM."""
     a, b = jnp.broadcast_arrays(a, b)
     af = a.astype(jnp.float32)
     bf = b.astype(jnp.float32)
@@ -207,7 +203,7 @@ def pow22523(z: jnp.ndarray) -> jnp.ndarray:
     backends the whole 254-multiply chain runs as ONE VMEM-resident
     kernel (pallas_field.pow22523) — per-squaring HBM round-trips cost
     more than the arithmetic."""
-    if _USE_PALLAS_POW:
+    if _USE_PALLAS:
         from . import pallas_field
 
         return pallas_field.pow22523(z)
@@ -215,7 +211,7 @@ def pow22523(z: jnp.ndarray) -> jnp.ndarray:
 
 
 def _pow22523_chain(z: jnp.ndarray) -> jnp.ndarray:
-    """The portable XLA formulation (also the A/B-probe baseline)."""
+    """The portable XLA formulation."""
     t0 = square(z)  # 2
     t1 = square(square(t0))  # 8
     t1 = mul(z, t1)  # 9

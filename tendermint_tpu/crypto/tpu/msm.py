@@ -84,18 +84,6 @@ def _mul_255(p: Point) -> Point:
 
 _BLOCK = 16  # sequential within-block scan length (see _boundary_prefixes)
 
-# When True, the within-block scan runs as ONE fused Pallas kernel
-# (pallas_field.scan_blocks: all 16 cached additions VMEM-resident)
-# instead of a lax.scan of XLA point additions. Set by the verify
-# module's on-device probe — correctness-checked and timed there;
-# default off (the XLA path is the portable oracle).
-_USE_PALLAS_SCAN = False
-
-
-def set_pallas_scan(on: bool) -> None:
-    global _USE_PALLAS_SCAN
-    _USE_PALLAS_SCAN = bool(on)
-
 
 def _boundary_prefixes(sorted_pts: Point, counts: jnp.ndarray) -> Point:
     """C_j = prefix sum of the first counts[j] sorted points (identity
@@ -140,7 +128,7 @@ def _boundary_prefixes(sorted_pts: Point, counts: jnp.ndarray) -> Point:
     # the fused kernel pads the lane axis to its TILE: only route batches
     # that FILL a tile (the R-side MSM at the 8192 bucket, g=512) — small
     # windows (the grouped A-side, g≈16) would pay ~TILE/g× padding waste
-    if _USE_PALLAS_SCAN and g % pallas_field.TILE == 0:
+    if F._USE_PALLAS and g % pallas_field.TILE == 0:
         prefixes = pallas_field.scan_blocks(tuple(first), tuple(rest_cached))
         within = Point(*(p.reshape(m, -1) for p in prefixes))  # (M, 32)
         last = Point(*(p[:, -1] for p in prefixes))  # (g, 32) block totals

@@ -258,8 +258,8 @@ def sha256_device(msgs: list[bytes]) -> list[bytes]:
 
 
 def warmup(*, blocks: int = 2, batch: int = _MIN_BUCKET) -> None:
-    """Compile the given bucket shape ahead of use (the hub's probe and
-    bench.py call this so the first real dispatch is warm)."""
+    """Compile the given bucket shape ahead of use (the hub's probe
+    calls this so the first real dispatch is warm)."""
     sha256_device([b"\x01" * 65] * min(batch, _MAX_BUCKET))
     if blocks != 2:
         n = min(blocks, _MAX_BLOCKS) * 64 - 9

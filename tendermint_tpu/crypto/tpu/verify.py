@@ -212,32 +212,27 @@ def _ensure_compile_cache() -> None:
         jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-    _maybe_enable_pallas()
+    _choose_formulation()
     _cache_ready = True
 
 
-#: Filled by _maybe_enable_pallas on TPU: timings of the field-multiply,
-#: pow22523 and in-block-scan formulations, so a run records WHY a path
-#: was chosen. Keys: gemm_us, pallas_us, chosen, pow_*, scan_*. A Pallas
-#: kernel that failed to compile or mismatched leaves `error` (mul/pow
-#: stage) or `scan_error` here AND counts in
+#: Filled by _choose_formulation on a TPU: `chosen` ("pallas" | "xla"), so
+#: a run records which family of programs it traces. A Pallas kernel that
+#: failed to compile or mismatched leaves `error` (multiply/power stage)
+#: or `scan_error` here AND counts in
 #: backend_telemetry.BACKEND["pallas_probe_errors"] — chip_smoke.py
 #: refuses a run that has either.
 field_mul_probe: dict = {}
 
-
-#: probe sizes: the MSM batch width the production range bucket runs at,
-#: the multiply-chain length, and the R-side window count. Module-level
-#: so the CPU regression test can shrink them (interpret-mode Pallas).
-_PROBE_WIDTH = 8192
-_PROBE_CHAIN = 65
-_PROBE_WINDOWS = 16
+#: windows of the self-test's MSM: two, so that the vmap batching rule
+#: hands the fused scan a real batch axis (production runs 16 and 32)
+_SELF_TEST_WINDOWS = 2
 
 
 def _probe_failed(key: str, e: Exception) -> None:
-    """A Pallas formulation failed on a real TPU. The GEMM/XLA path is
-    still a device path, so production keeps going on it — but loudly:
-    recorded, counted, WARNING."""
+    """A Pallas kernel failed its self-test on a real TPU. The XLA
+    formulations are still a device path, so production keeps going on
+    them — but loudly: recorded, counted, WARNING."""
     import logging
 
     from .. import backend_telemetry as bt
@@ -245,185 +240,88 @@ def _probe_failed(key: str, e: Exception) -> None:
     field_mul_probe.setdefault(key, repr(e))
     bt.BACKEND["pallas_probe_errors"] += 1
     logging.getLogger("crypto.tpu").warning(
-        "pallas probe stage %r failed on the TPU (%r); that formulation "
-        "stays on its XLA path", key, e,
+        "pallas self-test stage %r failed on the TPU (%r); every kernel "
+        "stays on its XLA formulation", key, e,
     )
 
 
-def _maybe_enable_pallas() -> None:
-    """A/B the kernel formulations on the attached TPU and route through
-    the faster of each pair: field multiply as the 0/1-matrix GEMM
-    convolution (MXU) vs the Pallas VMEM kernel (VPU); pow22523 as an
-    XLA chain vs one fused Pallas kernel; the MSM's in-block prefix scan
-    as a lax.scan vs one fused Pallas kernel. Each pair is cross-checked
-    for equality before it is timed; timings and winners land in
-    `field_mul_probe`. Off-TPU this is a no-op (the XLA formulations are
-    the portable path). TMTPU_NO_PALLAS=1 pins the XLA formulations."""
-    if os.environ.get("TMTPU_NO_PALLAS"):
-        return
+def _choose_formulation() -> None:
+    """The one decision of which formulation the kernels are traced in,
+    made once from the platform: on a TPU the Pallas kernels (VMEM field
+    multiply, fused pow22523, fused in-block MSM scan — field.set_pallas),
+    anywhere else the portable XLA ones, with nothing run.
+
+    Before anything trusts the Pallas kernels, each is compared once with
+    its XLA twin on the attached device (a kernel Mosaic refuses raises
+    here, not in the first commit). ANY failure puts the one switch back
+    off: the all-XLA family, which every CPU test run exercises."""
     import jax
+
+    from . import field as F
 
     if jax.default_backend() != "tpu":
         return
+    # benchmark/metrics/setup_probe_ab_s.py reads this span by name
     with trace.span("backend", "pallas_ab") as sp:
-        try:
-            _probe_mul_and_pow()
-        except Exception as e:  # noqa: BLE001 — surfaced by _probe_failed
-            _probe_failed("error", e)
-        try:
-            _probe_scan()
-        except Exception as e:  # noqa: BLE001 — surfaced by _probe_failed
-            _probe_failed("scan_error", e)
-        sp.set(**{k: v for k, v in field_mul_probe.items() if k.endswith("chosen")})
+        runs = _self_test_runs()
+        want = {name: run() for name, run in runs.items()}  # switch off: XLA
+        F.set_pallas(True)
+        ok = True
+        for name, key in (("mul", "error"), ("pow22523", "error"), ("scan_blocks", "scan_error")):
+            try:
+                if not np.array_equal(want[name], runs[name]()):
+                    raise RuntimeError(f"pallas {name} mismatch")
+            except Exception as e:  # noqa: BLE001 — surfaced by _probe_failed
+                _probe_failed(key, e)
+                F.set_pallas(False)
+                ok = False
+                break
+        field_mul_probe["chosen"] = "pallas" if ok else "xla"
+        sp.set(chosen=field_mul_probe["chosen"])
 
 
-def _limbs_equal(want, got) -> bool:
-    from . import field as F
-
-    want, got = np.asarray(want), np.asarray(got)
-    return all(
-        F.limbs_to_int(want[i]) == F.limbs_to_int(got[i])
-        for i in range(want.shape[0])
-    )
-
-
-def _probe_mul_and_pow() -> None:
-    import logging
-    import time as _t
-
-    import jax
-
-    from . import field as F
-    from . import pallas_field
-
-    a = np.full((4, 32), 3, np.int32)
-    if not _limbs_equal(F.mul(a, a), pallas_field.mul(a, a)):
-        raise RuntimeError("pallas field mul mismatch")
-
-    # timed at a realistic MSM batch width (8192 field elements), device-
-    # resident inputs, as ONE jitted chain of m multiplies: a lone
-    # multiply is shorter than a dispatch, so per-call timing would rank
-    # launch overhead, not the two formulations
-    big = jax.device_put(
-        np.random.default_rng(0)
-        .integers(0, 256, (_PROBE_WIDTH, 32))
-        .astype(np.int32)
-    )
-
-    def _chain(mul_fn, m):
-        def f(x, y):
-            for _ in range(m):
-                x = mul_fn(x, y)  # output limbs ≤ 293: invariant holds
-            return x
-
-        return jax.jit(f)
-
-    def _time(mul_fn, reps=3):
-        m = _PROBE_CHAIN
-        f = _chain(mul_fn, m)
-        np.asarray(f(big, big))  # compile + warm + sync
-        t0 = _t.perf_counter()
-        for _ in range(reps):
-            out = f(big, big)
-        np.asarray(out)  # sync
-        return (_t.perf_counter() - t0) / reps / m * 1e6
-
-    gemm_us = _time(F._mul_gemm)
-    pallas_us = _time(pallas_field.mul)
-    use_pallas = pallas_us < gemm_us
-
-    # the fused pow22523 chain is probed SEPARATELY: it amortizes its
-    # layout boundary over 254 multiplies, so it can win even when a
-    # lone Pallas mul loses to the GEMM inside fused graphs.
-    # cross-checked on the SAME operand it is timed on: a second, tiny
-    # shape would be a second multi-second Mosaic compile of the kernel
-    pow_xla = jax.jit(F._pow22523_chain)
-    if not np.array_equal(
-        np.asarray(F.canonical(pow_xla(big))),
-        np.asarray(F.canonical(pallas_field.pow22523(big))),
-    ):
-        raise RuntimeError("pallas pow22523 mismatch")
-
-    def _time_pow(fn, reps=3):
-        np.asarray(fn(big))
-        t0 = _t.perf_counter()
-        for _ in range(reps):
-            out = fn(big)
-        np.asarray(out)
-        return (_t.perf_counter() - t0) / reps * 1e3
-
-    pow_xla_ms = _time_pow(pow_xla)
-    pow_pallas_ms = _time_pow(pallas_field.pow22523)
-    use_pallas_pow = pow_pallas_ms < pow_xla_ms
-
-    field_mul_probe.update(
-        gemm_us=round(gemm_us, 1),
-        pallas_us=round(pallas_us, 1),
-        chosen="pallas" if use_pallas else "gemm",
-        pow_xla_ms=round(pow_xla_ms, 1),
-        pow_pallas_ms=round(pow_pallas_ms, 1),
-        pow_chosen="pallas" if use_pallas_pow else "xla",
-    )
-    logging.getLogger("crypto.tpu").info(
-        "field-mul A/B (8192-wide): gemm %.1fus pallas %.1fus -> %s; "
-        "pow22523 xla %.1fms fused %.1fms -> %s",
-        gemm_us, pallas_us, field_mul_probe["chosen"],
-        pow_xla_ms, pow_pallas_ms, field_mul_probe["pow_chosen"],
-    )
-    F.set_pallas(use_pallas, pow_chain=use_pallas_pow)
-
-
-def _probe_scan() -> None:
-    """Fused within-block scan probe, run through the PRODUCTION trace
-    shape — msm.msm with 16 vmapped windows at the 8192 bucket (the
-    R-side MSM): the pallas_call must survive the vmap batching rule,
-    the g % TILE routing gate, and the full sort/scan/collapse graph
-    before it is trusted. Operand "points" are random limb vectors —
-    both paths compute identical limb algebra whether or not the inputs
-    lie on the curve, so equality + timing transfer."""
-    import time as _t
-
+def _self_test_runs():
+    """The three production entry points that read the switch — field.mul,
+    field.pow22523 and msm.msm — each on the smallest operand that takes
+    the production path: one lane tile for the field kernels, and for the
+    scan an MSM under vmap whose blocks fill a tile (the `g % TILE` gate
+    of msm._boundary_prefixes). Every call traces anew, in whatever
+    formulation the switch then selects, and returns canonical limbs.
+    Operand "points" are random limb vectors — both formulations compute
+    identical limb algebra whether or not the inputs lie on the curve."""
     import jax
     import jax.numpy as jnp
 
     from . import field as F
     from . import msm as msm_mod
-    from .curve import Point as _Pt
+    from . import pallas_field
+    from .curve import Point
 
-    rng = np.random.default_rng(1)
-    pts = tuple(
-        jax.device_put(rng.integers(0, 256, (_PROBE_WIDTH, 32), dtype=np.int32))
-        for _ in range(4)
-    )
+    rng = np.random.default_rng(0)
+    tile, width = pallas_field.TILE, msm_mod._BLOCK * pallas_field.TILE
+
+    def limbs(*shape):
+        return jax.device_put(rng.integers(0, 256, shape + (32,), dtype=np.int32))
+
+    a, b = limbs(tile), limbs(tile)
+    pts = tuple(limbs(width) for _ in range(4))
     digs = jax.device_put(
-        rng.integers(0, 256, (_PROBE_WINDOWS, _PROBE_WIDTH), dtype=np.int32)
+        rng.integers(0, 256, (_SELF_TEST_WINDOWS, width), dtype=np.int32)
     )
 
-    def _run_msm(flag):
-        msm_mod.set_pallas_scan(flag)
-        try:
-            fn = jax.jit(lambda p, d: msm_mod.msm(_Pt(*p), d))
-            out = fn(pts, digs)
-            canon = np.asarray(F.canonical(jnp.stack(list(out))))
-            t0 = _t.perf_counter()
-            for _ in range(3):
-                out = fn(pts, digs)
-            np.asarray(out[0])
-            return canon, (_t.perf_counter() - t0) / 3 * 1e3
-        finally:
-            msm_mod.set_pallas_scan(False)
+    def mul():
+        return np.asarray(jax.jit(lambda x, y: F.canonical(F.mul(x, y)))(a, b))
 
-    want, scan_xla_ms = _run_msm(False)
-    got, scan_pallas_ms = _run_msm(True)
-    if not np.array_equal(want, got):
-        raise RuntimeError("pallas scan_blocks mismatch")
-    use_scan = scan_pallas_ms < scan_xla_ms
-    msm_mod.set_pallas_scan(use_scan)
-    field_mul_probe.update(
-        scan_xla_ms=round(scan_xla_ms, 1),
-        scan_pallas_ms=round(scan_pallas_ms, 1),
-        scan_chosen="pallas" if use_scan else "xla",
-    )
+    def pow22523():
+        return np.asarray(jax.jit(lambda z: F.canonical(F.pow22523(z)))(a))
+
+    def scan_blocks():
+        # canonical INSIDE the program: run eagerly it is a dozen tiny
+        # compiles that no persistent cache keeps (under its 1 s floor)
+        fn = jax.jit(lambda p, d: F.canonical(jnp.stack(msm_mod.msm(Point(*p), d))))
+        return np.asarray(fn(pts, digs))
+
+    return {"mul": mul, "pow22523": pow22523, "scan_blocks": scan_blocks}
 
 
 def _get_kernel():

@@ -1,6 +1,6 @@
-"""Test/bench fixtures: deterministic validator sets, signed commits, and
+"""Test fixtures: deterministic validator sets, signed commits, and
 chains — the analog of the reference's internal test factories. Used by the
-unit tests and bench.py; not part of the public API surface."""
+unit tests, chip_smoke.py and benchmark/fixtures.py; not part of the public API surface."""
 
 from __future__ import annotations
 
@@ -105,8 +105,8 @@ def make_light_chain(
     """A synthetic chain of properly-signed LightBlocks 1..n_heights
     over one static validator set: hash-linked headers with monotone
     times, each committed by the full set — the light-client serving /
-    hop-proof workload shape (LightFleet tests and `bench.py
-    light_fleet`) without spinning a live network."""
+    hop-proof workload shape (LightFleet tests) without spinning a live
+    network."""
     from .crypto.hashes import sha256 as _sha
     from .light.types import LightBlock, SignedHeader
     from .types.block import Header
@@ -143,7 +143,7 @@ def make_light_chain(
 def make_list_provider(blocks, chain_id: str = "light-chain"):
     """An in-memory light-block Provider over a prebuilt chain (height
     0 = tip), with a fetch counter — the serving-side fixture for the
-    LightFleet tests and `bench.py light_fleet`."""
+    LightFleet tests."""
     from .light.provider import LightBlockNotFoundError, Provider
 
     class ListProvider(Provider):
@@ -171,8 +171,8 @@ def make_list_provider(blocks, chain_id: str = "light-chain"):
 async def build_kvstore_chain(n_blocks: int, n_vals: int, chain_id: str = "ss-bench"):
     """Build an n_blocks kvstore chain through the real executor: returns
     (block_store, state_store, app_conns, genesis, keys_by_addr) with the
-    app holding its periodic snapshots. Shared by bench.py config 5 and
-    the statesync tests."""
+    app holding its periodic snapshots. Shared by the block-sync, crash-
+    recovery and chaos-net tests and `statesync_fleet_scenario`."""
     from .abci.kvstore import KVStoreApp
     from .consensus.replay import Handshaker
     from .proxy import AppConns
@@ -222,106 +222,6 @@ async def build_kvstore_chain(n_blocks: int, n_vals: int, chain_id: str = "ss-be
     return bstore, sstore, conns, genesis, by_addr
 
 
-async def statesync_restore_scenario(
-    n_blocks: int, n_vals: int, *, backfill_blocks: int | None = None
-) -> int:
-    """BASELINE config 5 shape: snapshot restore + verified backfill over
-    the real statesync reactor protocol, two reactors bridged in-process.
-    Returns the number of headers the restored node holds afterwards
-    (reference internal/statesync/reactor.go Sync + Backfill)."""
-    import asyncio
-
-    from .abci.kvstore import KVStoreApp
-    from .p2p.peermanager import PeerStatus, PeerUpdate
-    from .p2p.router import Channel
-    from .p2p.types import Envelope
-    from .proxy import AppConns
-    from .state.store import StateStore
-    from .statesync import (
-        CHUNK_CHANNEL,
-        LIGHT_BLOCK_CHANNEL,
-        PARAMS_CHANNEL,
-        SNAPSHOT_CHANNEL,
-    )
-    from .statesync import messages as ssm
-    from .statesync.reactor import StateSyncReactor, SyncConfig
-    from .store.blockstore import BlockStore
-    from .store.db import MemDB
-
-    src_bstore, src_sstore, src_conns, genesis, _keys = await build_kvstore_chain(
-        n_blocks, n_vals
-    )
-
-    def channels() -> dict[int, Channel]:
-        return {
-            cid: Channel(cid, name, 5, ssm.encode_message, ssm.decode_message)
-            for cid, name in (
-                (SNAPSHOT_CHANNEL, "snapshot"),
-                (CHUNK_CHANNEL, "chunk"),
-                (LIGHT_BLOCK_CHANNEL, "lightblock"),
-                (PARAMS_CHANNEL, "params"),
-            )
-        }
-
-    src_ch, dst_ch = channels(), channels()
-
-    server_q: asyncio.Queue = asyncio.Queue()
-    client_q: asyncio.Queue = asyncio.Queue()
-    server = StateSyncReactor(
-        genesis.chain_id, src_conns, src_sstore, src_bstore,
-        src_ch[SNAPSHOT_CHANNEL], src_ch[CHUNK_CHANNEL],
-        src_ch[LIGHT_BLOCK_CHANNEL], src_ch[PARAMS_CHANNEL], server_q,
-    )
-    dst_app = AppConns.local(KVStoreApp(MemDB()))
-    dst_bstore = BlockStore(MemDB())
-    dst_sstore = StateStore(MemDB())
-    client = StateSyncReactor(
-        genesis.chain_id, dst_app, dst_sstore, dst_bstore,
-        dst_ch[SNAPSHOT_CHANNEL], dst_ch[CHUNK_CHANNEL],
-        dst_ch[LIGHT_BLOCK_CHANNEL], dst_ch[PARAMS_CHANNEL], client_q,
-    )
-
-    async def pump(src: Channel, dst: Channel, from_name: str) -> None:
-        while True:
-            env = await src.out_q.get()
-            await dst.in_q.put(Envelope(env.channel_id, env.message, from_=from_name))
-
-    pumps = [
-        asyncio.get_running_loop().create_task(pump(a, b, name))
-        for cid in src_ch
-        for a, b, name in (
-            (dst_ch[cid], src_ch[cid], "client"),
-            (src_ch[cid], dst_ch[cid], "server"),
-        )
-    ]
-    await server.start()
-    await client.start()
-    await client_q.put(PeerUpdate("server", PeerStatus.UP))
-    try:
-        meta1 = src_bstore.load_block_meta(1)
-        cfg = SyncConfig(
-            trust_height=1,
-            trust_hash=meta1.header.hash(),
-            trust_period_ns=10 * 365 * 24 * 3600 * 10**9,
-            backfill_blocks=backfill_blocks,
-        )
-        state = await asyncio.wait_for(client.sync(cfg), timeout=300)
-        assert state.last_block_height >= n_blocks - 12, state.last_block_height
-        held = 0
-        h = state.last_block_height
-        while h >= 1 and dst_bstore.load_block_meta(h) is not None:
-            held += 1
-            h -= 1
-        return held
-    finally:
-        for t in pumps:
-            t.cancel()
-        await client.stop()
-        await server.stop()
-        await dst_app.stop()
-        await src_conns.stop()
-
-
 async def statesync_fleet_scenario(
     n_blocks: int,
     n_vals: int,
@@ -333,8 +233,8 @@ async def statesync_fleet_scenario(
 ) -> dict:
     """BootFleet in-process shape: ONE donor reactor (its BootD serving
     every joiner from the shared chunk cache) vs `n_joiners` concurrent
-    cold joiners, bridged by routing pumps — the `bench.py statesync`
-    join-wave workload and the tier-1 BootFleet fixtures, without a live
+    cold joiners, bridged by routing pumps — the join-wave workload of
+    the tier-1 BootFleet fixtures, without a live
     router mesh. Returns per-joiner sync times, the donor's BootD stats
     (cache amortization, sheds, store reads), and per-joiner join
     outcomes (a shed/failed joiner is an outcome, not a raise)."""

@@ -7,7 +7,7 @@ Public surface:
   * `Finding`, `Rule`, `FileContext`, `Allowlist` — extension points
 
 Driver: `scripts/tmtlint` (text/JSON output, --rule, --changed,
---update-lock; `scripts/lint.py` is the legacy alias).
+--update-lock).
 Invariant docs: README "Static analysis".
 """
 

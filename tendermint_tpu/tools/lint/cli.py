@@ -12,9 +12,8 @@ Usage (via the `scripts/tmtlint` entrypoint):
 Exit status: 0 clean, 1 findings, 2 usage/internal error.
 
 One code path for every consumer: the tier-1 gate (tests/test_lint.py)
-shells out to `scripts/tmtlint --json`, pre-commit runs `--changed`,
-and the legacy shims (`scripts/lint.py`, `scripts/check_*_callsites.py`)
-call `main()` here directly — there is no second driver to drift.
+shells out to `scripts/tmtlint --json` and pre-commit runs `--changed`
+— there is no second driver to drift.
 
 `--changed` analyzes the FULL default surface (the project rules need
 the whole tree: an interprocedural chain or a wire-schema diff does not

@@ -41,7 +41,7 @@ _RAW_CODEC = ("encode_message_py", "decode_message_py")
 
 #: files allowed to touch the interpreted entry points directly: the
 #: owning module, the generated module's fallback path, the toolchain
-#: that compiles/verifies them, and tests/bench (which pin A/B parity)
+#: that compiles/verifies them, and tests (which pin A/B parity)
 _RAW_ALLOWED_PREFIXES = (
     "tendermint_tpu/tools/",
     "tests/",
@@ -50,7 +50,6 @@ _RAW_ALLOWED_FILES = frozenset(
     {
         "tendermint_tpu/consensus/messages.py",
         GENERATED_REL,
-        "bench.py",
     }
 )
 

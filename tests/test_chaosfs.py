@@ -235,10 +235,13 @@ class TestChaosNetNewFaults:
 
 
 def test_fs_callsite_lint_clean():
-    """scripts/check_fs_callsites.py is the tier-1 guard against storage
-    writes sneaking around the injectable chaos-fs layer."""
+    """tmtlint's fs rules are the tier-1 guard against storage writes
+    sneaking around the injectable chaos-fs layer (the whole package: the
+    transitive rule follows helpers in other files)."""
     proc = subprocess.run(
-        [sys.executable, os.path.join(REPO, "scripts", "check_fs_callsites.py")],
+        [sys.executable, os.path.join(REPO, "scripts", "tmtlint"),
+         "--rule", "fs-discipline", "--rule", "transitive-fs", "tendermint_tpu"],
+        cwd=REPO,
         capture_output=True,
         text=True,
         timeout=60,

@@ -1375,31 +1375,6 @@ def test_repo_tree_is_clean_and_fast():
         assert required in payload["per_rule"]
 
 
-def test_legacy_lint_py_alias_same_code_path():
-    """scripts/lint.py predates the tmtlint CLI; it must stay a pure
-    alias (same main(), same output shape)."""
-    out = subprocess.run(
-        [sys.executable, os.path.join(REPO, "scripts", "lint.py"),
-         "--json", "--rule", "task-leak", "tendermint_tpu/libs"],
-        cwd=REPO, capture_output=True, text=True, timeout=120,
-    )
-    assert out.returncode == 0, out.stdout + out.stderr
-    payload = json.loads(out.stdout)
-    assert payload["rules"] == ["task-leak"] and "per_rule" in payload
-
-
-def test_retired_regex_shims_route_through_tmtlint():
-    """check_fs_callsites / check_verify_callsites predate the PR 4
-    framework; they are now aliases over the tmtlint rules (per-file +
-    transitive) and must exit clean on the tree."""
-    for shim in ("check_fs_callsites.py", "check_verify_callsites.py"):
-        out = subprocess.run(
-            [sys.executable, os.path.join(REPO, "scripts", shim)],
-            cwd=REPO, capture_output=True, text=True, timeout=120,
-        )
-        assert out.returncode == 0, (shim, out.stdout, out.stderr)
-
-
 def test_driver_rule_filter_and_errors():
     out = _lint("--rule", "no-such-rule")
     assert out.returncode == 2 and "unknown rule" in out.stderr

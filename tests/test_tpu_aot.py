@@ -127,8 +127,8 @@ def test_compiles_for_v5e(one_chip, no_persistent_cache, build, want_custom_call
 
 def test_sharded_eq_kernel_compiles_for_the_2x2_mesh_with_one_all_gather(topo, no_persistent_cache):
     """The four-chip deployment's program (`light150.mesh4`) as the chip
-    runs it — the gate's rung, 101 keys, the Pallas formulations ON (the
-    A/B's winners on a v5e; Mosaic kernels inside the shard_map are what
+    runs it — the gate's rung, 101 keys, the Pallas formulations ON (what
+    a TPU process traces; Mosaic kernels inside the shard_map are what
     XLA refused to partition before PR 22): it compiles for the described
     2x2 mesh under its own name, and the ONLY collective the compiler put
     in is the one all-gather of the partial points."""
@@ -138,7 +138,7 @@ def test_sharded_eq_kernel_compiles_for_the_2x2_mesh_with_one_all_gather(topo, n
     from jax.sharding import Mesh, NamedSharding
     from jax.sharding import PartitionSpec as P
 
-    from tendermint_tpu.crypto.tpu import field, msm, verify
+    from tendermint_tpu.crypto.tpu import field, verify
 
     mesh = Mesh(np.asarray(topo.devices), ("data",))
 
@@ -148,8 +148,7 @@ def test_sharded_eq_kernel_compiles_for_the_2x2_mesh_with_one_all_gather(topo, n
     m, gb = verify._SHARD_MIN_ROWS, verify._group_bucket(101)
     assert verify._plan_shape(m, 1, len(topo.devices)) == (True, m, len(topo.devices))
     rep, rows = P(), P("data")
-    field.set_pallas(True, pow_chain=True)
-    msm.set_pallas_scan(True)
+    field.set_pallas(True)
     try:
         compiled = verify.make_sharded_kernel_eq(mesh).lower(
             S((gb, 32), jnp.uint8, rep), S((m, 32), jnp.uint8, rows), S((32, gb), jnp.uint8, rep),
@@ -157,8 +156,7 @@ def test_sharded_eq_kernel_compiles_for_the_2x2_mesh_with_one_all_gather(topo, n
             S((m,), jnp.bool_, rows), S((m,), jnp.int32, rows),
         ).compile()
     finally:
-        field.set_pallas(False, pow_chain=False)
-        msm.set_pallas_scan(False)
+        field.set_pallas(False)
     text = compiled.as_text()
     assert re.search(r"HloModule jit__kernel_eq_sharded\b", text) and "tpu_custom_call" in text
     assert len(re.findall(r"= \S+ all-gather(?:-start)?\(", text)) == 1

@@ -1,12 +1,14 @@
 """Bring-up regressions that only a TPU would otherwise reach:
 
-  * the Pallas A/B probe (`verify._maybe_enable_pallas`) returns early off
+  * the Pallas self-test (`verify._choose_formulation`) returns early off
     TPU, so no CPU test ever ran its body — a missing import inside it went
     unnoticed and was swallowed on the chip. Here the backend gate is
     patched in-test and the Pallas kernels run in interpret mode at tiny
-    widths, so the whole probe executes on the CPU;
-  * a Pallas failure on a TPU is loud (recorded, counted, WARNING), not
-    INFO-and-carry-on;
+    widths, so the whole self-test executes on the CPU;
+  * a Pallas failure on a TPU is loud (recorded, counted, WARNING) and
+    leaves the ONE formulation switch off, not INFO-and-carry-on;
+  * the formulation has one switch: field.mul, field.pow22523 and the
+    MSM's block scan all follow `field.set_pallas`, and nothing else;
   * the compile cache can be placed from outside (JAX_COMPILATION_CACHE_DIR
     set -> the code sets no directory at all; unset -> the fixed in-checkout
     path; an uncreatable directory raises);
@@ -30,78 +32,129 @@ from tendermint_tpu.crypto.tpu import verify as V
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
+def _record_calls(monkeypatch, log, name, fn):
+    """Put `fn` in pallas_field.<name>'s place, noting every call in `log`."""
+
+    def stand_in(*a, **k):
+        log.append(name)
+        return fn(*a, **k)
+
+    monkeypatch.setattr(PF, name, stand_in)
+
+
 @pytest.fixture
 def probe_on_cpu(monkeypatch):
-    """Drive the probe past its backend gate on the CPU: tiny widths,
-    Pallas in interpret mode, switches restored afterwards."""
-    monkeypatch.delenv("TMTPU_NO_PALLAS", raising=False)
+    """Drive the self-test past its backend gate on the CPU: tiny widths,
+    Pallas in interpret mode, the switch restored afterwards. Yields the
+    list of values `field.set_pallas` was called with."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    monkeypatch.setattr(V, "_PROBE_WIDTH", 32)
-    monkeypatch.setattr(V, "_PROBE_CHAIN", 2)
-    monkeypatch.setattr(V, "_PROBE_WINDOWS", 1)
-    # 32 points in blocks of 4 -> 8 block lanes == the patched TILE, so
-    # msm routes the in-block scan through the Pallas gate (g % TILE == 0)
+    # the self-test sizes its MSM as _BLOCK * TILE points: 32 points in
+    # blocks of 4 -> 8 block lanes == the patched TILE, so msm routes the
+    # in-block scan through the Pallas gate (g % TILE == 0)
     monkeypatch.setattr(M, "_BLOCK", 4)
     monkeypatch.setattr(PF, "TILE", 8)
-    monkeypatch.setattr(PF, "mul", functools.partial(PF.mul, interpret=True))
+    pallas_mul = PF.mul
+
+    def mul(a, b):
+        # the self-test's own multiply runs the kernel (interpreted); inside
+        # the MSM every multiply would go through the interpreter under vmap
+        # — a minute of XLA-CPU compile — so there the GEMM stands in
+        if a.shape == b.shape == (PF.TILE, 32):
+            return pallas_mul(a, b, interpret=True)
+        return F._mul_gemm(a, b)
+
+    monkeypatch.setattr(PF, "mul", mul)
     monkeypatch.setattr(
         PF, "scan_blocks", functools.partial(PF.scan_blocks, interpret=True, tile=8)
     )
     # the fused pow22523 kernel is a 254-multiply chain: minutes in
-    # interpret mode (its own test is `slow`). The probe's control flow
-    # is what this guards, so the XLA chain stands in for it.
-    monkeypatch.setattr(PF, "pow22523", jax.jit(F._pow22523_chain))
+    # interpret mode (its own test is `slow`). The self-test's control
+    # flow is what this guards, so the XLA chain stands in for it.
+    monkeypatch.setattr(PF, "pow22523", F._pow22523_chain)
     monkeypatch.setattr(V, "field_mul_probe", {})
-    # which multiply "wins" a wall-clock race between XLA-CPU and the
-    # Pallas interpreter is noise, and with the interpreted multiply
-    # switched on the scan stage would push every msm multiply through the
-    # interpreter under vmap: record the decision instead of applying it
-    decided = []
-    monkeypatch.setattr(F, "set_pallas", lambda on, **kw: decided.append((on, kw)))
+    switched = []
+    set_pallas = F.set_pallas
+
+    def recording_set_pallas(on):
+        switched.append(on)
+        set_pallas(on)
+
+    monkeypatch.setattr(F, "set_pallas", recording_set_pallas)
     before = dict(bt.BACKEND)
-    yield decided
-    M.set_pallas_scan(False)
+    yield switched
+    set_pallas(False)
     bt.BACKEND.update(before)
 
 
-def test_pallas_probe_runs_clean_past_the_backend_gate(probe_on_cpu):
-    V._maybe_enable_pallas()
+def test_pallas_self_test_runs_clean_past_the_backend_gate(probe_on_cpu, monkeypatch):
+    ran = []
+    for name in ("mul", "pow22523", "scan_blocks"):
+        _record_calls(monkeypatch, ran, name, getattr(PF, name))
+    V._choose_formulation()
     probe = V.field_mul_probe
     assert not probe.get("error") and not probe.get("scan_error"), (
         f"error={probe.get('error')} scan_error={probe.get('scan_error')}"
     )
     assert bt.BACKEND["pallas_probe_errors"] == 0
-    # every pair was cross-checked, timed and decided
-    assert probe["chosen"] in ("gemm", "pallas")
-    assert probe["pow_chosen"] in ("xla", "pallas")
-    assert probe["scan_chosen"] in ("xla", "pallas")
-    assert {"gemm_us", "pallas_us", "scan_xla_ms", "scan_pallas_ms"} <= set(probe)
-    assert probe_on_cpu == [
-        (probe["chosen"] == "pallas", {"pow_chain": probe["pow_chosen"] == "pallas"})
-    ]
+    # all three kernels were reached and compared, and nothing is timed
+    assert {"mul", "pow22523", "scan_blocks"} <= set(ran)
+    assert probe == {"chosen": "pallas"}
+    assert probe_on_cpu == [True] and F._USE_PALLAS
 
 
-def test_pallas_probe_failure_is_loud(probe_on_cpu, monkeypatch, caplog):
+def test_pallas_self_test_failure_is_loud(probe_on_cpu, monkeypatch, caplog):
     def broken(*_a, **_k):
         raise RuntimeError("mosaic refused the kernel")
 
     monkeypatch.setattr(PF, "scan_blocks", broken)
     with caplog.at_level(logging.WARNING, logger="crypto.tpu"):
-        V._maybe_enable_pallas()
+        V._choose_formulation()
     assert "mosaic refused" in V.field_mul_probe["scan_error"]
-    assert "error" not in V.field_mul_probe  # mul/pow stage still decided
+    assert "error" not in V.field_mul_probe  # the mul/pow stage passed
+    assert V.field_mul_probe["chosen"] == "xla"
     assert bt.BACKEND["pallas_probe_errors"] == 1
     assert any(
         r.levelno == logging.WARNING and "scan_error" in r.getMessage()
         for r in caplog.records
     )
-    assert not M._USE_PALLAS_SCAN  # the failed formulation stays off
+    # ONE switch, and it is off: the all-XLA family, never a mixed one
+    assert probe_on_cpu == [True, False] and not F._USE_PALLAS
 
 
-def test_pallas_probe_is_a_noop_off_tpu(monkeypatch):
+def test_pallas_self_test_is_a_noop_off_tpu(monkeypatch):
     monkeypatch.setattr(V, "field_mul_probe", {})
-    V._maybe_enable_pallas()  # conftest: the CPU backend
-    assert V.field_mul_probe == {}
+    V._choose_formulation()  # conftest: the CPU backend
+    assert V.field_mul_probe == {} and not F._USE_PALLAS
+
+
+@pytest.mark.parametrize("on", [True, False])
+def test_one_switch_routes_all_three_kernels(on, monkeypatch):
+    """field.mul, field.pow22523 and an msm whose blocks fill a TILE reach
+    the Pallas kernels when `field.set_pallas` is on and none of them when
+    it is off — a fourth reader of a private flag would fail here."""
+    from tendermint_tpu.crypto.tpu.curve import Point
+
+    reached = []
+    _record_calls(monkeypatch, reached, "mul", F._mul_gemm)
+    _record_calls(monkeypatch, reached, "pow22523", F._pow22523_chain)
+    _record_calls(
+        monkeypatch, reached, "scan_blocks",
+        functools.partial(PF.scan_blocks, interpret=True, tile=8),
+    )
+    monkeypatch.setattr(M, "_BLOCK", 4)
+    monkeypatch.setattr(PF, "TILE", 8)
+    a = jax.ShapeDtypeStruct((8, 32), jax.numpy.int32)
+    pts = Point(*(jax.ShapeDtypeStruct((32, 32), jax.numpy.int32),) * 4)
+    digs = jax.ShapeDtypeStruct((2, 32), jax.numpy.int32)
+
+    F.set_pallas(on)
+    try:  # the stand-ins record as the readers of the switch trace
+        jax.eval_shape(lambda x: F.mul(x, x), a)
+        jax.eval_shape(lambda x: F.pow22523(x), a)
+        jax.eval_shape(lambda p, d: M.msm(p, d), pts, digs)
+    finally:
+        F.set_pallas(False)
+    assert set(reached) == ({"mul", "pow22523", "scan_blocks"} if on else set())
 
 
 @pytest.fixture
@@ -109,7 +162,7 @@ def cache_config(monkeypatch):
     """Record what _ensure_compile_cache would set, without setting it."""
     updates = {}
     monkeypatch.setattr(V, "_cache_ready", False)
-    monkeypatch.setattr(V, "_maybe_enable_pallas", lambda: None)
+    monkeypatch.setattr(V, "_choose_formulation", lambda: None)
     monkeypatch.setattr(jax.config, "update", lambda k, v: updates.__setitem__(k, v))
     return updates
 

@@ -245,10 +245,12 @@ class TestAdoption:
 
 
 def test_callsite_lint_clean():
-    """scripts/check_verify_callsites.py is the tier-1 guard against new
-    direct verify_signature call sites bypassing the hub."""
+    """tmtlint's verify rules are the tier-1 guard against new direct
+    verify_signature call sites bypassing the hub."""
     proc = subprocess.run(
-        [sys.executable, os.path.join(REPO, "scripts", "check_verify_callsites.py")],
+        [sys.executable, os.path.join(REPO, "scripts", "tmtlint"),
+         "--rule", "verify-chokepoint", "--rule", "transitive-verify", "tendermint_tpu"],
+        cwd=REPO,
         capture_output=True,
         text=True,
         timeout=60,
