@@ -66,11 +66,25 @@ class LightBlock:
     def header(self) -> Header:
         return self.signed_header.header
 
-    def validate_basic(self, chain_id: str) -> None:
+    def validate_basic(self, chain_id: str, pinned: bytes | None = None) -> None:
+        """Structural checks of header, commit and validator set.
+
+        `pinned` is the hash of a validator set the caller has ALREADY
+        put through ValidatorSet.validate_basic (verify_adjacent_chain
+        hands in the last one of its walk). A set that hashes to it is
+        not validated again: validators.hash() is the RFC-6962 merkle
+        root over simple_encode = exactly (public key, voting power) of
+        every validator in order, and ValidatorSet.validate_basic reads
+        nothing else (non-empty, power > 0, no two equal addresses — an
+        address is a function of the key). Equal roots => equal
+        (key, power) lists => the verdict already given. The hash is
+        computed two lines down anyway, to bind the set to the header;
+        every other check runs as without a pin, in the same order."""
         if self.validators is None:
             raise ValueError("light block missing validator set")
         self.signed_header.validate_basic(chain_id)
-        self.validators.validate_basic()
+        if pinned is None or self.validators.hash() != pinned:
+            self.validators.validate_basic()
         if self.header.validators_hash != self.validators.hash():
             raise ValueError("validators hash does not match header")
 

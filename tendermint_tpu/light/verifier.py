@@ -47,10 +47,12 @@ def _validate_untrusted(
     untrusted: LightBlock,
     now_ns: int,
     max_clock_drift_ns: int,
+    pinned: bytes | None = None,
 ) -> None:
     """Shared sanity checks (reference verifier.go
-    checkRequiredHeaderFields + verifyNewHeaderAndVals)."""
-    untrusted.validate_basic(chain_id)
+    checkRequiredHeaderFields + verifyNewHeaderAndVals). `pinned`: see
+    LightBlock.validate_basic — only verify_adjacent_chain hands one in."""
+    untrusted.validate_basic(chain_id, pinned)
     if untrusted.height <= trusted.height:
         raise VerificationError(
             f"untrusted height {untrusted.height} <= trusted {trusted.height}"
@@ -72,10 +74,12 @@ def _check_adjacent_link(
     trusting_period_ns: int,
     now_ns: int,
     max_clock_drift_ns: int,
+    pinned: bytes | None = None,
 ) -> None:
     """Every non-signature check of one adjacent step — shared verbatim
     by verify_adjacent and verify_adjacent_chain so the two paths cannot
-    drift."""
+    drift (the chain walk alone passes `pinned`, the hash of the last
+    validator set it validated in full)."""
     if untrusted.height != trusted.height + 1:
         raise VerificationError(
             f"headers must be adjacent in height "
@@ -83,7 +87,9 @@ def _check_adjacent_link(
         )
     if _expired(trusted, trusting_period_ns, now_ns):
         raise VerificationError(f"trusted header {trusted.height} has expired")
-    _validate_untrusted(chain_id, trusted, untrusted, now_ns, max_clock_drift_ns)
+    _validate_untrusted(
+        chain_id, trusted, untrusted, now_ns, max_clock_drift_ns, pinned
+    )
     if untrusted.header.validators_hash != trusted.header.next_validators_hash:
         raise VerificationError(
             "untrusted validators hash != trusted next_validators_hash"
@@ -140,17 +146,42 @@ def verify_adjacent_chain(
     signature proof to the end does not weaken the trust chain: a forged
     commit anywhere fails the batch and nothing is returned.
 
+    A validator set is validated in full (ValidatorSet.validate_basic:
+    150 address derivations on a 150-validator chain) the first time its
+    hash is seen in this walk and not again while the hash repeats — on
+    every chain whose validators did not just change, once a call
+    instead of once a header. What makes that exact: the hash is the
+    merkle root over (public key, voting power) of every validator in
+    order, validate_basic reads only those, so equal hashes fix its
+    verdict (LightBlock.validate_basic spells it out). The pin is a local
+    of this call and starts empty: the first block of every call is
+    validated in full wherever `trusted` came from (a store handed in by
+    a caller, a decode, an earlier window), and so is every set whose
+    hash differs from the pin — a validator change, or anything a faulty
+    primary sends — which then becomes the pin. Each set stays bound to
+    its header (validators_hash == validators.hash()) and to its
+    predecessor (next_validators_hash) as before; the `light.link` span's
+    `validated` counts the full validations of the call.
+
     Returns the new trusted head (the last block of `chain`). Raises
     VerificationError naming the offending height otherwise."""
     now_ns = time.time_ns() if now_ns is None else now_ns
     with hash_hub.lane_ctx(hash_hub.LANE_LIGHT):
         entries = []
         prev = trusted
-        with trace.span("light", "link", n=len(chain)):
+        pinned = None  # hash of the last set this call validated in full
+        validated = 0
+        with trace.span("light", "link", n=len(chain)) as sp:
             for lb in chain:
                 _check_adjacent_link(
-                    chain_id, prev, lb, trusting_period_ns, now_ns, max_clock_drift_ns
+                    chain_id, prev, lb, trusting_period_ns, now_ns,
+                    max_clock_drift_ns, pinned,
                 )
+                # the link held, so validators_hash IS the set's hash
+                vh = lb.header.validators_hash
+                if vh != pinned:
+                    pinned = vh
+                    validated += 1
                 entries.append(
                     (
                         lb.validators,
@@ -160,6 +191,7 @@ def verify_adjacent_chain(
                     )
                 )
                 prev = lb
+            sp.set(validated=validated)
         try:
             with trace.span("light", "verify", n=len(chain)):
                 verify_commit_range(chain_id, entries, lane="backfill")

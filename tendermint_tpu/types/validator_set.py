@@ -297,6 +297,7 @@ class ValidatorSet:
         for v in self.validators:
             if v.voting_power <= 0:
                 raise ValueError("validator with non-positive power")
-            if v.address in seen:
+            address = v.address  # a hash of the key: derived once
+            if address in seen:
                 raise ValueError("duplicate validator address")
-            seen.add(v.address)
+            seen.add(address)
