@@ -15,13 +15,21 @@ One process, through the entry points a node uses, at the size users run
              AdaptiveBatchVerifier and tpu.verify.verify_batch_eq: all
              valid -> all true; a few corrupted -> exactly those false;
              both bit for bit against Ed25519PubKey.verify_signature.
+  mixed      a 20-validator committee of 10 ed25519 + 10 secp256k1 keys
+             (BASELINE config 4's shape), as many commits as put the
+             range's Edwards rows over the measured cut-off, through
+             verify_commit_range and the AdaptiveBatchVerifier: the Edwards
+             rows on route `tpu`, the ECDSA rows on the host lane (route
+             `host-ecdsa`), none on `cpu`; valid -> accepted, one flipped
+             row of each scheme -> refused at the first, the bitmap equal
+             to each key's own host verify.
   blocksync  a seeded 300-block, 150-validator kvstore chain replayed
              through the real BlockSyncReactor to the app hash the chain
              was built with.
   net4       four node.Node validators over the memory transport to
              height 3 with the device probe live.
 
-After the 150-validator phases it asserts the DEVICE served them — the
+After the range, mixed and blocksync phases it asserts the DEVICE served them — the
 production path re-verifies on the host after any device error, so a right
 bitmap alone proves nothing about the chip: route "tpu", breaker never
 opened, zero host re-verifies, zero degrade retries, active kind "tpu",
@@ -52,6 +60,7 @@ N_VALS = 150
 N_COMMITS = 108
 N_BLOCKS = 300
 N_CORRUPT = 5
+MIXED_VALS = 20
 NET_VALS = 4
 NET_HEIGHT = 3
 TXS_PER_BLOCK = 2
@@ -253,6 +262,78 @@ def phase_range(seed: int) -> dict:
     say(f"range: tpu.verify.verify_batch_eq valid+corrupted match in "
         f"{time.monotonic() - t1:.2f}s")
     return {"signatures": len(items), "seconds": round(time.monotonic() - t0, 2)}
+
+
+def phase_mixed(seed: int) -> dict:
+    """One mixed commit range through the device route: Edwards rows
+    `tpu`, ECDSA rows `host-ecdsa`, verdicts equal to the host's."""
+    import dataclasses
+
+    from tendermint_tpu import testing as tt
+    from tendermint_tpu.crypto import backend_telemetry as bt
+    from tendermint_tpu.crypto import batch as cb
+    from tendermint_tpu.types import validation
+
+    t0 = time.monotonic()
+    chain_id = "smoke-mixed"
+    vals, keys = tt.make_validator_set(MIXED_VALS, power=10, seed=b"smoke-mixed-%d" % seed,
+                                       key_types=("ed25519", "secp256k1"))
+    quorum = MIXED_VALS * 2 // 3 + 1  # equal powers, everyone signs
+    schemes = [v.pub_key.TYPE for v in vals.validators[:quorum]]
+    ed, ec = schemes.count("ed25519"), schemes.count("secp256k1")
+    assert ed and ec, f"seed {seed}: the quorum holds one key type only: {schemes}"
+    n_commits = -(-cb.MIN_TPU_BATCH // ed) + 1  # the Edwards rows clear the cut-off
+    entries, items = [], []
+    for h in range(1, n_commits + 1):
+        bid = tt.make_block_id(b"smoke-mixed-%d-%d" % (seed, h))
+        commit = tt.make_commit(chain_id, h, 0, bid, vals, keys)
+        entries.append((vals, bid, h, commit))
+        sign_bytes = commit.sign_bytes(chain_id)
+        items += [(vals.validators[i].pub_key, sign_bytes(i), commit.signatures[i].signature)
+                  for i in range(quorum)]
+
+    before = {k: v[1] for k, v in bt.ROUTES.items()}
+    validation.verify_commit_range(chain_id, entries)
+    routes = {k: v[1] - before.get(k, 0) for k, v in bt.ROUTES.items() if v[1] != before.get(k, 0)}
+    assert routes == {"tpu": n_commits * ed, "host-ecdsa": n_commits * ec}, (
+        f"mixed range of {n_commits} commits ({ed} Edwards + {ec} ECDSA rows each, "
+        f"cut-off {cb.MIN_TPU_BATCH}) was routed {routes}")
+
+    # one flipped row of each scheme, the ECDSA one in the EARLIER commit
+    def flip_bit(sig: bytes) -> bytes:
+        return sig[:7] + bytes([sig[7] ^ 0x10]) + sig[8:]
+
+    def flip(entry, index):
+        sigs = list(entry[3].signatures)
+        sigs[index] = dataclasses.replace(sigs[index], signature=flip_bit(sigs[index].signature))
+        return entry[:3] + (dataclasses.replace(entry[3], signatures=tuple(sigs)),)
+
+    i_ec, i_ed = schemes.index("secp256k1"), schemes.index("ed25519")
+    bad = list(entries)
+    bad[1], bad[n_commits - 1] = flip(bad[1], i_ec), flip(bad[n_commits - 1], i_ed)
+    try:
+        validation.verify_commit_range(chain_id, bad)
+        raise AssertionError("a mixed range with two flipped rows was accepted")
+    except validation.InvalidCommitError as e:
+        assert e.failed_index == 1 and f"index {i_ec}" in str(e), (e.failed_index, str(e))
+
+    # the production verifier over the flat rows, against each key's own verify
+    flat_bad = list(items)
+    for row in (quorum + i_ec, (n_commits - 1) * quorum + i_ed):
+        pk, msg, sig = flat_bad[row]
+        flat_bad[row] = (pk, msg, flip_bit(sig))
+    for label, rows in (("valid", items), ("corrupted", flat_bad)):
+        want = [pk.verify_signature(msg, sig) for pk, msg, sig in rows]
+        bv = cb.AdaptiveBatchVerifier()
+        bv.add_many(rows)
+        ok, got = bv.verify()
+        assert bv.last_route == "mixed", f"{label} mixed rows ran on {bv.last_route!r}"
+        assert got == want and ok is all(want), f"{label} mixed rows: bitmap != host verify"
+    say(f"mixed: {n_commits} commits x ({ed} Edwards + {ec} ECDSA rows): range accepted on "
+        f"routes {routes}; flipped rows refused at the first; bitmaps match the host's, in "
+        f"{time.monotonic() - t0:.2f}s")
+    return {"commits": n_commits, "edwards_rows": n_commits * ed, "ecdsa_rows": n_commits * ec,
+            "seconds": round(time.monotonic() - t0, 2)}
 
 
 async def _blocksync(seed: int) -> dict:
@@ -571,6 +652,8 @@ def main(argv=None) -> int:
     else:
         phases["range"] = phase_range(args.seed)
         assert_device_served("range")
+        phases["mixed"] = phase_mixed(args.seed)
+        assert_device_served("mixed")
         phases["blocksync"] = asyncio.run(_blocksync(args.seed))
         assert_device_served("blocksync")
         phases["net4"] = asyncio.run(_net4())

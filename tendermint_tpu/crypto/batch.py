@@ -2,8 +2,10 @@
 
 `create_batch_verifier(pubkey)` returns the best available batch verifier for
 the key type: the TPU-backed JAX verifier for ed25519 when a TPU/accelerator
-backend is usable, otherwise a CPU loop verifier. secp256k1 does not support
-batching (matching the reference) — callers fall back to single verification.
+backend is usable, otherwise a CPU loop verifier. secp256k1 has no batch
+kernel (matching the reference: `supports_batch_verifier` is False for it),
+but the AdaptiveBatchVerifier does not refuse it: in a mixed batch its rows
+take a host lane (`_HostLane`) that runs beside the device partitions.
 """
 
 from __future__ import annotations
@@ -11,6 +13,8 @@ from __future__ import annotations
 import logging
 import os
 import threading
+import time
+from collections import Counter
 from operator import attrgetter, itemgetter
 
 from ..libs import trace
@@ -19,6 +23,7 @@ from ..libs.retry import CircuitBreaker
 from . import BatchVerifier, PubKey
 from .bls import KEY_TYPE as BLS12381
 from .ed25519 import KEY_TYPE as ED25519
+from .secp256k1 import KEY_TYPE as SECP256K1
 from .sr25519 import KEY_TYPE as SR25519
 
 #: key types sharing the Edwards-curve MSM kernel (one TPU dispatch)
@@ -28,6 +33,11 @@ _EDWARDS = (ED25519, SR25519)
 #: AdaptiveBatchVerifier partitions by scheme so mixed validator sets
 #: still funnel through one verifier object
 _BATCHABLE = (ED25519, SR25519, BLS12381)
+#: the route a key type WITHOUT a batch kernel is counted under
+#: (`backend_telemetry.record_route`): its rows verify one by one on the
+#: host lane, by design — never `cpu` (Edwards rows under the cut-off)
+#: and never `cpu-fallback` (a host re-verify after a device error)
+_HOST_LANE_ROUTES = {SECP256K1: "host-ecdsa"}
 #: the key of a (pub_key, msg, sig) item and a key's scheme, for C-level
 #: passes (`map`) over a handed-over list
 _KEY_OF = itemgetter(0)
@@ -71,6 +81,9 @@ def _verify_one(item: tuple[PubKey, bytes, bytes]) -> bool:
 
 
 _pool = None
+#: threads of the process's one host-verification pool (`_cpu_pool`): the
+#: host route of a CPUBatchVerifier and the host lane of a mixed batch
+_POOL_WIDTH = min(32, os.cpu_count() or 4)
 
 
 def _cpu_pool():
@@ -78,10 +91,7 @@ def _cpu_pool():
     if _pool is None:
         from concurrent.futures import ThreadPoolExecutor
 
-        _pool = ThreadPoolExecutor(
-            max_workers=min(32, os.cpu_count() or 4),
-            thread_name_prefix="sigverify",
-        )
+        _pool = ThreadPoolExecutor(max_workers=_POOL_WIDTH, thread_name_prefix="sigverify")
     return _pool
 
 
@@ -355,6 +365,14 @@ class AdaptiveBatchVerifier(BatchVerifier):
     else verifies on the host. Small commits therefore never pay a
     device round-trip or a first-call compile.
 
+    Rows of a key type that has NO batch kernel (secp256k1) are a third
+    partition, the host lane (`_HostLane`): `verify_signature` a row on
+    the process's `_cpu_pool()`, started BEFORE the device partitions
+    are routed and joined AFTER them, so it runs under their resolve,
+    prep, dispatch and wait and not behind them. A mixed validator set
+    therefore keeps its Edwards rows in one batch on the device;
+    verdicts land in the caller's order whichever lane gave them.
+
     Degradation: a device failure mid-batch (backend crash, kernel
     error) re-verifies the SAME partition on the CPU path — the caller
     sees the identical (ok, per-signature) result, never the error —
@@ -381,26 +399,14 @@ class AdaptiveBatchVerifier(BatchVerifier):
         self.last_dispatch = None
 
     def add(self, pub_key: PubKey, msg: bytes, sig: bytes) -> None:
-        scheme = pub_key.TYPE
-        if scheme not in _BATCHABLE:
-            raise ValueError(
-                f"adaptive batch verifier supports {_BATCHABLE}, got {scheme!r}"
-            )
-        self._schemes.add(scheme)
+        self._schemes.add(pub_key.TYPE)
         self._items.append((pub_key, msg, sig))
 
     def add_many(self, items: list[tuple[PubKey, bytes, bytes]]) -> None:
         """Bulk hand-over beside the reference's `add`: a whole list of
         (pub_key, msg, sig) in one step — one C-level pass for the key
-        types, one list extend — instead of a call a signature. Same
-        refusal as `add`, before anything is kept."""
-        schemes = set(map(_SCHEME_OF, map(_KEY_OF, items)))
-        if not schemes.issubset(_BATCHABLE):
-            raise ValueError(
-                f"adaptive batch verifier supports {_BATCHABLE}, got "
-                f"{sorted(schemes.difference(_BATCHABLE))!r}"
-            )
-        self._schemes |= schemes
+        types, one list extend — instead of a call a signature."""
+        self._schemes.update(map(_SCHEME_OF, map(_KEY_OF, items)))
         self._items.extend(items)
 
     def verify(self) -> tuple[bool, list[bool]]:
@@ -409,7 +415,7 @@ class AdaptiveBatchVerifier(BatchVerifier):
 
         items = self._items
         self.last_dispatch = None
-        if BLS12381 not in self._schemes:
+        if self._schemes.issubset(_EDWARDS):
             # every key is an Edwards key (every commit of an ed25519
             # chain): nothing to partition — the list goes on as it is,
             # and the route's verdicts are the answer
@@ -420,27 +426,39 @@ class AdaptiveBatchVerifier(BatchVerifier):
             LAST_ROUTE = self.last_route = route
             return all(results) and bool(results), results
         results = [False] * len(items)
-        edwards = [i for i, it in enumerate(items) if it[0].TYPE in _EDWARDS]
-        bls = [i for i, it in enumerate(items) if it[0].TYPE == BLS12381]
-        bres, route = self._verify_bls([items[i] for i in bls])
-        for i, ok in zip(bls, bres):
-            results[i] = ok
-        bt.record_route(route, len(bls))
-        if edwards:
-            eres, eroute = self._verify_edwards([items[i] for i in edwards], partitions=2)
-            for i, ok in zip(edwards, eres):
+        edwards, bls, host = [], [], []
+        for i, it in enumerate(items):
+            scheme = it[0].TYPE
+            (edwards if scheme in _EDWARDS else bls if scheme == BLS12381 else host).append(i)
+        partitions = bool(edwards) + bool(bls) + bool(host)
+        # the host lane first: it runs on the pool while this thread
+        # routes, prepares, dispatches and waits for the device partitions
+        lane = _HostLane([items[i] for i in host]) if host else None
+        routes = []
+
+        def settle(idxs, verdicts, route):
+            for i, ok in zip(idxs, verdicts):
                 results[i] = ok
-            bt.record_route(eroute, len(edwards))
-            if eroute != route:
-                route = "mixed"
-        LAST_ROUTE = self.last_route = route
+            bt.record_route(route, len(idxs))
+            routes.append(route)
+
+        if bls:
+            settle(bls, *self._verify_bls([items[i] for i in bls]))
+        if edwards:
+            settle(edwards, *self._verify_edwards([items[i] for i in edwards], partitions))
+        if lane is not None:
+            for i, ok in zip(host, lane.join()):
+                results[i] = ok
+            routes.extend(lane.routes)
+        LAST_ROUTE = self.last_route = routes[0] if len(set(routes)) == 1 else "mixed"
         return all(results) and bool(results), results
 
     def _verify_edwards(self, items, partitions: int) -> tuple[list[bool], str]:
         """The ed25519/sr25519 partition: shared-MSM TPU kernel when the
         batch clears the measured cutoff, host loop otherwise.
         `partitions` goes on the span: 1 = the verifier's list went
-        through whole, 2 = it was split by scheme beside a BLS part."""
+        through whole, else the parts it was split into by scheme (the
+        Edwards rows, a BLS part, the host lane: each counts one)."""
         with trace.span(
             "batch", "route", n=len(items), cutoff=MIN_TPU_BATCH, partitions=partitions
         ) as sp:
@@ -538,6 +556,61 @@ class AdaptiveBatchVerifier(BatchVerifier):
         return target.verify()
 
 
+#: rows to a pool task of the host lane: the lane is cut into tasks this
+#: long and the pool's threads take them as they come free, so a thread on
+#: a slower core takes fewer. secp256k1 verification drops the GIL for the
+#: length of its OpenSSL call (`crypto/secp256k1.py`), so the threads run
+#: side by side: read on the chip machine's 13-core host (PERF.md §6, PR
+#: 32), 6,528 rows take 2.97 s on one thread and 0.37 s on thirteen (0.42
+#: in thirteen equal slices). 64 rows are some 30 ms of one core against
+#: some 50 us to hand a task over
+_HOST_LANE_TASK_ROWS = 64
+
+
+def _verify_slice(items) -> list[bool]:
+    return [pk.verify_signature(msg, sig) for pk, msg, sig in items]
+
+
+class _HostLane:
+    """The rows of a batch whose key type has no batch kernel, verified
+    one by one (`pub_key.verify_signature`) on `_cpu_pool()`, in tasks of
+    `_HOST_LANE_TASK_ROWS` rows over the pool's width: started when made,
+    joined by `join()` once the caller has served the device partitions.
+    Leaves two rows in the flight recorder: `batch.host_lane`
+    [n, scheme, workers], from the lane's start to the end of its join,
+    and under it `batch.host_lane_wait` [n], the part of the join the
+    caller spent blocked — what the overlap did not hide. Counts its
+    rows under their scheme's own route (`_HOST_LANE_ROUTES`)."""
+
+    def __init__(self, items):
+        self._items = items
+        step = _HOST_LANE_TASK_ROWS
+        self._t0 = time.monotonic()
+        pool = _cpu_pool()
+        self._futs = [
+            pool.submit(_verify_slice, items[i : i + step]) for i in range(0, len(items), step)
+        ]
+        self._workers = min(_POOL_WIDTH, len(self._futs))
+        self.routes: list[str] = []
+
+    def join(self) -> list[bool]:
+        from . import backend_telemetry as bt
+
+        n = len(self._items)
+        with trace.span("batch", "host_lane_wait", n=n):
+            verdicts = [ok for f in self._futs for ok in f.result()]
+        by_scheme = Counter(map(_SCHEME_OF, map(_KEY_OF, self._items)))
+        for scheme, rows in by_scheme.items():
+            route = _HOST_LANE_ROUTES.get(scheme, f"host-{scheme}")
+            bt.record_route(route, rows)
+            self.routes.append(route)
+        trace.emit(
+            "batch", "host_lane", duration_s=time.monotonic() - self._t0,
+            n=n, scheme="+".join(sorted(by_scheme)), workers=self._workers,
+        )
+        return verdicts
+
+
 #: sentinel distinguishing "device attempt failed (breaker tripped)"
 #: from "breaker already open" in _device_guarded
 _DEVICE_FAILED = object()
@@ -632,11 +705,23 @@ def supports_batch_verifier(pub_key: PubKey) -> bool:
     """ed25519 and sr25519 batch through the Edwards MSM kernel
     (reference crypto/batch/batch.go:26 — same two types); bls12381
     batches through the pairing kernel / pure-Python path. secp256k1
-    does not batch (falls back to single verify)."""
+    has no batch kernel: False here, as in the reference — its rows in
+    an AdaptiveBatchVerifier take the host lane."""
     return pub_key.TYPE in _BATCHABLE
 
 
+def rows_by_lane(rows_by_scheme: dict[str, int]) -> tuple[int, int]:
+    """(Edwards rows, host-lane rows) of a count of rows by key type —
+    what the commit funnel notes on `validation.collect`."""
+    edwards = sum(n for scheme, n in rows_by_scheme.items() if scheme in _EDWARDS)
+    host = sum(n for scheme, n in rows_by_scheme.items() if scheme not in _BATCHABLE)
+    return edwards, host
+
+
 def create_batch_verifier(pub_key: PubKey) -> BatchVerifier:
+    """The batch verifier for a key type that has a batch kernel (the
+    reference's CreateBatchVerifier: a key type without one is refused
+    HERE; the verifier itself takes such rows beside the others)."""
     if pub_key.TYPE in _BATCHABLE:
         return AdaptiveBatchVerifier()
     raise ValueError(f"key type {pub_key.TYPE!r} does not support batch verification")

@@ -3,9 +3,21 @@
 Signatures are 64-byte compact (r||s, 32 bytes each, big-endian) with the
 low-S malleability rule enforced on both sign and verify, matching the
 reference (crypto/secp256k1/secp256k1_nocgo.go:21-48). Public keys are
-33-byte compressed SEC1. Like the reference, secp256k1 has no batch verifier
-in round 1 — commits fall back to single verification (the TPU ECDSA-recover
-kernel is a later milestone, see BASELINE.md config 4).
+33-byte compressed SEC1. Like the reference, secp256k1 has no batch kernel
+(`crypto.batch.supports_batch_verifier` is False for it): in a commit or a
+commit range its rows take the AdaptiveBatchVerifier's host lane — one
+`verify_signature` a row on the host pool, beside the Edwards rows' device
+dispatch — so a mixed validator set keeps its ed25519 majority in the range
+batch (a secp256k1 device kernel is a later milestone, see BASELINE.md
+config 4 and PERF.md §7).
+
+Verification calls the system's OpenSSL (`libcrypto`) through `ctypes`, one
+`ECDSA_verify` a signature with the parsed key kept per thread: a `ctypes`
+call drops the GIL for its length, which `cryptography`'s binding does not
+(its verify holds the GIL from end to end, so a pool of threads verifies no
+faster than one: PERF.md §6, PR 32) — the host lane's threads therefore run
+side by side. Where no `libcrypto` with the curve is found, verification
+goes through `cryptography`, same verdicts, one thread's worth of speed.
 
 When the OpenSSL-backed `cryptography` package is absent the module degrades
 to the pure-Python RFC 6979 implementation in softcrypto.py (deterministic
@@ -13,7 +25,10 @@ nonces on both paths, so signatures are stable either way)."""
 
 from __future__ import annotations
 
+import ctypes
+import ctypes.util
 import secrets
+import threading
 
 try:
     from cryptography.exceptions import InvalidSignature
@@ -47,6 +62,78 @@ N = 0xFFFFFFFFFFFFFFFFFFFFFFFFFFFFFFFEBAAEDCE6AF48A03BBFD25E8CD0364141
 HALF_N = N // 2
 
 
+#: OpenSSL's number for the curve (obj_mac.h NID_secp256k1)
+_NID_SECP256K1 = 714
+#: parsed keys a thread keeps before it frees them all and starts again
+_KEY_CACHE_MAX = 1024
+
+
+def _load_libcrypto():
+    """The system's libcrypto with the calls verification needs declared,
+    or None where there is none or it lacks the curve."""
+    try:
+        lib = ctypes.CDLL(ctypes.util.find_library("crypto") or "libcrypto.so.3")
+        vp = ctypes.c_void_p
+        lib.EC_KEY_new_by_curve_name.restype = vp
+        lib.EC_KEY_new_by_curve_name.argtypes = [ctypes.c_int]
+        lib.EC_KEY_oct2key.restype = ctypes.c_int
+        lib.EC_KEY_oct2key.argtypes = [vp, ctypes.c_char_p, ctypes.c_size_t, vp]
+        lib.EC_KEY_free.restype = None
+        lib.EC_KEY_free.argtypes = [vp]
+        lib.ECDSA_verify.restype = ctypes.c_int
+        lib.ECDSA_verify.argtypes = [
+            ctypes.c_int, ctypes.c_char_p, ctypes.c_int, ctypes.c_char_p, ctypes.c_int, vp
+        ]
+        lib.ERR_clear_error.restype = None
+        key = lib.EC_KEY_new_by_curve_name(_NID_SECP256K1)
+        if not key:
+            return None
+        lib.EC_KEY_free(key)
+        return lib
+    except (OSError, AttributeError):
+        return None
+
+
+#: loaded with the module, before any pool thread can ask for it
+_LIBCRYPTO = _load_libcrypto()
+_parsed = threading.local()
+
+
+def _ec_key(lib, data: bytes):
+    """This thread's EC_KEY for a 33-byte compressed point (None where the
+    bytes are no point of the curve). Per thread, so that no OpenSSL
+    object is ever shared between threads."""
+    keys = _parsed.__dict__.setdefault("keys", {})
+    key = keys.get(data)
+    if key is None and data not in keys:
+        if len(keys) >= _KEY_CACHE_MAX:
+            for old in keys.values():
+                if old:
+                    lib.EC_KEY_free(old)
+            keys.clear()
+        key = lib.EC_KEY_new_by_curve_name(_NID_SECP256K1)
+        if not lib.EC_KEY_oct2key(key, data, len(data), None):
+            lib.EC_KEY_free(key)
+            lib.ERR_clear_error()
+            key = None
+        keys[data] = key
+    return key
+
+
+def _der_int(b: bytes) -> bytes:
+    b = b.lstrip(b"\0")
+    if b[0] & 0x80:
+        b = b"\0" + b
+    return b"\x02" + bytes((len(b),)) + b
+
+
+def _der_signature(sig: bytes) -> bytes:
+    """r||s (each 32 bytes, neither zero) as the DER SEQUENCE of two
+    INTEGERs that OpenSSL's ECDSA_verify takes."""
+    body = _der_int(sig[:32]) + _der_int(sig[32:])
+    return b"\x30" + bytes((len(body),)) + body
+
+
 class Secp256k1PubKey(PubKey):
     TYPE = KEY_TYPE
 
@@ -64,6 +151,16 @@ class Secp256k1PubKey(PubKey):
         r = int.from_bytes(sig[:32], "big")
         s = int.from_bytes(sig[32:], "big")
         if not (0 < r < N and 0 < s <= HALF_N):  # reject high-S (malleability)
+            return False
+        lib = _LIBCRYPTO
+        if lib is not None:
+            key = _ec_key(lib, self._bytes)
+            if key is None:
+                return False
+            der = _der_signature(sig)
+            if lib.ECDSA_verify(0, sha256(msg), 32, der, len(der), key) == 1:
+                return True
+            lib.ERR_clear_error()
             return False
         if not _HAVE_OPENSSL:
             return softcrypto.secp256k1_verify(self._bytes, sha256(msg), r, s)

@@ -14,10 +14,13 @@ BatchVerifier call (one TPU kernel launch):
                                and requires `trust_level` (default 1/3) of
                                its total power.
 
-Batch verification engages when the key type supports it and there are at
-least BATCH_VERIFY_THRESHOLD signatures (reference types/validation.go:12);
-otherwise single verification. On batch failure the per-signature bitmap
-pinpoints the offending signature for the error message.
+Batch verification engages when there are at least BATCH_VERIFY_THRESHOLD
+signatures (reference types/validation.go:12), whatever the validators' key
+types: the one verifier takes every row, and sends those of a key type
+without a batch kernel (secp256k1 in a mixed set) down its host lane beside
+the others' dispatch (crypto/batch.AdaptiveBatchVerifier). Otherwise single
+verification. On batch failure the per-signature bitmap pinpoints the
+offending signature for the error message.
 """
 
 from __future__ import annotations
@@ -41,14 +44,14 @@ class _CommitVerifier:
     """Batch-verifier shim for the verify_commit* funnel: routes the
     collected signatures through the node's VerifyHub when one is
     running (cross-subsystem micro-batching + gossip-duplicate dedup),
-    and otherwise through the local `create_batch_verifier` path — the
+    and otherwise through a local AdaptiveBatchVerifier — the
     verdicts are identical, the hub only changes where/when the batch
     launches. `lane` picks the hub scheduler lane: block-sync /
     state-sync / light-client callers submit as "backfill" so bulk
-    catch-up ranges never starve live consensus."""
+    catch-up ranges never starve live consensus. Rows of any key type:
+    the hub and the AdaptiveBatchVerifier both partition by scheme."""
 
-    def __init__(self, pub_key, lane: str = "live"):
-        self._pub_key = pub_key
+    def __init__(self, lane: str = "live"):
         self._lane = lane
         self._items: list[tuple] = []
         #: where the last verify() went: "hub" or "local"
@@ -74,7 +77,7 @@ class _CommitVerifier:
                     e,
                     len(self._items),
                 )
-        bv = crypto_batch.create_batch_verifier(self._pub_key)
+        bv = crypto_batch.AdaptiveBatchVerifier()
         bv.add_many(self._items)
         return bv.verify()
 
@@ -92,10 +95,8 @@ def _basic_commit_checks(
         )
 
 
-def _should_batch_verify(vals: ValidatorSet, commit: Commit) -> bool:
-    return commit.size() >= BATCH_VERIFY_THRESHOLD and all(
-        crypto_batch.supports_batch_verifier(v.pub_key) for v in vals.validators
-    )
+def _should_batch_verify(commit: Commit) -> bool:
+    return commit.size() >= BATCH_VERIFY_THRESHOLD
 
 
 def verify_commit(
@@ -186,7 +187,7 @@ def _verify(
         _verify_aggregate(
             chain_id, vals, commit, voting_power_needed, lookup_by_index
         )
-    elif _should_batch_verify(vals, commit):
+    elif _should_batch_verify(commit):
         _verify_batch(
             chain_id, vals, commit, voting_power_needed, count_all_signatures,
             lookup_by_index, lane=lane,
@@ -287,7 +288,7 @@ def _verify_batch(
     chain_id, vals, commit, voting_power_needed, count_all_signatures,
     lookup_by_index, lane="live",
 ) -> None:
-    bv = _CommitVerifier(vals.validators[0].pub_key, lane=lane)
+    bv = _CommitVerifier(lane=lane)
     sign_bytes = commit.sign_bytes(chain_id)
     tallied = 0
     added = 0
@@ -336,37 +337,38 @@ def verify_commit_range(
     entry index) on failure."""
     if not entries:
         return
-    # the verifier is created LAZILY, from the first batchable entry: a
-    # mixed ed25519+secp256k1 validator set routes every commit through
-    # the individual path below, and eagerly keying the verifier off
-    # validators[0] crashed whenever address ordering put a secp256k1
-    # key first (seen as a restarted node's block-sync dying mid-e2e)
+    # made at the first commit that has rows for it: a range stopped by a
+    # basic check, or all of aggregate commits, builds none
     bv = None
     added = 0
     templates = 0
+    #: rows by key type, for the span: the verifier partitions them itself
+    by_scheme: dict[str, int] = {}
     # the whole collect loop is ONE span (basic checks, sign-bytes, tally,
     # add): thousands of signatures a range, never a row each
     with trace.span("validation", "collect", commits=len(entries)) as sp:
         for ei, (vals, block_id, height, commit) in enumerate(entries):
             try:
                 _basic_commit_checks(vals, block_id, height, commit)
-                if commit.is_aggregate() or not _should_batch_verify(vals, commit):
+                if commit.is_aggregate() or not _should_batch_verify(commit):
                     # aggregate commits are one indivisible pairing product
-                    # (verdict-cached in the hub); mixed/secp256k1 sets
-                    # verify individually
+                    # (verdict-cached in the hub); a one-validator commit
+                    # verifies singly
                     verify_commit_light(
                         chain_id, vals, block_id, height, commit, lane=lane
                     )
                     continue
                 if bv is None:
-                    bv = _CommitVerifier(vals.validators[0].pub_key, lane=lane)
+                    bv = _CommitVerifier(lane=lane)
                 voting_power_needed = vals.total_voting_power() * 2 // 3
                 sign_bytes = commit.sign_bytes(chain_id)
                 tallied = 0
                 for idx, cs, val in _iter_entries(vals, commit, lookup_by_index=True):
                     if not cs.is_commit():
                         continue
-                    bv.add(val.pub_key, sign_bytes(idx), cs.signature)
+                    pub_key = val.pub_key
+                    bv.add(pub_key, sign_bytes(idx), cs.signature)
+                    by_scheme[pub_key.TYPE] = by_scheme.get(pub_key.TYPE, 0) + 1
                     added += 1
                     tallied += val.voting_power
                     if tallied > voting_power_needed:
@@ -380,7 +382,8 @@ def verify_commit_range(
             except InvalidCommitError as e:
                 e.failed_index = ei
                 raise
-        sp.set(sigs=added, templates=templates)
+        edwards, host = crypto_batch.rows_by_lane(by_scheme)
+        sp.set(sigs=added, templates=templates, edwards=edwards, host=host)
     if not added:
         return
     with trace.span("validation", "verify", sigs=added) as sp:

@@ -325,18 +325,24 @@ def test_mixed_edwards_and_bls_verifier_is_two_partitions(recorder):
     assert [(a["partitions"], a["n"]) for a in _route_spans(recorder)] == [(1, 6), (2, 6)]
 
 
-def test_add_many_refuses_what_add_refuses():
+def test_add_many_takes_what_add_takes(recorder):
+    """A key type with no batch kernel is no longer refused: its rows go
+    down the host lane beside the Edwards partition, verdicts in the
+    caller's order, the partition count on the one `batch.route`."""
     from tendermint_tpu.crypto import batch as cb
     from tendermint_tpu.crypto.secp256k1 import Secp256k1PrivKey
 
     priv = Secp256k1PrivKey(b"\x09" * 32)
-    bad = (priv.pub_key(), b"m", priv.sign(b"m"))
-    bv = cb.AdaptiveBatchVerifier()
-    with pytest.raises(ValueError):
-        bv.add(*bad)
-    with pytest.raises(ValueError):
-        bv.add_many(_signed(3) + [bad])
-    assert bv.verify() == (False, [])  # a refused hand-over kept nothing
+    good = (priv.pub_key(), b"m", priv.sign(b"m"))
+    bad = (priv.pub_key(), b"other", good[2])
+    one = cb.AdaptiveBatchVerifier()
+    for it in _signed(3) + [good]:
+        one.add(*it)
+    assert one.verify() == (True, [True] * 4)
+    many = cb.AdaptiveBatchVerifier()
+    many.add_many([bad] + _signed(3) + [good])
+    assert many.verify() == (False, [False, True, True, True, True])
+    assert [(a["partitions"], a["n"]) for a in _route_spans(recorder)] == [(2, 3), (2, 3)]
 
 
 def test_commit_verifier_hands_its_list_over_in_one_step(monkeypatch):
@@ -350,7 +356,7 @@ def test_commit_verifier_hands_its_list_over_in_one_step(monkeypatch):
     monkeypatch.setattr(cb.AdaptiveBatchVerifier, "add",
                         lambda *a: pytest.fail("one add a signature"))
     items = _signed(9)
-    cv = validation._CommitVerifier(items[0][0])
+    cv = validation._CommitVerifier()
     for it in items:
         cv.add(*it)
     assert cv.verify() == (True, [True] * 9) and calls == [9] and cv.via == "local"

@@ -282,6 +282,7 @@ class TestBlockSyncTree:
         # one sign-bytes template a commit, applied once per signature
         assert collect["attrs"] == {
             "commits": n, "sigs": n * needed, "templates": n,
+            "edwards": n * needed, "host": 0,  # rows by lane: an ed25519 chain has one
         }
         assert root["attrs"]["sigs"] == n * N_VALS
         assert next(x for x in mine if _key(x) == "validation.verify")["attrs"]["via"] == "hub"

@@ -570,7 +570,7 @@ class StubVerifier:
     made: list = []
     verdict = None  # None: all good; else the index (in add order) that fails
 
-    def __init__(self, pub_key, lane="live"):
+    def __init__(self, lane="live"):
         self.items = []
         self.via = "stub"
         StubVerifier.made.append(self)
@@ -780,7 +780,8 @@ def test_collect_span_counts_one_template_a_commit(stub):
         trace.RECORDER.enabled = old
         trace.RECORDER.clear()
     (row,) = rows
-    assert row["attrs"] == {"commits": 5, "sigs": 5 * 101, "templates": 5}
+    assert row["attrs"] == {"commits": 5, "sigs": 5 * 101, "templates": 5,
+                            "edwards": 5 * 101, "host": 0}
     assert row["attrs"]["sigs"] == 101 * row["attrs"]["templates"]
     assert len(stub.made[0].items) == 5 * 101
 
