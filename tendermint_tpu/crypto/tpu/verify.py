@@ -26,6 +26,7 @@ from __future__ import annotations
 import hashlib
 import os
 import threading
+from contextlib import contextmanager
 from functools import partial
 from math import gcd as _gcd
 from typing import NamedTuple
@@ -875,6 +876,61 @@ def last_dispatch_info() -> dict | None:
     return getattr(_dispatch_local, "info", None)
 
 
+class _InFlightWork:
+    """One registration of `while_in_flight`: the callable, whether a
+    dispatch loop has run it, and what it raised."""
+
+    __slots__ = ("fn", "ran", "error")
+
+    def __init__(self, fn):
+        self.fn = fn
+        self.ran = False
+        self.error: Exception | None = None
+
+
+@contextmanager
+def while_in_flight(fn):
+    """Hand the dispatch loop host work to do while its chunks are on the
+    device: inside the `with`, the first `_dispatch_and_collect` THIS
+    thread runs calls `fn()` once, after its last chunk's dispatch and
+    before its first collect — the one stretch in which the caller would
+    otherwise only wait. Kept in `_dispatch_local`, so the registration
+    crosses the call chain between a caller and the dispatch without an
+    argument, and no other thread (a VerifyHub runner) ever sees it.
+
+    `fn` is work the caller owes anyway. Where no device dispatch goes
+    out (the `cpu` route, a host lane, an open breaker) it does not run,
+    and the caller does it where it always did; the registration yielded
+    says which (`ran`). What `fn` raises is not a device fault: the
+    dispatch loop collects as if it had returned, and the error is raised
+    here, on the caller's side, when the `with` ends (unless the body
+    raised: that error wins)."""
+    work = _InFlightWork(fn)
+    prev = getattr(_dispatch_local, "work", None)
+    _dispatch_local.work = work
+    try:
+        yield work
+    finally:
+        _dispatch_local.work = prev
+    if work.error is not None:
+        raise work.error
+
+
+def _run_in_flight_work(work: _InFlightWork, chunks: int) -> None:
+    """Run a registration, once: a degrade retry re-enters the dispatch
+    loop and must not run it again. Whatever it raises is kept for
+    `while_in_flight`'s exit and never reaches `crypto/batch`'s device
+    guard, which would count it against the breaker and re-verify the
+    batch on the host."""
+    work.ran = True
+    with trace.span("tpu", "fill", chunks=chunks) as sp:
+        try:
+            work.fn()
+        except Exception as e:  # noqa: BLE001 — re-raised at the caller's exit
+            work.error = e
+        sp.set(ran=work.error is None)
+
+
 def verify_resolved(
     entries: list[ResolvedSig | None], pad_multiple: int = 1
 ) -> np.ndarray:
@@ -914,6 +970,13 @@ def _dispatch_and_collect(n: int, get_entries, pad_multiple: int) -> np.ndarray:
     every chunk is in flight; a failed equation falls back to the
     per-signature kernel for that chunk alone.
 
+    The in-flight phase: between the two loops — every chunk dispatched,
+    none collected — the calling thread's `while_in_flight` registration
+    runs, under a `tpu.fill` [chunks, ran] span. Only if a chunk really
+    went out (a loop whose every dispatch raised has nothing in flight),
+    once a registration (a degrade retry re-enters this function), and
+    never as a device fault: what it raises waits for the caller.
+
     Mesh degradation: a sharded chunk that raises (a chip died mid-MSM)
     hands the error to mesh.on_dispatch_failure, which probes every
     device and trips the breakers of the dead ones. When membership
@@ -952,6 +1015,11 @@ def _dispatch_and_collect(n: int, get_entries, pad_multiple: int) -> np.ndarray:
         except Exception as e:  # noqa: BLE001 — settled at collect time
             res = e
         in_flight.append((chunk, res))
+    work = getattr(_dispatch_local, "work", None)
+    if work is not None and not work.ran and any(
+        not isinstance(res, Exception) for _chunk, res in in_flight
+    ):
+        _run_in_flight_work(work, len(in_flight))
     outs = []
     shards_total = [0] * len(ids) if ids else None
     retried = False

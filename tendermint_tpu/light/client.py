@@ -15,7 +15,9 @@ import logging
 import time
 from dataclasses import dataclass
 from fractions import Fraction
+from functools import partial
 
+from ..crypto.tpu.verify import while_in_flight
 from ..libs import trace
 from ..store.db import DB, MemDB
 from . import verifier
@@ -33,6 +35,18 @@ FETCH_CONCURRENCY = 16
 
 async def _as_ready(value):
     return value
+
+
+def _encode_ahead(chain: list[LightBlock], encoded: dict[int, bytes]) -> None:
+    """`LightBlock.encode()` of every block of `chain`, by height, into
+    `encoded`: the one place the trusted store's bytes are made when they
+    are made ahead of the store step."""
+    size = 0
+    with trace.span("light", "encode_ahead", n=len(chain)) as sp:
+        for lb in chain:
+            raw = encoded[lb.height] = lb.encode()
+            size += len(raw)
+        sp.set(bytes=size)
 
 
 async def _gather_cancelling(coros: list) -> list:
@@ -88,8 +102,16 @@ class TrustedStore:
     def __init__(self, db: DB | None = None):
         self.db = db or MemDB()
 
-    def save(self, lb: LightBlock) -> None:
-        self.db.set(_LB_PREFIX + lb.height.to_bytes(8, "big"), lb.encode())
+    def save(self, lb: LightBlock, encoded: bytes | None = None) -> None:
+        """Persist `lb` under its height. `encoded`, where given, is what
+        `lb.encode()` returned to the caller earlier (the light client
+        encodes a window's blocks while the window's signatures are on
+        the device) and is stored as it is; `save` itself is still called
+        only after the witness cross-check."""
+        self.db.set(
+            _LB_PREFIX + lb.height.to_bytes(8, "big"),
+            lb.encode() if encoded is None else encoded,
+        )
 
     def get(self, height: int) -> LightBlock | None:
         raw = self.db.get(_LB_PREFIX + height.to_bytes(8, "big"))
@@ -186,19 +208,34 @@ class LightClient:
         # nothing primary-supplied may reach the trusted store until the
         # witness cross-check has passed, or a divergence would leave
         # forged intermediate headers behind as future trust anchors.
+        # The same holds for `encoded` (height -> LightBlock.encode()): the
+        # sequential strategy makes a window's bytes AHEAD, while that
+        # window's signatures are on the device, but they are a local of
+        # this call like `pending` — never on self, never in the store —
+        # and a VerificationError, a Divergence or a cancelled fetch drops
+        # them with the frame. They are persisted below, after
+        # _detect_divergence, in the order and at the place the blocks
+        # always were; a block with no bytes here (no dispatch went out,
+        # the skipping or backwards strategy) is encoded there as before.
         pending: list[LightBlock] = []
+        encoded: dict[int, bytes] = {}
         if target.height < latest.height:
             verified = await self._verify_backwards(target, latest, pending)
         elif self.sequential:
-            verified = await self._verify_sequential(latest, target, now_ns, pending)
+            verified = await self._verify_sequential(
+                latest, target, now_ns, pending, encoded
+            )
         else:
             verified = await self._verify_skipping(latest, target, now_ns, pending)
         with trace.span("light", "detect_divergence", height=verified.height):
             await self._detect_divergence(verified, now_ns, trust_anchor=latest)
-        with trace.span("light", "store", n=len(pending) + 1):
-            for lb in pending:
-                self.store.save(lb)
-            self.store.save(verified)
+        to_store = [*pending, verified]
+        with trace.span(
+            "light", "store", n=len(to_store),
+            ahead=sum(lb.height in encoded for lb in to_store),
+        ):
+            for lb in to_store:
+                self.store.save(lb, encoded.get(lb.height))
         return verified
 
     async def update(self, now_ns: int | None = None) -> LightBlock:
@@ -214,11 +251,19 @@ class LightClient:
         target: LightBlock,
         now_ns: int,
         pending: list[LightBlock],
+        encoded: dict[int, bytes],
     ) -> LightBlock:
         """Reference verifySequential client.go:546, bulked: headers are
         fetched in windows and each window's commits are proven in ONE
         range-batched call (verifier.verify_adjacent_chain) — the
-        structural trust chain is still checked strictly in order."""
+        structural trust chain is still checked strictly in order.
+
+        While a window's signatures are on the device this thread would
+        only wait, so it is handed the work the session owes next: the
+        encoding of that window's blocks for the trusted store
+        (`while_in_flight`; they have passed the link checks by then).
+        The bytes go into `encoded`, the caller's local; where no device
+        dispatch goes out nothing is encoded here."""
         window = 128
         h = trusted.height + 1
         while h <= target.height:
@@ -242,9 +287,12 @@ class LightClient:
                             for hh in range(h, top + 1)
                         ]
                     )
-                trusted = verifier.verify_adjacent_chain(
-                    self.chain_id, trusted, chain, self.trust_options.period_ns, now_ns
-                )
+                # no await inside: the registration is this thread's, and
+                # must not be seen by another task's dispatch
+                with while_in_flight(partial(_encode_ahead, chain, encoded)):
+                    trusted = verifier.verify_adjacent_chain(
+                        self.chain_id, trusted, chain, self.trust_options.period_ns, now_ns
+                    )
             pending.extend(chain)
             h = top + 1
         return trusted

@@ -290,14 +290,14 @@ class _CountingStore(TrustedStore):
         super().__init__(db)
         self._owner = owner
 
-    def save(self, lb) -> None:
+    def save(self, lb, encoded: bytes | None = None) -> None:
         from .client import _LB_PREFIX
 
         # re-saves don't count (the client persists the sync target both
         # via its pending buffer and as the verified head)
         if not self.db.has(_LB_PREFIX + lb.height.to_bytes(8, "big")):
             self._owner.stats["hops_verified"] += 1
-        super().save(lb)
+        super().save(lb, encoded)
 
 
 class LightD(Service):

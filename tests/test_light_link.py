@@ -11,17 +11,28 @@ whose hash the header AND the predecessor carry; (b) the `light.link`
 span's `validated` counts the full validations; (c) a second set object
 with the same keys and powers is pinned; (d) `ValidatorSet.validate_basic`
 keeps its errors and their precedence; (e) the single-step paths carry no
-pin."""
+pin.
+
+(f) (ISSUE 34) a sequential session encodes a window's light blocks while
+the window's signatures are on the device (`verify.while_in_flight`, here
+over `tests/stub_dispatch.py`'s stub kernels): the trusted store holds the
+same bytes with the mechanism engaged, partly engaged and on the host
+route, and nothing of a walk that a bad commit or a diverging witness
+ends, although bytes had been made ahead."""
 
 import dataclasses
 import random
 
 import pytest
+import stub_dispatch
 
 from tendermint_tpu import testing as tt
+from tendermint_tpu.crypto import backend_telemetry as bt
 from tendermint_tpu.crypto.hashes import sha256
+from tendermint_tpu.crypto.tpu import verify as tpu_verify
 from tendermint_tpu.libs import trace
 from tendermint_tpu.light import verifier
+from tendermint_tpu.light.client import Divergence, LightClient, TrustedStore, TrustOptions
 from tendermint_tpu.light.types import LightBlock, SignedHeader
 from tendermint_tpu.light.verifier import VerificationError
 from tendermint_tpu.types.block import BlockID, Header, PartSetHeader
@@ -401,3 +412,174 @@ def test_single_step_validates_in_full(call, fault, full_validations):
         with pytest.raises(kind, match=message):
             run()
         assert len(full_validations) == 1
+
+
+# -- (f) the store's bytes, made ahead under a dispatch ------------------------------
+
+SESSION_N = 300  # trusting height 1: windows of 128, 128 and 43 headers
+WINDOWS = [128, 128, 43]
+SESSION_BLOCKS = _forge(_steps(SESSION_N, lambda h: SET_A))
+QUORUM = 3  # signatures of SET_A's four that pass 2/3
+
+
+class _Memory:
+    """A provider serving light blocks from a list (index height - 1)."""
+
+    def __init__(self, blocks):
+        self.blocks = blocks
+
+    def chain_id(self):
+        return CHAIN_ID
+
+    async def light_block(self, height):
+        return self.blocks[(height or len(self.blocks)) - 1]
+
+    async def report_evidence(self, ev):
+        pass
+
+
+#: route -> the cut-off the device route starts at (None: the host serves
+#: all); "partly" sits between the last window's signatures and a whole one's
+ROUTES = {"engaged": 1, "partly": 64 * QUORUM, "host": None}
+
+
+@pytest.fixture
+def session(monkeypatch, recorder):
+    """A sequential client over `blocks` on one of ROUTES; `saves` collects
+    (height, bytes handed in or None) of every `TrustedStore.save`."""
+    saves = []
+    real = TrustedStore.save
+
+    def save(self, lb, encoded=None):
+        saves.append((lb.height, encoded))
+        real(self, lb, encoded)
+
+    monkeypatch.setattr(TrustedStore, "save", save)
+
+    def make(route, blocks, witness=None):
+        if ROUTES[route] is not None:
+            # whole windows a chunk, so a window is one dispatch loop
+            stub_dispatch.install_kernels(monkeypatch, [], max_bucket=8192)
+            stub_dispatch.install_device_route(monkeypatch, cutoff=ROUTES[route])
+        return LightClient(
+            CHAIN_ID, TrustOptions(PERIOD_NS, 1, blocks[0].header.hash()),
+            _Memory(blocks), [_Memory(witness or blocks)], sequential=True,
+        )
+
+    make.saves = saves
+    yield make
+    bt.reset()
+
+
+def _stored(client) -> dict:
+    return {int.from_bytes(k[-8:], "big"): v for k, v in client.store.db.iterate(b"lb/", b"lb0")}
+
+
+def _light_spans(recorder, name):
+    return [x["attrs"] for x in recorder.dump(subsystem="light") if x["name"] == name]
+
+
+async def _run(client, height=SESSION_N):
+    return await client.verify_light_block_at_height(height, _now(SESSION_BLOCKS))
+
+
+@pytest.mark.asyncio
+@pytest.mark.parametrize("route", list(ROUTES))
+async def test_session_stores_the_same_bytes_on_every_route(route, session, recorder):
+    client = session(route, SESSION_BLOCKS)
+    head = await _run(client)
+    assert head.height == SESSION_N
+    want = {lb.height: lb.encode() for lb in SESSION_BLOCKS}
+    assert _stored(client) == want
+    # the anchor, then every block of the walk in order, then the head again
+    assert [h for h, _raw in session.saves] == [1, *range(2, SESSION_N + 1), SESSION_N]
+    ahead_windows = {"engaged": WINDOWS, "partly": WINDOWS[:2], "host": []}[route]
+    encoded = _light_spans(recorder, "encode_ahead")
+    assert [x["n"] for x in encoded] == ahead_windows
+    first = 2
+    for x in encoded:
+        assert x["bytes"] == sum(len(want[h]) for h in range(first, first + x["n"]))
+        first += x["n"]
+    fills = [x["attrs"] for x in recorder.dump(subsystem="tpu") if x["name"] == "fill"]
+    assert fills == [{"chunks": 1, "ran": True}] * len(ahead_windows)
+    # bytes made ahead are the ones saved, and the rest is encoded at the store
+    (store,) = _light_spans(recorder, "store")
+    handed_in = [(h, raw) for h, raw in session.saves[1:] if raw is not None]
+    assert all(raw == want[h] for h, raw in handed_in)
+    at_store = len(session.saves) - 1 - len(handed_in)
+    head_twice = 1 if route == "engaged" else 0
+    assert store == {"n": SESSION_N, "ahead": sum(ahead_windows) + head_twice}
+    assert store["ahead"] == len(handed_in) and store["ahead"] + at_store == store["n"]
+    if route != "host":
+        assert bt.BACKEND["fallbacks"] == 0 and "cpu-fallback" not in bt.ROUTES
+
+
+def _with_refused_commit(blocks, height):
+    """`blocks` with one signature of the quorum at `height` given an
+    s >= L, which the host's prep (and so the stub's bitmap) refuses."""
+    lb = blocks[height - 1]
+    commit = lb.signed_header.commit
+    sigs = list(commit.signatures)
+    sigs[1] = dataclasses.replace(sigs[1], signature=sigs[1].signature[:32] + b"\xff" * 32)
+    bad = dataclasses.replace(commit, signatures=tuple(sigs))
+    out = list(blocks)
+    out[height - 1] = LightBlock(SignedHeader(lb.header, bad), lb.validators)
+    return out
+
+
+@pytest.mark.asyncio
+@pytest.mark.parametrize("route", ["engaged", "host"])
+@pytest.mark.parametrize("height", [77, 200, 290])
+async def test_a_refused_commit_leaves_nothing_of_the_walk(route, height, session, recorder):
+    client = session(route, _with_refused_commit(SESSION_BLOCKS, height))
+    with pytest.raises(VerificationError, match=f"invalid commit at height {height}:"):
+        await _run(client)
+    assert _stored(client) == {1: SESSION_BLOCKS[0].encode()}
+    assert [h for h, _raw in session.saves] == [1]
+    assert _light_spans(recorder, "store") == []
+    # every window up to the refused one was encoded ahead, and dropped
+    windows = WINDOWS[: 1 + (height > 129) + (height > 257)]
+    encoded = [x["n"] for x in _light_spans(recorder, "encode_ahead")]
+    assert encoded == (windows if route == "engaged" else [])
+    assert getattr(tpu_verify._dispatch_local, "work", None) is None
+
+
+@pytest.mark.asyncio
+@pytest.mark.parametrize("route", ["engaged", "host"])
+async def test_a_diverging_witness_leaves_only_the_anchor(route, session, recorder):
+    """The witness serves another, validly signed header at the target
+    height: Divergence, after every window's bytes were made ahead, and
+    none of them in the store."""
+    steps = _steps(SESSION_N, lambda h: SET_A)
+    lb = SESSION_BLOCKS[-1]
+    evil = dataclasses.replace(lb.header, app_hash=sha256(b"evil"))
+    bid = BlockID(evil.hash(), lb.signed_header.commit.block_id.part_set_header)
+    commit = tt.make_commit(CHAIN_ID, SESSION_N, 0, bid, *steps[-1].signer,
+                            timestamp_ns=evil.time_ns)
+    fork = SESSION_BLOCKS[:-1] + [LightBlock(SignedHeader(evil, commit), lb.validators)]
+    client = session(route, SESSION_BLOCKS, witness=fork)
+    with pytest.raises(Divergence):
+        await _run(client)
+    assert _stored(client) == {1: SESSION_BLOCKS[0].encode()}
+    assert [h for h, _raw in session.saves] == [1]
+    encoded = [x["n"] for x in _light_spans(recorder, "encode_ahead")]
+    assert encoded == (WINDOWS if route == "engaged" else [])
+    assert _light_spans(recorder, "store") == []
+
+
+@pytest.mark.asyncio
+@pytest.mark.parametrize("strategy", ["skipping", "backwards"])
+async def test_other_strategies_encode_at_the_store(strategy, session, recorder):
+    client = session("engaged", SESSION_BLOCKS)
+    if strategy == "skipping":
+        client.sequential = False
+        await _run(client, 40)
+    else:
+        client.trust_options = TrustOptions(PERIOD_NS, 40, SESSION_BLOCKS[39].header.hash())
+        await _run(client, 30)
+    assert _light_spans(recorder, "encode_ahead") == []
+    (store,) = _light_spans(recorder, "store")
+    assert store["ahead"] == 0 and store["n"] >= 2
+    assert all(raw is None for _h, raw in session.saves)
+    stored = _stored(client)
+    assert all(stored[h] == SESSION_BLOCKS[h - 1].encode() for h in stored)

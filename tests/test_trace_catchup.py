@@ -350,7 +350,8 @@ class TestLightTree:
         outside = {_key(x): x for x in spans if x["trace_id"] != root["trace_id"]
                    and x["subsystem"] == "light"}
         assert set(outside) == {"light.detect_divergence", "light.store"}
-        assert outside["light.store"]["attrs"] == {"n": 30}
+        # host route: no dispatch went out, so nothing was encoded ahead
+        assert outside["light.store"]["attrs"] == {"n": 30, "ahead": 0}
         direct = sum(x["duration_ms"] for x in mine if x["parent_id"] == root["span_id"])
         assert 0 <= root["duration_ms"] - direct <= 0.1 * root["duration_ms"] + 2.0
 
