@@ -7,10 +7,14 @@ channel 0x40) — restructured as the TPU pipeline:
 
 The reference verifies and applies one block per poolRoutine tick
 (reactor.go:439-568); here a contiguous window of up to `window` blocks
-is verified in a single batched call, then applied in order. Validator-
-set changes inside a window are handled safely: each block's assumed
-validator hash is checked just before apply, and a mismatch triggers
-individual re-verification with the true set."""
+is verified in batched calls, then applied in order. A run is PLANNED
+before it is verified: after height h the state holds the validator sets
+of h+1 and h+2, and every fetched header names its own set
+(`validators_hash`), so each commit is verified against the set its
+header names — as many blocks as name one of those two sets in ONE call
+— and the run is cut where a header names a third; the rest is planned
+again once the apply has derived the next sets. No commit is ever
+verified against a set the state did not derive, and none twice."""
 
 from __future__ import annotations
 
@@ -70,22 +74,28 @@ class BlockSyncReactor(Service):
             "blocks_applied": 0,
             "sigs_verified": 0,
             "ranges": 0,
+            # one plan a verify call; a cut is a plan that ended at a header
+            # naming a set the state does not know yet (a third set);
+            # sequential_blocks counts blocks applied after a one-commit
+            # verify in _apply_sequential (0 on honest traffic)
+            "plans": 0,
+            "cuts": 0,
+            "sequential_blocks": 0,
             "peer_bans": 0,
         }
-        # Commits for heights in [_commit_verified_from, _commit_verified_upto]
-        # are signature-proven by a range batch (or the sequential fallback)
-        # against the validator set whose hash is recorded alongside; lets
-        # apply_block skip the redundant host re-verification of each block's
-        # LastCommit. NOTE the lower bound: a range starting at height h
-        # proves the commits FOR h..upto (block h+1's LastCommit is the
-        # commit for h) — it proves nothing about the commit for h-1, so the
-        # first block applied after startup/resume must be full-verified
-        # (commit_verified=False). Reset on redo(): a re-fetched block can
-        # carry a different commit; reset on resume(): the proof interval is
-        # stale after a consensus interlude.
-        self._commit_verified_from = None  # no proof interval yet
-        self._commit_verified_upto = 0
-        self._commit_verified_vals = b""
+        # height -> hash of the validator set under which the commit FOR
+        # that height was signature-proven (a planned range call, or the
+        # sequential fallback); lets apply_block skip the redundant host
+        # re-verification of each block's LastCommit. One entry a height,
+        # because one call proves commits under up to two sets. NOTE what is
+        # NOT in it: a range starting at height h proves the commits FOR
+        # h..upto (block h+1's LastCommit is the commit for h) — nothing
+        # about the commit for h-1, so the first block applied after
+        # startup/resume is full-verified (commit_verified=False). An entry
+        # is dropped once its block is applied, on redo() (a re-fetched block
+        # can carry a different commit) and on resume() (stale after a
+        # consensus interlude).
+        self._commit_proofs: dict[int, bytes] = {}
 
     async def on_start(self) -> None:
         self.spawn(self._process_peer_updates(), name="bsr.peers")
@@ -106,9 +116,7 @@ class BlockSyncReactor(Service):
         self.pool.blocks = {
             h: b for h, b in self.pool.blocks.items() if h > state.last_block_height
         }
-        self._commit_verified_from = None
-        self._commit_verified_upto = 0
-        self._commit_verified_vals = b""
+        self._commit_proofs.clear()
         self.synced = asyncio.Event()
         self.spawn(self._request_routine(), name="bsr.req")
         self.spawn(self._sync_routine(), name="bsr.sync")
@@ -209,125 +217,139 @@ class BlockSyncReactor(Service):
                 await self._verify_and_apply(run, sp)
 
     async def _verify_and_apply(self, run, range_span=trace.NOP_SPAN) -> None:
-        """Verify blocks run[0..-2] using each successor's LastCommit in
-        ONE batched call, then apply them in order."""
+        """Verify blocks run[0..-2] using each successor's LastCommit, as
+        many as the state knows the validator set of in ONE batched call,
+        and apply them in order; plan the rest of the run again from the
+        state the apply produced. A static set gives one plan of the whole
+        run."""
         chain_id = self.state.chain_id
-        # Stage 1 (host): build verification entries. Block i is verified
-        # by run[i+1].last_commit against the CURRENT validator set —
-        # valid while the set doesn't change mid-range; the apply loop
-        # re-checks per block and re-verifies individually on rotation.
-        entries = []
+        n = len(run) - 1
+        # Stage 1 (host), once a run: part sets and block IDs. Block i is
+        # verified by run[i+1].last_commit.
         parts_list = []
-        assumed_vals = self.state.validators
-        with trace.span("blocksync", "build", n=len(run) - 1):
-            for i in range(len(run) - 1):
-                block, _provider = run[i]
-                next_block, _ = run[i + 1]
+        block_ids = []
+        with trace.span("blocksync", "build", n=n):
+            for i in range(n):
+                block = run[i][0]
                 parts = block.make_part_set()
                 parts_list.append(parts)
-                block_id = BlockID(block.hash(), parts.header)
-                entries.append(
-                    (assumed_vals, block_id, block.header.height, next_block.last_commit)
-                )
+                block_ids.append(BlockID(block.hash(), parts.header))
         first_height = run[0][0].header.height
 
-        # Stage 2 (TPU): one batched verification for the whole range
-        try:
-            n_sigs = sum(
-                sum(1 for s in e[3].signatures if s.is_commit()) for e in entries
-            )
-            range_span.set(sigs=n_sigs)
-            with trace.span("blocksync", "verify", sigs=n_sigs):
-                await asyncio.to_thread(
-                    verify_commit_range, chain_id, entries, lane="backfill"
-                )
-            self.metrics["ranges"] += 1
-            self.metrics["sigs_verified"] += n_sigs
-            # the batch proved the commits FOR first_height..first+len-1
-            # (each block's successor LastCommit), all against assumed_vals
-            self._record_commit_proof(
-                first_height, first_height + len(entries) - 1, assumed_vals.hash()
-            )
-        except InvalidCommitError as e:
-            # NOT necessarily byzantine: the whole range was verified
-            # against today's validator set, so a legitimate mid-range
-            # validator rotation also lands here. Re-process the run
-            # sequentially against the true (evolving) state; only a
-            # block that fails against its CORRECT set evicts peers.
-            self.logger.debug(
-                "range verify failed at h=%d (%s); falling back to sequential",
-                first_height + getattr(e, "failed_index", 0),
-                e,
-            )
-            await self._apply_sequential(run, parts_list)
-            return
+        start = 0
+        run_sigs = 0
+        while start < n:
+            # Stage 2 (host): plan. After height h the state holds the sets
+            # of h+1 (validators) and h+2 (next_validators); a header names
+            # its own. An entry takes the set its header names; the plan
+            # ends at the first header that names neither (state.validate
+            # holds every header to the true state at apply time, so a
+            # header that names the wrong one of the two is refused there).
+            vals, nxt = self.state.validators, self.state.next_validators
+            known = {nxt.hash(): nxt, vals.hash(): vals}
+            entries = []
+            sets = set()
+            with trace.span("blocksync", "plan", run=n - start) as plan_span:
+                for i in range(start, n):
+                    block = run[i][0]
+                    named = block.header.validators_hash
+                    entry_vals = known.get(named)
+                    if entry_vals is None:
+                        break
+                    sets.add(named)
+                    entries.append(
+                        (entry_vals, block_ids[i], block.header.height,
+                         run[i + 1][0].last_commit)
+                    )
+                cut = "run_end" if start + len(entries) == n else "third_set"
+                plan_span.set(planned=len(entries), sets=len(sets), cut=cut)
+            self.metrics["plans"] += 1
+            self.metrics["cuts"] += cut == "third_set"
+            if not entries:
+                # the NEXT block names a set the state contradicts: no commit
+                # can vouch for it; state.validate refuses it by name
+                await self._apply_sequential(run, parts_list, block_ids, start, start + 1)
+                return
+            stop = start + len(entries)
 
-        # Stage 3: apply in order (ABCI)
-        for i in range(len(run) - 1):
-            block, provider = run[i]
-            height = block.header.height
-            parts = parts_list[i]
-            block_id = BlockID(block.hash(), parts.header)
-            next_block, next_provider = run[i + 1]
-            # validator rotation guard: if the set changed mid-range, the
-            # batch's assumption is stale from here on — re-verify this
-            # block against the true set before applying
-            if self.state.validators.hash() != assumed_vals.hash():
+            # Stage 3 (TPU): one batched verification for the planned blocks
+            try:
+                n_sigs = sum(
+                    sum(1 for s in e[3].signatures if s.is_commit()) for e in entries
+                )
+                run_sigs += n_sigs
+                range_span.set(sigs=run_sigs)
+                with trace.span("blocksync", "verify", sigs=n_sigs, sets=len(sets)):
+                    await asyncio.to_thread(
+                        verify_commit_range, chain_id, entries, lane="backfill"
+                    )
+                self.metrics["ranges"] += 1
+                self.metrics["sigs_verified"] += n_sigs
+                # the batch proved the commit FOR each planned height (its
+                # successor's LastCommit), each under the set its header names
+                for entry_vals, _bid, height, _commit in entries:
+                    self._commit_proofs[height] = entry_vals.hash()
+            except InvalidCommitError as e:
+                # every entry was held to the set its own header names and
+                # the state derived, so this is no rotation: the commit at
+                # failed_index is bad, or a header lies about its set. Blocks
+                # before it are good; go through the planned blocks one
+                # commit at a time against the true (evolving) state, which
+                # applies those and punishes the pair that served the bad one.
+                self.logger.debug(
+                    "range verify failed at h=%d (%s); falling back to sequential",
+                    first_height + start + getattr(e, "failed_index", 0),
+                    e,
+                )
+                await self._apply_sequential(run, parts_list, block_ids, start, stop)
+                return
+
+            # Stage 4: apply in order (ABCI)
+            for i in range(start, stop):
+                block, provider = run[i]
+                if not await self._apply_one(
+                    block, block_ids[i], parts_list[i], run[i + 1][0], provider
+                ):
+                    return
+            start = stop
+
+    async def _apply_sequential(self, run, parts_list, block_ids, start, stop) -> None:
+        """Per-block verify (against the true evolving validator set) +
+        apply of run[start:stop] — the answer to a planned call that FAILED
+        against its true sets, and the semantic twin of the reference's
+        one-at-a-time poolRoutine: blocks before the bad commit are applied,
+        the pair that served it is punished."""
+        chain_id = self.state.chain_id
+        with trace.span("blocksync", "sequential", n=stop - start) as sp:
+            applied = 0
+            for i in range(start, stop):
+                block, provider = run[i]
+                height = block.header.height
+                next_block, next_provider = run[i + 1]
                 try:
                     await asyncio.to_thread(
                         verify_commit_light,
                         chain_id,
                         self.state.validators,
-                        block_id,
+                        block_ids[i],
                         height,
                         next_block.last_commit,
                         lane="backfill",
                     )
                 except InvalidCommitError as e:
                     await self._punish(height, provider, next_provider, e)
-                    return
-                # record the re-proof so the NEXT block's apply doesn't
-                # redo this commit on the host (same bookkeeping as the
-                # sequential fallback)
-                self._record_commit_proof(
-                    height, height, self.state.validators.hash()
-                )
-            if not await self._apply_one(block, block_id, parts, next_block, provider):
-                return
-        return
-
-    async def _apply_sequential(self, run, parts_list) -> None:
-        """Per-block verify (against the true evolving validator set) +
-        apply — the fallback when a range batch fails, and the semantic
-        twin of the reference's one-at-a-time poolRoutine."""
-        chain_id = self.state.chain_id
-        for i in range(len(run) - 1):
-            block, provider = run[i]
-            height = block.header.height
-            if height < self.pool.height:
-                continue  # already applied
-            parts = parts_list[i]
-            block_id = BlockID(block.hash(), parts.header)
-            next_block, next_provider = run[i + 1]
-            try:
-                await asyncio.to_thread(
-                    verify_commit_light,
-                    chain_id,
-                    self.state.validators,
-                    block_id,
-                    height,
-                    next_block.last_commit,
-                    lane="backfill",
-                )
-            except InvalidCommitError as e:
-                await self._punish(height, provider, next_provider, e)
-                return
-            # commit for `height` proven against the TRUE set for that
-            # height (state.validators now == state.last_validators when
-            # block height+1 is applied next iteration)
-            self._record_commit_proof(height, height, self.state.validators.hash())
-            if not await self._apply_one(block, block_id, parts, next_block, provider):
-                return
+                    break
+                # commit for `height` proven against the TRUE set for that
+                # height (state.validators now == state.last_validators when
+                # block height+1 is applied next iteration)
+                self._commit_proofs[height] = self.state.validators.hash()
+                if not await self._apply_one(
+                    block, block_ids[i], parts_list[i], next_block, provider
+                ):
+                    break
+                applied += 1
+                self.metrics["sequential_blocks"] += 1
+            sp.set(applied=applied)
 
     async def _punish(self, height, provider, next_provider, err) -> None:
         """Bad block/commit confirmed against the correct validator set:
@@ -340,43 +362,26 @@ class BlockSyncReactor(Service):
         if next_provider != provider:
             await self.channel.error(PeerError(next_provider, f"bad commit: {err}"))
         self.pool.redo(height, provider, next_provider)
-        self._commit_verified_upto = min(self._commit_verified_upto, height - 1)
+        self._drop_commit_proofs(height)
 
-    def _record_commit_proof(self, a: int, b: int, vals_hash: bytes) -> None:
-        """Merge a freshly proven commit interval [a, b] (commits FOR
-        those heights, proven against vals_hash). A proof under a
-        different validator-set hash, or one not contiguous with the
-        recorded interval, REPLACES it — extending across a gap or a set
-        change would claim proofs that were never computed."""
-        lo, hi = self._commit_verified_from, self._commit_verified_upto
-        if (
-            lo is None
-            or vals_hash != self._commit_verified_vals
-            or hi < lo  # emptied by a redo/punish rollback
-            or a > hi + 1  # gap above
-            or b < lo - 1  # gap below
-        ):
-            self._commit_verified_from, self._commit_verified_upto = a, b
-            self._commit_verified_vals = vals_hash
-        else:
-            self._commit_verified_from = min(lo, a)
-            self._commit_verified_upto = max(hi, b)
+    def _drop_commit_proofs(self, height: int) -> None:
+        """Forget the proofs of the commits FOR `height` and above: their
+        blocks are fetched again and may carry other commits."""
+        for h in [h for h in self._commit_proofs if h >= height]:
+            del self._commit_proofs[h]
 
     def _commit_preverified(self, height: int) -> bool:
         """True when block `height`'s LastCommit (the commit for
-        height-1) was already signature-proven by a batch/sequential
-        verification against exactly the set validate_block will check
-        it with (state.last_validators).
+        height-1) was already signature-proven by a planned range call or
+        the sequential fallback against exactly the set validate_block will
+        check it with (state.last_validators).
 
-        The lower bound matters: the first range proves commits from its
-        OWN first height onward, never the commit for first_height-1, so
-        the first block applied after startup/resume always takes the
-        full apply-time verification path (commit_verified=False)."""
-        return (
-            self._commit_verified_from is not None
-            and self._commit_verified_from <= height - 1 <= self._commit_verified_upto
-            and self.state.last_validators.hash() == self._commit_verified_vals
-        )
+        A range proves commits from its OWN first height onward, never the
+        commit for first_height-1, so the first block applied after
+        startup/resume always takes the full apply-time verification path
+        (commit_verified=False)."""
+        proven = self._commit_proofs.get(height - 1)
+        return proven is not None and proven == self.state.last_validators.hash()
 
     async def _apply_one(self, block, block_id, parts, next_block, provider) -> bool:
         height = block.header.height
@@ -398,7 +403,8 @@ class BlockSyncReactor(Service):
             self.logger.error("apply failed at height %d: %r", height, e)
             await self.channel.error(PeerError(provider, f"apply: {e!r}"))
             self.pool.redo(height, provider)
-            self._commit_verified_upto = min(self._commit_verified_upto, height - 1)
+            self._drop_commit_proofs(height)
             return False
+        self._commit_proofs.pop(height - 1, None)
         self.pool.pop(height)
         return True

@@ -280,7 +280,11 @@ class BlockExecutor:
         n_val_set = state.next_validators.copy()
         last_height_vals_changed = state.last_height_validators_changed
         if val_updates:
-            n_val_set.update_with_change_set(val_updates)
+            # entered only on a change: a static set leaves no row
+            with trace.span(
+                "state", "valset_update", changes=len(val_updates), size=len(n_val_set)
+            ):
+                n_val_set.update_with_change_set(val_updates)
             last_height_vals_changed = height + 2
         n_val_set.increment_proposer_priority(1)
 

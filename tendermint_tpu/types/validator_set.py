@@ -247,7 +247,7 @@ class ValidatorSet:
         types/validator_set.go:77-109 region). Memoized: the hash covers
         pubkeys + powers only, which change solely through
         update_with_change_set (proposer-priority churn doesn't touch it),
-        and hot paths (block-sync rotation guards) call this per block."""
+        and hot paths (block-sync's plan and commit proofs) call this per block."""
         if self._hash is None:
             self._hash = hash_from_byte_slices(
                 [v.simple_encode() for v in self.validators]

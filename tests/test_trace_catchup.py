@@ -217,6 +217,7 @@ N_VALS, POWER = 10, 10
 
 RANGE_TREE = {
     "blocksync.build": "blocksync.range",
+    "blocksync.plan": "blocksync.range",
     "blocksync.verify": "blocksync.range",
     "validation.collect": "blocksync.verify",
     "validation.verify": "blocksync.verify",
@@ -274,9 +275,14 @@ class TestBlockSyncTree:
         per_block = [k for k, v in RANGE_TREE.items() if v == "blocksync.apply"]
         for k in ["blocksync.apply"] + per_block:
             assert sum(1 for x in mine if _key(x) == k) == n, k
-        for k in ("blocksync.build", "blocksync.verify", "validation.collect",
-                  "validation.verify", "hub.submit", "hub.wait"):
+        for k in ("blocksync.build", "blocksync.plan", "blocksync.verify",
+                  "validation.collect", "validation.verify", "hub.submit", "hub.wait"):
             assert sum(1 for x in mine if _key(x) == k) == 1, k
+        # a static set: ONE plan of the whole run, one set, ended by the run
+        plan = next(x for x in mine if _key(x) == "blocksync.plan")
+        assert plan["attrs"] == {"run": n, "planned": n, "sets": 1, "cut": "run_end"}
+        assert next(x for x in mine if _key(x) == "blocksync.verify")["attrs"]["sets"] == 1
+        assert not any(x["subsystem"] == "state" and x["name"] == "valset_update" for x in spans)
         collect = next(x for x in mine if _key(x) == "validation.collect")
         needed = N_VALS * 2 // 3 + 1  # equal powers: the quorum's early cut-off
         # one sign-bytes template a commit, applied once per signature
@@ -288,8 +294,8 @@ class TestBlockSyncTree:
         assert next(x for x in mine if _key(x) == "validation.verify")["attrs"]["via"] == "hub"
         # range = build + verify + sum(apply) to within its own self time
         direct = [x for x in mine if x["parent_id"] == root["span_id"]]
-        assert {_key(x) for x in direct} == {"blocksync.build", "blocksync.verify",
-                                             "blocksync.apply"}
+        assert {_key(x) for x in direct} == {"blocksync.build", "blocksync.plan",
+                                             "blocksync.verify", "blocksync.apply"}
         self_ms = root["duration_ms"] - sum(x["duration_ms"] for x in direct)
         assert 0 <= self_ms <= 0.1 * root["duration_ms"] + 5.0
         # no row per signature: the hub's rows go by dispatch, and the
