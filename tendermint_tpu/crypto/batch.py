@@ -110,7 +110,11 @@ def _probe_tpu() -> None:
     gets the same story as one trace: `backend.probe` (root, to the
     thread's end; `available_s` is when routing could use the device)
     over `backend.attach`, `backend.warmup` [shape] — the first holds
-    `backend.pallas_ab`, the Pallas self-test — and `backend.cutoff`
+    `backend.pallas_ab` [chosen, programs, stages], the Pallas self-test:
+    each Pallas kernel held once to a known answer the host computes, all
+    of it BEFORE the floor programs are traced and so before availability
+    flips; a kernel that fails leaves the process on the XLA family,
+    loudly, and the probe goes on with that — and `backend.cutoff`
     [value]."""
     with trace.span("backend", "probe", root=True) as sp:
         _probe_tpu_traced(sp)

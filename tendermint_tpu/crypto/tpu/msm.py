@@ -85,6 +85,36 @@ def _mul_255(p: Point) -> Point:
 _BLOCK = 16  # sequential within-block scan length (see _boundary_prefixes)
 
 
+def _block_prefixes(first: Point, rest_cached: curve.CachedPoint) -> tuple[Point, Point]:
+    """Within-block inclusive prefix sums: `first` the first point of each
+    of g blocks (g, 32), `rest_cached` the other _BLOCK-1 of each in cached
+    form (_BLOCK-1, g, 32). Returns (prefixes (g, _BLOCK, 32), prefix 0 =
+    the first point; block totals (g, 32)). THE reader of the formulation
+    switch in this module: verify's self-test proves it under vmap."""
+    from . import pallas_field
+
+    g = first.x.shape[0]
+    # the fused kernel pads the lane axis to its TILE: only route batches
+    # that FILL a tile (the R-side MSM at the 8192 bucket, g=512) — small
+    # windows (the grouped A-side, g≈16) would pay ~TILE/g× padding waste
+    if F._USE_PALLAS and g % pallas_field.TILE == 0:
+        prefixes = Point(*pallas_field.scan_blocks(tuple(first), tuple(rest_cached)))
+        return prefixes, Point(*(p[:, -1] for p in prefixes))
+
+    def step(acc: Point, nxt: curve.CachedPoint):
+        acc = curve.add_cached(acc, nxt)
+        return acc, acc
+
+    last, tail = jax.lax.scan(step, first, rest_cached)
+    prefixes = Point(
+        *(
+            jnp.concatenate([f[:, None], jnp.moveaxis(t, 0, 1)], axis=1)
+            for f, t in zip(first, tail)
+        )
+    )
+    return prefixes, last
+
+
 def _boundary_prefixes(sorted_pts: Point, counts: jnp.ndarray) -> Point:
     """C_j = prefix sum of the first counts[j] sorted points (identity
     when counts[j] == 0), for the 256 bucket boundaries.
@@ -123,29 +153,8 @@ def _boundary_prefixes(sorted_pts: Point, counts: jnp.ndarray) -> Point:
     rest = Point(*(jnp.moveaxis(c[:, 1:], 1, 0) for c in blocks))  # (B-1, g, 32)
     rest_cached = curve.to_cached(rest)
 
-    from . import pallas_field
-
-    # the fused kernel pads the lane axis to its TILE: only route batches
-    # that FILL a tile (the R-side MSM at the 8192 bucket, g=512) — small
-    # windows (the grouped A-side, g≈16) would pay ~TILE/g× padding waste
-    if F._USE_PALLAS and g % pallas_field.TILE == 0:
-        prefixes = pallas_field.scan_blocks(tuple(first), tuple(rest_cached))
-        within = Point(*(p.reshape(m, -1) for p in prefixes))  # (M, 32)
-        last = Point(*(p[:, -1] for p in prefixes))  # (g, 32) block totals
-    else:
-        def step(acc: Point, nxt: curve.CachedPoint):
-            acc = curve.add_cached(acc, nxt)
-            return acc, acc
-
-        last, tail = jax.lax.scan(step, first, rest_cached)
-        within = Point(
-            *(
-                jnp.concatenate(
-                    [f[:, None], jnp.moveaxis(t, 0, 1)], axis=1
-                ).reshape(m, -1)
-                for f, t in zip(first, tail)
-            )
-        )  # (M, 32) within-block inclusive prefixes; `last` = block totals
+    prefixes, last = _block_prefixes(first, rest_cached)
+    within = Point(*(p.reshape(m, -1) for p in prefixes))  # (M, 32)
 
     # exclusive block offsets: shift the inclusive totals scan right
     totals_prefix = jax.lax.associative_scan(curve.point_add, last, axis=0)

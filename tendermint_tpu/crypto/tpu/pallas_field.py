@@ -27,8 +27,8 @@ VMEM-resident, vs the GEMM path's 64.5k routed MXU MACs + a materialized
 The host-side wrapper transposes (…, 32) limbs-last operands to the
 kernel layout and back; XLA fuses those transposes into neighbours where
 it can. Enabled on TPU backends (verify._choose_formulation, after a
-self-test against the GEMM); the GEMM path remains for CPU and as the
-differential-testing oracle. Tests run this kernel in Pallas interpret
+self-test against the host's integers); the GEMM path remains for CPU and
+as the differential-testing oracle. Tests run this kernel in Pallas interpret
 mode on CPU.
 """
 

@@ -195,7 +195,11 @@ COMPILE_CACHE_DIR = os.path.join(
 def _ensure_compile_cache() -> None:
     """Persist XLA compilations to disk — the verification kernels cost
     seconds (CPU) to minutes (TPU) to compile per batch bucket; the cache
-    makes that a one-time cost across processes.
+    makes that a one-time cost across processes — and, behind it, choose
+    the kernels' formulation (`_choose_formulation`: on a TPU each Pallas
+    kernel proven once against the host's known answer). Every way to a
+    jitted kernel passes here first, so no production program is traced
+    before that proof is over.
 
     Placement: when JAX_COMPILATION_CACHE_DIR is set, JAX already reads
     it and this code sets NO directory at all; otherwise the fixed
@@ -219,8 +223,8 @@ def _ensure_compile_cache() -> None:
 
 #: Filled by _choose_formulation on a TPU: `chosen` ("pallas" | "xla"), so
 #: a run records which family of programs it traces. A Pallas kernel that
-#: failed to compile or mismatched leaves `error` (multiply/power stage)
-#: or `scan_error` here AND counts in
+#: failed to compile or gave a wrong answer leaves `error` (multiply /
+#: power stage) or `scan_error` here AND counts in
 #: backend_telemetry.BACKEND["pallas_probe_errors"] — chip_smoke.py
 #: refuses a run that has either.
 field_mul_probe: dict = {}
@@ -228,6 +232,12 @@ field_mul_probe: dict = {}
 #: windows of the self-test's MSM: two, so that the vmap batching rule
 #: hands the fused scan a real batch axis (production runs 16 and 32)
 _SELF_TEST_WINDOWS = 2
+
+#: the self-test's stages in the order they are proven, each with the key
+#: of `field_mul_probe` its failure is recorded under
+_SELF_TEST_STAGES = (
+    ("mul", "error"), ("pow22523", "error"), ("scan_blocks", "scan_error")
+)
 
 
 def _probe_failed(key: str, e: Exception) -> None:
@@ -252,77 +262,193 @@ def _choose_formulation() -> None:
     multiply, fused pow22523, fused in-block MSM scan — field.set_pallas),
     anywhere else the portable XLA ones, with nothing run.
 
-    Before anything trusts the Pallas kernels, each is compared once with
-    its XLA twin on the attached device (a kernel Mosaic refuses raises
-    here, not in the first commit). ANY failure puts the one switch back
-    off: the all-XLA family, which every CPU test run exercises."""
+    Before anything trusts the Pallas kernels — before any production
+    program is traced, and so before the probe can call the device
+    available — each is proven once in this process against a KNOWN
+    ANSWER the host computes over Python integers (`_known_answer`): one
+    small device program a kernel, in the Pallas formulation only, no XLA
+    twin beside it (a kernel Mosaic refuses raises here, not in the first
+    commit; a fault the two formulations shared would have passed a twin
+    and does not pass the integers). ANY failure puts the one switch back
+    off, skips the stages after it and is loud (`_probe_failed`): the
+    all-XLA family, which every CPU test run exercises — never a mixed
+    one. Nothing is timed, nothing is kept on disk, no stage is skipped
+    on any start."""
     import jax
 
     from . import field as F
 
     if jax.default_backend() != "tpu":
         return
-    # benchmark/metrics/setup_probe_ab_s.py reads this span by name
+    # benchmark/metrics/setup_probe_ab_s.py reads this span by name;
+    # `programs` = device programs the proof traced, `stages` = kernels
+    # proven (3 and 3 on a sound chip)
     with trace.span("backend", "pallas_ab") as sp:
-        runs = _self_test_runs()
-        want = {name: run() for name, run in runs.items()}  # switch off: XLA
         F.set_pallas(True)
-        ok = True
-        for name, key in (("mul", "error"), ("pow22523", "error"), ("scan_blocks", "scan_error")):
+        programs = stages = 0
+        for name, key in _SELF_TEST_STAGES:
             try:
-                if not np.array_equal(want[name], runs[name]()):
-                    raise RuntimeError(f"pallas {name} mismatch")
+                case = _known_answer(name)  # host work only
+                programs += 1
+                case.check(np.asarray(jax.jit(case.program)(*case.operands)))
             except Exception as e:  # noqa: BLE001 — surfaced by _probe_failed
                 _probe_failed(key, e)
                 F.set_pallas(False)
-                ok = False
                 break
-        field_mul_probe["chosen"] = "pallas" if ok else "xla"
-        sp.set(chosen=field_mul_probe["chosen"])
+            stages += 1
+        proven = stages == len(_SELF_TEST_STAGES)
+        field_mul_probe["chosen"] = "pallas" if proven else "xla"
+        sp.set(chosen=field_mul_probe["chosen"], programs=programs, stages=stages)
 
 
-def _self_test_runs():
-    """The three production entry points that read the switch — field.mul,
-    field.pow22523 and msm.msm — each on the smallest operand that takes
-    the production path: one lane tile for the field kernels, and for the
-    scan an MSM under vmap whose blocks fill a tile (the `g % TILE` gate
-    of msm._boundary_prefixes). Every call traces anew, in whatever
-    formulation the switch then selects, and returns canonical limbs.
-    Operand "points" are random limb vectors — both formulations compute
-    identical limb algebra whether or not the inputs lie on the curve."""
+class _KnownAnswer(NamedTuple):
+    """One stage of the self-test: ONE device program over `operands`
+    (numpy), traced in whatever formulation the switch then selects, and
+    the host's judgement of what it returned — `check(out)` raises on a
+    wrong answer."""
+
+    program: object
+    operands: tuple
+    check: object
+
+
+def _limb_ints(name: str, got: np.ndarray) -> list[int]:
+    """Limb rows from the device, (..., 32) -> the integers mod p they
+    represent, in C order. Holds the module invariant of field.py on the
+    way (every limb in [0, 2^9)): the next multiply's exactness rests on
+    it, and no comparison of reduced values would see it. Host integers
+    only: each limb's low byte and its carry bit are read as two
+    little-endian numbers."""
+    from .field import LIMBS, P_INT
+
+    if got.shape[-1] != LIMBS or got.min() < 0 or got.max() >= 512:
+        raise RuntimeError(f"pallas {name}: shape {got.shape} or a limb outside [0, 2^9)")
+    lo, hi = (got & 0xFF).astype(np.uint8).tobytes(), (got >> 8).astype(np.uint8).tobytes()
+    from_bytes = int.from_bytes
+    return [
+        (from_bytes(lo[i : i + LIMBS], "little") + (from_bytes(hi[i : i + LIMBS], "little") << 8))
+        % P_INT
+        for i in range(0, len(lo), LIMBS)
+    ]
+
+
+def _check_limbs(name: str, want: list[int], got: np.ndarray) -> None:
+    """`got` must represent the integers `want`, row by row."""
+    ints = _limb_ints(name, got)
+    bad = [i for i, (g, w) in enumerate(zip(ints, want)) if g != w]
+    if bad or len(ints) != len(want):
+        raise RuntimeError(
+            f"pallas {name} mismatch against the host's integers in "
+            f"{len(bad)} of {len(want)} rows (first: {bad[:1]}; {len(ints)} came back)"
+        )
+
+
+def _known_answer(name: str) -> _KnownAnswer:
+    """The three readers of the switch — field.mul, field.pow22523 and
+    msm._block_prefixes — each on the smallest operand that takes the
+    production path, with the answer the HOST computes for it over Python
+    integers (exact, and independent of the limb code, the carry passes
+    and `field.canonical`, which both device formulations share).
+
+    `mul`, `pow22523`: lane tiles of limb rows over the whole range the
+    module invariant admits ([0, 2^9); row 0 every limb at the bound, and
+    for the power rows of 0 and 1): a·b mod p and z^(2^252 − 3) mod p.
+    The multiply takes one tile plainly (decompression's call) and two
+    under vmap (the batching rule every multiply of the MSM's windows goes
+    through); the power is only ever called plainly.
+
+    `scan_blocks`: the in-block prefix scan under vmap over two windows
+    (production: 16 and 32), each of TILE blocks — the `g % TILE` gate of
+    msm._block_prefixes. Its operands are REAL curve points, consecutive
+    multiples n·B by repeated host addition (extended coordinates, Z ≠ 1;
+    the cached form (Y−X, Y+X, 2dT, 2Z) in integers too), and the answer
+    is every one of the windows' 2·TILE·_BLOCK prefixes by the host's own
+    addition, compared projectively with the extended coordinate's
+    relation T·Z = X·Y. On the curve the group law fixes each prefix
+    whatever the formulas; random limb "points" (the twins' operand) only
+    ever worked twin against twin. NOT the MSM around the scan: the sort,
+    the boundary gather, the tree and the fold are the same XLA code in
+    both families and every CPU test runs them, while lowering them costs
+    six times the scan's own program at every start (PERF.md §6, PR 36).
+
+    The program is a fresh lambda a call, so it traces anew in the
+    formulation of the moment."""
     import jax
     import jax.numpy as jnp
 
+    from .. import ed25519_math as em
     from . import field as F
     from . import msm as msm_mod
     from . import pallas_field
-    from .curve import Point
+    from .curve import CachedPoint, Point
 
     rng = np.random.default_rng(0)
-    tile, width = pallas_field.TILE, msm_mod._BLOCK * pallas_field.TILE
+    tile, p = pallas_field.TILE, F.P_INT
 
-    def limbs(*shape):
-        return jax.device_put(rng.integers(0, 256, shape + (32,), dtype=np.int32))
+    def limb_rows():
+        rows = rng.integers(0, 512, (3, tile, F.LIMBS), dtype=np.int32)
+        rows[:, 0] = 511
+        return rows
 
-    a, b = limbs(tile), limbs(tile)
-    pts = tuple(limbs(width) for _ in range(4))
-    digs = jax.device_put(
-        rng.integers(0, 256, (_SELF_TEST_WINDOWS, width), dtype=np.int32)
-    )
+    if name == "mul":
+        a, b = limb_rows(), limb_rows()
+        want = [x * y % p for x, y in zip(_limb_ints(name, a), _limb_ints(name, b))]
 
-    def mul():
-        return np.asarray(jax.jit(lambda x, y: F.canonical(F.mul(x, y)))(a, b))
+        def program(x, y):
+            plain = F.mul(x[0], y[0])
+            return jnp.concatenate([plain[None], jax.vmap(F.mul)(x[1:], y[1:])])
 
-    def pow22523():
-        return np.asarray(jax.jit(lambda z: F.canonical(F.pow22523(z)))(a))
+        return _KnownAnswer(program, (a, b), partial(_check_limbs, name, want))
+    if name == "pow22523":
+        z = limb_rows()[0]
+        z[1], z[2] = 0, F.ONE
+        want = [pow(v, 2**252 - 3, p) for v in _limb_ints(name, z)]
+        return _KnownAnswer(lambda x: F.pow22523(x), (z,), partial(_check_limbs, name, want))
+    assert name == "scan_blocks", name
 
-    def scan_blocks():
-        # canonical INSIDE the program: run eagerly it is a dozen tiny
-        # compiles that no persistent cache keeps (under its 1 s floor)
-        fn = jax.jit(lambda p, d: F.canonical(jnp.stack(msm_mod.msm(Point(*p), d))))
-        return np.asarray(fn(pts, digs))
+    block, windows = msm_mod._BLOCK, _SELF_TEST_WINDOWS
+    pts = [em.scalar_mul_base(7)]
+    for _ in range(windows * tile * block - 1):
+        pts.append(pts[-1].add(em.BASE))
+    want = []  # (window, block, position): the prefix inside the block
+    for i, pt in enumerate(pts):
+        want.append(pt if i % block == 0 else want[-1].add(pt))
 
-    return {"mul": mul, "pow22523": pow22523, "scan_blocks": scan_blocks}
+    def limbs(values, *shape):
+        raw = b"".join(v.to_bytes(32, "little") for v in values)
+        return np.frombuffer(raw, np.uint8).reshape(*shape, F.LIMBS).astype(np.int32)
+
+    grid = (windows, tile, block)
+    first = tuple(limbs([getattr(pt, c) for pt in pts], *grid)[:, :, 0] for c in "XYZT")
+    cached = (
+        [(pt.Y - pt.X) % p for pt in pts], [(pt.Y + pt.X) % p for pt in pts],
+        [2 * F.D_INT * pt.T % p for pt in pts], [2 * pt.Z % p for pt in pts],
+    )  # curve.to_cached, in integers
+    rest = tuple(np.moveaxis(limbs(c, *grid)[:, :, 1:], 2, 1) for c in cached)
+
+    def check(got: np.ndarray) -> None:
+        n = tile * block
+        if got.shape != (windows, 4, tile, block, F.LIMBS):
+            raise RuntimeError(f"pallas {name}: shape {got.shape}")
+        ints = _limb_ints(name, got)
+        bad = 0
+        for w in range(windows):
+            coords = (ints[(4 * w + c) * n : (4 * w + c + 1) * n] for c in range(4))
+            for x, y, z, t, h in zip(*coords, want[w * n : (w + 1) * n]):
+                bad += z == 0 or (t * z - x * y) % p != 0 or not h.equals(em.Point(x, y, z, t))
+        if bad:
+            raise RuntimeError(
+                f"pallas {name} mismatch: {bad} of {windows * n} in-block prefixes "
+                "are not the host's sums of the same multiples of the base point"
+            )
+
+    def scan(f, r):
+        return msm_mod._block_prefixes(Point(*f), CachedPoint(*r))[0]
+
+    def program(f, r):
+        return jnp.stack(jax.vmap(scan)(f, r), axis=1)
+
+    return _KnownAnswer(program, (first, rest), check)
 
 
 def _get_kernel():

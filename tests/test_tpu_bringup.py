@@ -5,6 +5,10 @@
     unnoticed and was swallowed on the chip. Here the backend gate is
     patched in-test and the Pallas kernels run in interpret mode at tiny
     widths, so the whole self-test executes on the CPU;
+  * the self-test holds each Pallas kernel to a KNOWN ANSWER the host
+    computes over Python integers (no XLA twin is traced beside it): the
+    answers are themselves held to the XLA formulation the CPU runs, and a
+    kernel with one wrong limb fails its own stage;
   * a Pallas failure on a TPU is loud (recorded, counted, WARNING) and
     leaves the ONE formulation switch off, not INFO-and-carry-on;
   * the formulation has one switch: field.mul, field.pow22523 and the
@@ -28,6 +32,7 @@ from tendermint_tpu.crypto.tpu import field as F
 from tendermint_tpu.crypto.tpu import msm as M
 from tendermint_tpu.crypto.tpu import pallas_field as PF
 from tendermint_tpu.crypto.tpu import verify as V
+from tendermint_tpu.libs import trace
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -86,9 +91,56 @@ def probe_on_cpu(monkeypatch):
     bt.BACKEND.update(before)
 
 
-def test_pallas_self_test_runs_clean_past_the_backend_gate(probe_on_cpu, monkeypatch):
+STAGES = ["mul", "pow22523", "scan_blocks"]
+
+
+@pytest.fixture
+def recorder():
+    was = trace.RECORDER.enabled
+    trace.RECORDER.enabled = True
+    trace.RECORDER.clear()
+    yield trace.RECORDER
+    trace.RECORDER.clear()
+    trace.RECORDER.enabled = was
+
+
+def _proof_spans(recorder):
+    return [x.get("attrs", {}) for x in recorder.dump()
+            if (x["subsystem"], x["name"]) == ("backend", "pallas_ab")]
+
+
+@pytest.fixture
+def traced_programs(monkeypatch):
+    """Every `jax.jit` the self-test makes, as the state of the formulation
+    switch at that moment: an XLA twin would show as a False."""
+    states = []
+    jit = jax.jit
+
+    def recording_jit(fn, *a, **k):
+        states.append(F._USE_PALLAS)
+        return jit(fn, *a, **k)
+
+    monkeypatch.setattr(jax, "jit", recording_jit)
+    return states
+
+
+def _one_wrong_limb(fn):
+    """`fn`'s result with the lowest bit of one limb flipped."""
+
+    def wrong(*a, **k):
+        out = fn(*a, **k)
+        if isinstance(out, tuple):  # scan_blocks: four coordinate arrays
+            return (out[0].at[-1, -1, 3].set(out[0][-1, -1, 3] ^ 1),) + tuple(out[1:])
+        return out.at[-1, 3].set(out[-1, 3] ^ 1)
+
+    return wrong
+
+
+def test_pallas_self_test_runs_clean_past_the_backend_gate(
+    probe_on_cpu, monkeypatch, recorder, traced_programs
+):
     ran = []
-    for name in ("mul", "pow22523", "scan_blocks"):
+    for name in STAGES:
         _record_calls(monkeypatch, ran, name, getattr(PF, name))
     V._choose_formulation()
     probe = V.field_mul_probe
@@ -96,10 +148,14 @@ def test_pallas_self_test_runs_clean_past_the_backend_gate(probe_on_cpu, monkeyp
         f"error={probe.get('error')} scan_error={probe.get('scan_error')}"
     )
     assert bt.BACKEND["pallas_probe_errors"] == 0
-    # all three kernels were reached and compared, and nothing is timed
-    assert {"mul", "pow22523", "scan_blocks"} <= set(ran)
+    # all three kernels were reached and held to the host's answer
+    assert set(STAGES) <= set(ran)
     assert probe == {"chosen": "pallas"}
     assert probe_on_cpu == [True] and F._USE_PALLAS
+    # one span, three programs for three kernels, each traced with the
+    # switch ON: no XLA twin beside any of them
+    assert _proof_spans(recorder) == [{"chosen": "pallas", "programs": 3, "stages": 3}]
+    assert traced_programs == [True, True, True]
 
 
 def test_pallas_self_test_failure_is_loud(probe_on_cpu, monkeypatch, caplog):
@@ -119,6 +175,81 @@ def test_pallas_self_test_failure_is_loud(probe_on_cpu, monkeypatch, caplog):
     )
     # ONE switch, and it is off: the all-XLA family, never a mixed one
     assert probe_on_cpu == [True, False] and not F._USE_PALLAS
+
+
+@pytest.fixture
+def tiny_tiles(monkeypatch):
+    """The interpret-mode sizes of `probe_on_cpu` without its stand-ins: a
+    field tile of 8 lanes, an MSM of 32 points in blocks of 4."""
+    monkeypatch.setattr(M, "_BLOCK", 4)
+    monkeypatch.setattr(PF, "TILE", 8)
+
+
+@pytest.mark.parametrize("stage", STAGES)
+def test_host_oracle_agrees_with_the_xla_formulation(stage, tiny_tiles):
+    """The known answer is itself proven on every PR: the program of each
+    stage, traced in the XLA formulation the CPU runs, returns what the
+    host's integers say — and a single flipped bit of one limb does not."""
+    import numpy as np
+
+    assert not F._USE_PALLAS
+    case = V._known_answer(stage)
+    out = np.array(jax.jit(case.program)(*case.operands))
+    case.check(out)
+    out[..., -1, 3] ^= 1
+    with pytest.raises(RuntimeError, match=f"pallas {stage} mismatch"):
+        case.check(out)
+    out[..., -1, 3] ^= 1
+    out[..., 0, 0] = 512  # the right value mod p is not enough: the limb bound
+    with pytest.raises(RuntimeError, match="limb outside"):
+        case.check(out)
+
+
+@pytest.mark.parametrize("stage", STAGES)
+def test_a_wrong_pallas_kernel_fails_its_own_stage(
+    stage, probe_on_cpu, monkeypatch, caplog, recorder, traced_programs
+):
+    """A Pallas kernel that returns ONE wrong limb ends the start on the
+    all-XLA family, loudly, at its own stage; the stages after it are not
+    run, and still no XLA twin was traced."""
+    ran = []
+    for name in STAGES:
+        fn = getattr(PF, name)
+        _record_calls(monkeypatch, ran, name, _one_wrong_limb(fn) if name == stage else fn)
+    with caplog.at_level(logging.WARNING, logger="crypto.tpu"):
+        V._choose_formulation()
+    k = STAGES.index(stage)
+    key, other = ("scan_error", "error") if stage == "scan_blocks" else ("error", "scan_error")
+    assert f"pallas {stage}" in V.field_mul_probe[key] and other not in V.field_mul_probe
+    assert V.field_mul_probe["chosen"] == "xla"
+    assert probe_on_cpu == [True, False] and not F._USE_PALLAS
+    assert bt.BACKEND["pallas_probe_errors"] == 1
+    assert any(
+        r.levelno == logging.WARNING and key in r.getMessage() for r in caplog.records
+    )
+    # later stages skipped: pow22523 and the scan are reached by no stage
+    # before their own (the MSM of the scan stage multiplies, so `mul` is)
+    assert not {"pow22523", "scan_blocks"} & set(STAGES[k + 1:]) & set(ran)
+    assert _proof_spans(recorder) == [{"chosen": "xla", "programs": k + 1, "stages": k}]
+    assert traced_programs == [True] * (k + 1)
+
+
+@pytest.mark.parametrize("stage", STAGES)
+def test_proof_span_counts_programs_and_stages(stage, probe_on_cpu, monkeypatch, recorder):
+    """`backend.pallas_ab` is entered ONCE whatever happens, with `chosen`,
+    `programs` (device programs the proof traced: never more than the three
+    Pallas ones) and `stages` (kernels proven) — here with `stage` refused
+    by the compiler, which is a program traced and a kernel not proven."""
+
+    def refused(*_a, **_k):
+        raise RuntimeError("mosaic refused the kernel")
+
+    monkeypatch.setattr(PF, stage, refused)
+    V._choose_formulation()
+    (attrs,) = _proof_spans(recorder)
+    k = STAGES.index(stage)
+    assert attrs == {"chosen": "xla", "programs": k + 1, "stages": k}
+    assert attrs["programs"] <= 3
 
 
 def test_pallas_self_test_is_a_noop_off_tpu(monkeypatch):
