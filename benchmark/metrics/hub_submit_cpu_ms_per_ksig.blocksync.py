@@ -1,0 +1,17 @@
+"""hub_submit_cpu_ms_per_ksig.blocksync
+
+On-CPU ms of `hub.submit` (`verify_many`'s submit loop + flush) over thousands of signatures
+submitted: the WORK inside `hub_submit_ms_per_ksig.blocksync`, whose wall reading also holds the
+worker thread's wait for the GIL and for the hub's lock.
+"""
+
+from benchmark import cpu_readers
+
+LAYER = "scheduler"
+UNIT = "ms/ksig"
+SOURCE = "program_span"
+MOVES = "blocksync_blocks_per_s"
+
+
+def read(r):
+    return cpu_readers.cpu_ms_per_ksig(r, "n", "hub.submit")

@@ -11,16 +11,23 @@ accepted: ``{"spans": [...]}`` wrappers or a bare span list.
     python scripts/tracectl.py dump.json --trace 42 # one trace, as a tree
     python scripts/tracectl.py dump.json --subsystem hub
     python scripts/tracectl.py dump.json --per-device  # mesh shard table
+    python scripts/tracectl.py dump.json --per-thread  # rows, wall, on-CPU by thread
 
 The per-stage table answers the ROADMAP question ("where did this vote
 spend its time?") in aggregate: count, p50, p90, p99, max, total and
 SELF time (the stage minus what its children — by ``parent_id`` — cover)
 per (subsystem, name) stage. ``--trace`` prints one end-to-end trace as
 a tree by ``parent_id``, children under their parent in start order,
-each with its self time: a block-sync range reads
+each with its self time — and, where the rows carry them (a recorder
+that reads its thread's CPU clock), its on-CPU time and its thread: a
+block-sync range reads
 ``blocksync.range > build, verify > validation.* > hub.* > batch.route >
 tpu.*``, then one ``blocksync.apply > state.*`` per block. Dumps from
-before span ids existed fall back to start order.
+before span ids existed fall back to start order. Rows that carry
+``cpu_ms`` add a ``cpums`` column to the table (wall minus on-CPU is what
+the stage's threads spent waiting: for the GIL, the device, a future) and
+a per-thread summary under it: rows, wall and on-CPU ms of each thread's
+outermost spans. Dumps without the keys render as before.
 
 The same spans appear in any ``jax.profiler`` trace taken while the node
 runs (``jax.profiler.start_trace`` / the profiler server): events named
@@ -93,12 +100,15 @@ def summarize(spans: list[dict]) -> str:
     """Per-stage latency table (the shape the acceptance run reads)."""
     stages: dict[str, list[float]] = {}
     selfs: dict[str, float] = {}
+    cpus: dict[str, float] = {}
     own = self_times(spans)
     for s in spans:
         key = f"{s.get('subsystem', '?')}.{s.get('name', '?')}"
         dur = float(s.get("duration_ms", 0.0))
         stages.setdefault(key, []).append(dur)
         selfs[key] = selfs.get(key, 0.0) + own.get(s.get("span_id"), dur)
+        if "cpu_ms" in s:
+            cpus[key] = cpus.get(key, 0.0) + float(s["cpu_ms"])
     if not stages:
         return "no spans"
     rows = []
@@ -121,12 +131,63 @@ def summarize(spans: list[dict]) -> str:
         f"{'stage':<28} {'count':>7} {'p50ms':>9} {'p90ms':>9} {'p99ms':>9} "
         f"{'maxms':>9} {'totalms':>10} {'selfms':>10}"
     )
+    if cpus:  # on-CPU ms of the stage's spans that carry a reading
+        header += f" {'cpums':>10}"
     lines = [header, "-" * len(header)]
     for key, n, p50, p90, p99, mx, total, own_ms in rows:
-        lines.append(
+        line = (
             f"{key:<28} {n:>7} {p50:>9.3f} {p90:>9.3f} {p99:>9.3f} "
             f"{mx:>9.3f} {total:>10.2f} {own_ms:>10.2f}"
         )
+        if cpus:
+            line += f" {cpus[key]:>10.2f}" if key in cpus else f" {'-':>10}"
+        lines.append(line)
+    if cpus:
+        lines += ["", per_thread(spans)]
+    return "\n".join(lines)
+
+
+def thread_totals(spans: list[dict]) -> dict:
+    """thread -> [rows, wall ms, on-CPU ms]: every row the thread
+    recorded, and the wall and on-CPU time of its OUTERMOST spans that
+    carry ``cpu_ms`` (a nested span's time is inside its parent's; where
+    two spans of one thread overlap partly — tasks of one event loop —
+    the later one counts by the share that sticks out)."""
+    out: dict = {}
+    by_thread: dict = {}
+    for s in spans:
+        if "thread" not in s:
+            continue
+        out.setdefault(s["thread"], [0, 0.0, 0.0])[0] += 1
+        if "cpu_ms" in s:
+            by_thread.setdefault(s["thread"], []).append(s)
+    for thread, mine in by_thread.items():
+        mine.sort(key=lambda s: (s.get("start_s", 0.0), -s.get("duration_ms", 0.0)))
+        end = float("-inf")
+        for s in mine:
+            a = s.get("start_s", 0.0) * 1e3
+            dur = float(s.get("duration_ms", 0.0))
+            b = a + dur
+            if b <= end:
+                continue
+            out_ms = b - max(a, end)
+            out[thread][1] += out_ms
+            out[thread][2] += float(s["cpu_ms"]) * (out_ms / dur if dur > 0 else 1.0)
+            end = b
+    return out
+
+
+def per_thread(spans: list[dict]) -> str:
+    """Per-thread summary: which thread recorded how many rows, and how
+    long its spans had it on a core against how long they were open."""
+    totals = thread_totals(spans)
+    if not totals:
+        return "no row carries a thread (a dump from before the recorder kept it)"
+    header = f"{'thread':<28} {'rows':>7} {'wallms':>11} {'cpums':>11} {'on-cpu':>7}"
+    lines = [header, "-" * len(header)]
+    for thread, (n, wall, cpu) in sorted(totals.items(), key=lambda kv: -kv[1][2]):
+        share = f"{cpu / wall:>6.1%}" if wall > 0 else f"{'-':>6}"
+        lines.append(f"{thread!s:<28} {n:>7} {wall:>11.2f} {cpu:>11.2f} {share:>7}")
     return "\n".join(lines)
 
 
@@ -188,9 +249,12 @@ def render_trace(spans: list[dict], trace_id: int) -> str:
         extra = " ".join(f"{k}={v}" for k, v in attrs.items())
         dur = s.get("duration_ms", 0.0)
         label = "  " * depth + f"{s.get('subsystem', '?')}.{s.get('name', '?')}"
+        cpu = f"cpu {s['cpu_ms']:9.3f}ms " if "cpu_ms" in s else ""
+        if "thread" in s:
+            extra = f"[{s['thread']}] {extra}"
         lines.append(
             f"  +{at:9.3f}ms {label:<34} {dur:9.3f}ms "
-            f"self {own.get(s.get('span_id'), dur):9.3f}ms  {extra}"
+            f"self {own.get(s.get('span_id'), dur):9.3f}ms {cpu} {extra}"
         )
         for c in children.get(s.get("span_id"), ()):
             walk(c, depth + 1)
@@ -206,6 +270,11 @@ def main(argv: list[str] | None = None) -> int:
     ap.add_argument("--subsystem", help="only this subsystem's spans")
     ap.add_argument("--trace", type=int, help="print one trace as a tree, with self times")
     ap.add_argument(
+        "--per-thread",
+        action="store_true",
+        help="per-thread rows, wall and on-CPU ms (rows that carry thread / cpu_ms)",
+    )
+    ap.add_argument(
         "--per-device",
         action="store_true",
         help="per-device mesh shard occupancy from hub.dispatch spans",
@@ -220,6 +289,8 @@ def main(argv: list[str] | None = None) -> int:
         spans = [s for s in spans if s.get("subsystem") == args.subsystem]
     if args.trace is not None:
         print(render_trace(spans, args.trace))
+    elif args.per_thread:
+        print(per_thread(spans))
     elif args.per_device:
         print(per_device(spans))
     else:

@@ -56,6 +56,32 @@ Two recording APIs:
     boundary timestamps for contiguous pipeline stages, so per-stage
     durations share boundaries and sum EXACTLY to the end-to-end time.
 
+Who ran it, and how long on a core. Every ring row carries the id of
+the thread that recorded it (``thread`` in a dump: the thread's name
+where it is still alive at dump time, else its id — the event loop, the
+hub's dispatcher and runners, the host lane's pool and every
+``to_thread`` worker write this one ring). A `Span` also reads the
+calling thread's CPU clock (``time.thread_time``:
+CLOCK_THREAD_CPUTIME_ID) at enter and at exit: ``cpu_ms`` is the
+THREAD's time on a core between the two, so ``duration_ms - cpu_ms`` is
+what the thread spent off the core — waiting for the GIL in a span that
+is pure Python, for the device in ``tpu.collect``, for a future in
+``hub.wait``. In a synchronous span that is the span's own work; in a
+span that awaits (``blocksync.verify``, ``blocksync.apply``,
+``light.fetch``, the roots) it also counts whatever other task the
+event loop ran on that thread meanwhile — read ``cpu_ms`` as work only
+on spans with no ``await`` inside. A span closed on another thread than
+it was entered on gets no ``cpu_ms``, never a wrong one; `record` /
+`finish` / `emit` rows are made from boundary timestamps, not from a
+measured stretch, and carry ``thread`` alone. A ``root=True`` span also
+reads the PROCESS's CPU clock (``time.process_time``) →
+``proc_cpu_ms``: over the span's wall time it is how many cores the host
+really used (≈ 1.0 is a process that is GIL-bound however many threads
+it has). The CPU clocks are always the real ones — a `ManualClock`
+trace still reports true CPU — and enter nothing but the ring, so they
+cannot touch same-seed reproducibility. No knob: they follow the
+recorder's one switch.
+
 The ring dumps on demand (`/debug/traces`, `scripts/tracectl.py`) and
 automatically on wedge/breaker-trip via `auto_dump(reason)` (wired from
 `libs/watchdog.LoopWatchdog` and the TPU breaker in `crypto/batch.py`).
@@ -75,7 +101,10 @@ import json
 import logging
 import os
 import re
+import threading
 from collections import deque
+from threading import get_ident
+from time import process_time, thread_time
 
 from .clock import SYSTEM, Clock
 
@@ -143,10 +172,11 @@ class Span:
 
     __slots__ = (
         "_rec", "trace_id", "span_id", "parent_id", "subsystem", "name", "clock",
-        "_t0", "attrs", "_token", "_ann",
+        "_t0", "attrs", "_token", "_ann", "_thread", "_cpu0", "_proc0",
     )
 
-    def __init__(self, rec, trace_id, parent_id, subsystem, name, clock, attrs):
+    def __init__(self, rec, trace_id, parent_id, subsystem, name, clock, attrs,
+                 root=False):
         self._rec = rec
         self.trace_id = trace_id
         self.span_id = next(_span_ids)
@@ -158,6 +188,9 @@ class Span:
         self.attrs = attrs
         self._token = None
         self._ann = None
+        # `_thread` and `_cpu0` are set at enter. None = not a root: the
+        # process's CPU clock is not read
+        self._proc0 = 0.0 if root else None
 
     def set(self, **attrs) -> None:
         self.attrs.update(attrs)
@@ -167,11 +200,23 @@ class Span:
         if _annotator is not None:
             self._ann = _annotator(f"tm.{self.subsystem}.{self.name}")
             self._ann.__enter__()
+        self._thread = get_ident()
+        if self._proc0 is not None:
+            self._proc0 = process_time()
         self._t0 = self.clock.monotonic()
+        # the CPU stretch lies inside the wall stretch: cpu <= duration
+        # on the system clock, whatever the two reads cost
+        self._cpu0 = thread_time()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
+        # another thread's CPU clock says nothing about this span
+        cpu = (
+            thread_time() - self._cpu0
+            if get_ident() == self._thread else None
+        )
         dur = self.clock.monotonic() - self._t0
+        proc = None if self._proc0 is None else process_time() - self._proc0
         if self._ann is not None:
             self._ann.__exit__(None, None, None)
         try:
@@ -191,6 +236,9 @@ class Span:
             self.attrs or None,
             self.span_id,
             self.parent_id,
+            self._thread,
+            cpu,
+            proc,
         )
 
 
@@ -229,7 +277,8 @@ class FlightRecorder:
         self.ring_size = max(1, ring_size)
         self.out_dir = out_dir
         # (trace_id, subsystem, name, start_s, duration_s, attrs|None,
-        #  span_id, parent_id); rows land in the order spans END
+        #  span_id, parent_id, thread, cpu_s|None, proc_cpu_s|None); rows
+        #  land in the order spans END
         self._ring: deque[tuple] = deque(maxlen=self.ring_size)
         self.recorded = 0  # total appended; dropped = recorded - len(ring)
         # auto_dump records (reason + path); bounded — /debug/flight?dump=
@@ -241,13 +290,15 @@ class FlightRecorder:
     # -- recording -------------------------------------------------------
 
     def _append(
-        self, trace_id, subsystem, name, start_s, dur_s, attrs, span_id=0, parent_id=0
+        self, trace_id, subsystem, name, start_s, dur_s, attrs, span_id=0, parent_id=0,
+        thread=0, cpu_s=None, proc_cpu_s=None,
     ) -> None:
         # deque.append with maxlen evicts the oldest atomically under the
         # GIL — safe from both the event loop and the hub's threads
         self._ring.append(
             (trace_id, subsystem, name, start_s, dur_s, attrs,
-             span_id or next(_span_ids), parent_id)
+             span_id or next(_span_ids), parent_id,
+             thread or get_ident(), cpu_s, proc_cpu_s)
         )
         self.recorded += 1
 
@@ -306,7 +357,9 @@ class FlightRecorder:
         if not self.enabled:
             return NOP_SPAN
         if root:
-            return Span(self, next(_ids), 0, subsystem, name, clock or SYSTEM, attrs)
+            return Span(
+                self, next(_ids), 0, subsystem, name, clock or SYSTEM, attrs, True
+            )
         if ctx is None:
             ctx = _current.get()
             if ctx is None:
@@ -351,10 +404,13 @@ class FlightRecorder:
         trace_id: int | None = None,
     ) -> list[dict]:
         """Last `n` spans (oldest first) as JSON-ready dicts, optionally
-        filtered by subsystem or trace id."""
+        filtered by subsystem or trace id. `thread` is the recording
+        thread's name where it is still alive, else its id (an id the
+        system has handed to a later thread reads that thread's name)."""
         spans = list(self._ring)
+        names = {t.ident: t.name for t in threading.enumerate()}
         out = []
-        for tid, sub, name, start, dur, attrs, sid, pid in spans:
+        for tid, sub, name, start, dur, attrs, sid, pid, thread, cpu, proc in spans:
             if subsystem is not None and sub != subsystem:
                 continue
             if trace_id is not None and tid != trace_id:
@@ -367,7 +423,12 @@ class FlightRecorder:
                 "name": name,
                 "start_s": round(start, 6),
                 "duration_ms": round(dur * 1e3, 4),
+                "thread": names.get(thread, thread),
             }
+            if cpu is not None:
+                d["cpu_ms"] = round(cpu * 1e3, 4)
+            if proc is not None:
+                d["proc_cpu_ms"] = round(proc * 1e3, 4)
             if attrs:
                 d["attrs"] = attrs
             out.append(d)

@@ -55,7 +55,10 @@ def test_traced_run_reports_the_planner_s_layers(root):
             "hub_sigs_per_dispatch.churn", "hub_submit_ms_per_ksig.churn",
             "verify_self_ms_per_block.churn", "store_ms_per_block.churn",
             "exec_ms_per_block.churn", "device_route_share.churn",
-            "inline_compiles.churn"} == set(m)
+            "inline_compiles.churn",
+            # the recorder's CPU readings (PR 37)
+            "host_off_cpu_share.churn", "host_cores_busy.churn"} == set(m)
+    assert 0 <= m["host_off_cpu_share.churn"] <= 100 and m["host_cores_busy.churn"] > 0
     assert 2 <= m["plan_commits_per_verify.churn"] <= 5  # a change every 4 heights
     assert m["plan_sets_per_verify.churn"] in (1.0, 2.0)
     assert m["cuts_per_range.churn"] >= 3 and m["sequential_block_share.churn"] == 0.0

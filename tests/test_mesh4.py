@@ -219,7 +219,11 @@ def test_light_client_on_the_mesh_equals_the_reference(mesh4, monkeypatch, tmp_p
     assert {"verify_ms_per_header.mesh4", "tpu_prep_ms_per_ksig.mesh4",
             "tpu_dispatch_ms_per_dispatch.mesh4", "device_wait_ms_per_dispatch.mesh4",
             "device_route_share.mesh4", "inline_compiles.mesh4",
-            "shard_fill_min_share.mesh4"} <= set(m)
+            "shard_fill_min_share.mesh4",
+            "tpu_dispatch_cpu_ms_per_dispatch.mesh4"} <= set(m)
+    # on-CPU ms of the SHARDED dispatches alone (the wall twin averages over
+    # every `tpu.dispatch`, the one-chip ones of this tiny cell among them)
+    assert m["tpu_dispatch_cpu_ms_per_dispatch.mesh4"] > 0
     assert not any(k.startswith(("kernel_", "device_idle")) for k in m)
     assert m["device_route_share.mesh4"] == 100.0
     # 98 real rows over shards of 32: 32, 32, 32, 2

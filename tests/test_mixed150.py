@@ -73,7 +73,13 @@ def test_tiny_mixed_cell_on_the_device_route(device_route, monkeypatch, tmp_path
             "host_lane_share.mixed", "edwards_row_share.mixed", "collect_ms_per_ksig.mixed",
             "verify_ms_per_header.mixed", "tpu_resolve_ms_per_ksig.mixed",
             "tpu_prep_ms_per_ksig.mixed", "device_wait_ms_per_dispatch.mixed",
-            "device_route_share.mixed", "inline_compiles.mixed"} == set(m)
+            "device_route_share.mixed", "inline_compiles.mixed",
+            # the recorder's CPU readings (PR 37): the work inside the wall twins
+            "collect_cpu_ms_per_ksig.mixed", "tpu_prep_cpu_ms_per_ksig.mixed",
+            "host_off_cpu_share.mixed", "host_cores_busy.mixed"} == set(m)
+    assert 0 < m["collect_cpu_ms_per_ksig.mixed"] <= m["collect_ms_per_ksig.mixed"] + 1e-6
+    assert 0 < m["tpu_prep_cpu_ms_per_ksig.mixed"] <= m["tpu_prep_ms_per_ksig.mixed"] + 1e-6
+    assert 0 <= m["host_off_cpu_share.mixed"] <= 100 and m["host_cores_busy.mixed"] > 0
     assert m["edwards_row_share.mixed"] == 50.0 and m["device_route_share.mixed"] == 50.0
     assert 0 <= m["host_lane_share.mixed"] <= 100 and m["host_lane_ms_per_ksig.mixed"] > 0
 

@@ -5,9 +5,11 @@ that matters most — tracing must not perturb same-seed chaos
 bit-reproducibility."""
 
 import asyncio
+import contextvars
 import importlib.util
 import json
 import os
+import threading
 import time
 
 import pytest
@@ -134,6 +136,184 @@ class TestRecorder:
             time.sleep(0.01)
         (s,) = rec.dump()
         assert s["duration_ms"] >= 5.0
+
+
+# ---------------------------------------------------------------------------
+# which thread ran a span, and how long that thread was on a core (ISSUE 37).
+# Every case compares a span with itself (its CPU reading against its own
+# duration, or against what the test itself burnt), never with the wall.
+
+
+def _burn(cpu_s: float) -> None:
+    """Hold a core until THIS thread's CPU clock has advanced `cpu_s`."""
+    t0 = time.thread_time()
+    while time.thread_time() - t0 < cpu_s:
+        sum(range(2000))
+
+
+OLD_KEYS = {"trace_id", "span_id", "parent_id", "subsystem", "name", "start_s",
+            "duration_ms", "attrs"}
+
+
+class TestThreadAndCpu:
+    def test_a_busy_span_reads_its_cpu(self):
+        rec = FlightRecorder(enabled=True, ring_size=8)
+        with rec.span("t", "busy"):
+            _burn(0.020)
+        (s,) = rec.dump()
+        assert 20.0 <= s["cpu_ms"] <= s["duration_ms"] + 1.0
+
+    def test_a_sleeping_span_reads_almost_none(self):
+        rec = FlightRecorder(enabled=True, ring_size=8)
+        with rec.span("t", "asleep"):
+            time.sleep(0.05)
+        (s,) = rec.dump()
+        assert s["duration_ms"] >= 45.0 and s["cpu_ms"] < 10.0
+
+    def test_two_named_threads_dump_their_names(self):
+        rec = FlightRecorder(enabled=True, ring_size=8)
+        gate = threading.Barrier(3, timeout=30)
+
+        def work(name):
+            with rec.span("t", name):
+                pass
+            gate.wait()  # both stay alive until the dump is taken
+            gate.wait()
+
+        threads = [threading.Thread(target=work, args=(n,), name=f"tm-test-{n}")
+                   for n in ("a", "b")]
+        for t in threads:
+            t.start()
+        gate.wait()
+        dumped = rec.dump()
+        gate.wait()
+        for t in threads:
+            t.join(30)
+            assert not t.is_alive()
+        assert {s["name"]: s["thread"] for s in dumped} == {
+            "a": "tm-test-a", "b": "tm-test-b"}
+        # once its thread is gone a row falls back to the thread's id
+        assert all(isinstance(s["thread"], int) for s in rec.dump())
+
+    def test_a_span_closed_on_another_thread_has_no_cpu_reading(self):
+        rec = FlightRecorder(enabled=True, ring_size=8)
+        sp = rec.span("t", "handed_over")
+        # entered in a copy of the context: the span that never exits here
+        # must not stay this thread's current span for the tests that follow
+        contextvars.copy_context().run(sp.__enter__)
+        assert trace.current() is None
+        t = threading.Thread(target=sp.__exit__, args=(None, None, None),
+                             name="tm-test-closer")
+        t.start()
+        t.join(30)
+        assert not t.is_alive()
+        (s,) = rec.dump()
+        assert "cpu_ms" not in s and s["duration_ms"] >= 0.0
+        # the row names the thread that ENTERED it
+        assert s["thread"] == threading.current_thread().name
+
+    def test_a_root_span_also_reads_the_process(self):
+        rec = FlightRecorder(enabled=True, ring_size=8)
+        with rec.span("t", "root", root=True):
+            _burn(0.005)
+            with rec.span("t", "child"):
+                _burn(0.005)
+        child, root = rec.dump()
+        assert root["proc_cpu_ms"] >= root["cpu_ms"] - 1.0 and root["cpu_ms"] >= 10.0
+        assert "proc_cpu_ms" not in child and child["cpu_ms"] >= 5.0
+
+    def test_boundary_rows_carry_the_thread_alone(self):
+        rec = FlightRecorder(enabled=True, ring_size=8)
+        ctx = rec.start()
+        rec.record(ctx, "t", "stage", ctx.t0, ctx.t0 + 0.5)
+        rec.emit("t", "event", duration_s=0.1)
+        rec.finish(ctx, "t", "done")
+        rows = rec.dump()
+        assert [s["name"] for s in rows] == ["stage", "event", "done"]
+        me = threading.current_thread().name
+        for s in rows:
+            assert s["thread"] == me
+            assert "cpu_ms" not in s and "proc_cpu_ms" not in s
+
+    @pytest.mark.parametrize("clock", ["manual", "stub"])
+    def test_wall_follows_the_injected_clock_and_cpu_stays_real(self, clock):
+        rec = FlightRecorder(enabled=True, ring_size=8)
+        clk = ManualClock(0) if clock == "manual" else _StubClock()
+        with rec.span("t", "s", clock=clk):
+            _burn(0.005)
+        (s,) = rec.dump()
+        if clock == "stub":  # one read at enter, one at exit: exactly 1 s
+            assert s["duration_ms"] == pytest.approx(1000.0)
+            assert 5.0 <= s["cpu_ms"] < 1000.0
+        else:  # ManualClock's monotonic domain is the real one
+            assert 5.0 <= s["cpu_ms"] <= s["duration_ms"] + 1.0
+
+    def test_dump_keeps_every_key_it_had(self):
+        rec = FlightRecorder(enabled=True, ring_size=8)
+        with rec.span("t", "s", root=True, k=1):
+            pass
+        (s,) = rec.dump()
+        assert OLD_KEYS <= set(s)
+        assert set(s) - OLD_KEYS == {"thread", "cpu_ms", "proc_cpu_ms"}
+        json.dumps(s)  # /debug/traces serves it as JSON
+
+    def test_disabled_reads_no_clock_and_no_thread(self, monkeypatch):
+        def boom():
+            raise AssertionError("read while the recorder is disabled")
+
+        for name in ("thread_time", "process_time", "get_ident"):
+            monkeypatch.setattr(trace, name, boom)
+        rec = FlightRecorder(enabled=False, ring_size=8)
+        assert rec.span("t", "s", root=True) is NOP_SPAN
+        with rec.span("t", "s", root=True):
+            pass
+        rec.emit("t", "e")
+        rec.record(None, "t", "r", 0.0, 1.0)
+        rec.finish(None, "t", "f")
+        assert len(rec) == 0 and rec.recorded == 0
+
+    def test_tracectl_shows_cpu_and_threads_where_the_rows_carry_them(self):
+        tracectl = _load_tracectl()
+        rec = FlightRecorder(enabled=True, ring_size=16)
+        with rec.span("t", "root", root=True) as root:
+            with rec.span("t", "busy"):
+                _burn(0.005)
+            with rec.span("t", "asleep"):
+                time.sleep(0.02)
+        spans = rec.dump()
+        table = tracectl.summarize(spans)
+        assert "cpums" in table.splitlines()[0]
+        me = threading.current_thread().name
+        (mine,) = [ln for ln in table.splitlines() if ln.startswith(me)]
+        # one thread: 3 rows; its outermost span's wall and CPU, not the sum of all three
+        rows, wall, cpu = int(mine.split()[-4]), float(mine.split()[-3]), float(mine.split()[-2])
+        by_name = {s["name"]: s for s in spans}
+        assert rows == 3
+        assert wall == pytest.approx(by_name["root"]["duration_ms"], abs=0.02)
+        assert cpu == pytest.approx(by_name["root"]["cpu_ms"], abs=0.02)
+        tree = tracectl.render_trace(spans, root.trace_id)
+        assert "cpu " in tree and f"[{me}]" in tree
+        assert tracectl.thread_totals(spans)[me][0] == 3
+
+    def test_tracectl_renders_an_old_dump_as_before(self):
+        tracectl = _load_tracectl()
+        rec = FlightRecorder(enabled=True, ring_size=16)
+        with rec.span("t", "root", root=True, k=1) as root:
+            with rec.span("t", "child"):
+                pass
+        new = rec.dump()
+        old = [{k: v for k, v in s.items() if k in OLD_KEYS} for s in new]
+        table = tracectl.summarize(old)
+        assert "cpums" not in table and "thread" not in table
+        assert table.splitlines()[0].split() == [
+            "stage", "count", "p50ms", "p90ms", "p99ms", "maxms", "totalms", "selfms"]
+        # the same rows, the same table above the new column and summary
+        assert [ln[: len(table.splitlines()[0])] for ln in
+                tracectl.summarize(new).splitlines()[: len(table.splitlines())]] == (
+            table.splitlines())
+        tree = tracectl.render_trace(old, root.trace_id)
+        assert "cpu " not in tree and "[" not in tree
+        assert "no row carries a thread" in tracectl.per_thread(old)
 
 
 class TestWedgeDump:
