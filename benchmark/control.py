@@ -90,7 +90,7 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     seeds = [int(s) for s in args.seeds.split(",")]
     run.place_compile_cache(args.workload)
-    device = harness.attach(1)
+    device = harness.attach(run.load_cell(harness.ROOT, args.workload)[1]["chips"])
     rc = 0
     plan = [(s, None) for s in seeds]
     if args.control:

@@ -9,8 +9,14 @@ as many as fill the pool's request window.
 
 Closed loop, one pass by a fresh node from height 1, so no signature is
 seen twice (the hub remembers verdicts). The window runs from
-`reactor.start()` for --seconds, or to the chain's end if that comes
-first; the rate is blocks applied over the seconds that passed.
+`reactor.start()` for --seconds; the rate is blocks applied over the
+seconds that passed. The chain (the cell file's `blocks`) is sized to
+outlast the window and the traced stretch after it at several times the
+rate the program reads today; every result line says how much of it was
+left (`chain_left_blocks`). A chain that ends inside an untraced window
+closes it there and reads 1 left (the last block waits for a successor's
+commit); in a traced run it raises `ChainEnded`: there is no stretch to
+trace, and the cell file's `blocks` is what to lengthen.
 """
 
 from __future__ import annotations
@@ -24,41 +30,49 @@ from benchmark import reference as ref
 from benchmark.harness import Check, say
 
 END_TO_END = "blocksync_blocks_per_s"
+#: the fixture is wire bytes and tables: built by `harness.FixtureChild`
+FIXTURE = "child"
+
+
+class ChainEnded(RuntimeError):
+    """A traced run's chain ended before its traced stretch did."""
 
 
 @dataclass
 class Fixture:
-    chain: fixtures.KVChain
     warm: fixtures.KVChain
     warm_bad_height: int  # the commit FOR this height is corrupted
     warm_bad_index: int
+    chain: fixtures.KVChain | None = None  # the window's: handed over after the rest
     observed: dict = field(default_factory=dict)
     hub: object = None
 
 
-def build(cfg: dict, cell: dict, seed: int) -> Fixture:
+def build(cfg: dict, cell: dict, seed: int):
+    """The warm-up chain and its corruption first (the cell's warm-up starts
+    on them), then the window's chain: what a run holds of a chain is its
+    wire bytes and tables (`harness.assemble` for the protocol)."""
     p, v = cell["traffic"], cfg["validators"]
-
-    async def both():
-        chain = await fixtures.kvstore_chain(
-            seed, "sync", p["blocks"], v["count"], v["power"], p["txs_per_block"])
-        warm = await fixtures.kvstore_chain(
-            seed, "swarm", p["warmup_blocks"], v["count"], v["power"], p["txs_per_block"])
-        return chain, warm
-
     t0 = time.perf_counter()
-    chain, warm = asyncio.run(both())
+    warm = asyncio.run(fixtures.kvstore_chain(
+        seed, "swarm", p["warmup_blocks"], v["count"], v["power"], p["txs_per_block"]))
     needed = ref.commit_verdict(warm.commit_data(1))[1]
     fx = Fixture(
-        chain=chain, warm=warm,
+        warm=warm,
         warm_bad_height=fixtures.seeded_index(seed, "sbadh", 8, min(40, p["warmup_blocks"] - 8)),
         warm_bad_index=fixtures.seeded_index(seed, "sbadi", needed - max(1, needed // 10), needed - 1),
     )
-    say(f"blocksync: built {p['blocks']}-block chain + {p['warmup_blocks']}-block "
-        f"warm-up chain ({v['count']} validators, {p['txs_per_block']} txs a block, "
-        f"{needed} signatures reach > 2/3) in {time.perf_counter() - t0:.1f}s; warm-up "
-        f"corruption: commit for height {fx.warm_bad_height}, signature {fx.warm_bad_index}")
-    return fx
+    say(f"blocksync: built {p['warmup_blocks']}-block warm-up chain ({v['count']} validators, "
+        f"{p['txs_per_block']} txs a block, {needed} signatures reach > 2/3) in "
+        f"{time.perf_counter() - t0:.1f}s; warm-up corruption: commit for height "
+        f"{fx.warm_bad_height}, signature {fx.warm_bad_index}")
+    yield fx
+    t0 = time.perf_counter()
+    chain = asyncio.run(fixtures.kvstore_chain(
+        seed, "sync", p["blocks"], v["count"], v["power"], p["txs_per_block"]))
+    say(f"blocksync: built {p['blocks']}-block chain in {time.perf_counter() - t0:.1f}s, "
+        f"{sum(map(len, chain.wire.values()))} wire bytes")
+    yield {"chain": chain}
 
 
 def install(patches: harness.Patches, spans: harness.Spans, traced: bool) -> None:
@@ -116,6 +130,7 @@ class Sync:
     t0: float = 0.0
     t1: float = 0.0
     ended_early: bool = False
+    chain_left: int = 0  # chain length minus the store height where the run's reading ended
 
 
 async def _sync(chain: fixtures.KVChain, cell: dict, seconds: float, spans: harness.Spans,
@@ -219,7 +234,9 @@ async def _sync(chain: fixtures.KVChain, cell: dict, seconds: float, spans: harn
             stretch_end = time.perf_counter() + trace_seconds
             while time.perf_counter() < stretch_end and not reactor.synced.is_set():
                 await asyncio.sleep(0.005)
+            out.ended_early = reactor.synced.is_set()
             trace.stop()
+        out.chain_left = chain.n_blocks - bstore.height()
     finally:
         if trace is not None:
             trace.stop()
@@ -246,7 +263,7 @@ def _bad_wire(chain: fixtures.KVChain, height: int, sig_index: int) -> dict:
 
     from tendermint_tpu.blocksync import messages as bsm
 
-    nxt = chain.store.load_block(height + 1)
+    nxt = chain.block(height + 1)
     forged = dataclasses.replace(
         nxt, last_commit=fixtures.corrupt_commit(nxt.last_commit, sig_index))
     return {height + 1: bsm.encode_message(bsm.BlockResponse(forged))}
@@ -261,7 +278,7 @@ def _warm_shapes(fx: Fixture) -> list[str]:
 
     items = []
     for h in range(1, 8):
-        c = fx.warm.store.load_seen_commit(h)
+        c = fx.warm.commit(h)
         n = ref.commit_verdict(fx.warm.commit_data(h))[1]
         for idx in range(n):
             items.append((fx.warm.vals.validators[idx].pub_key,
@@ -325,6 +342,12 @@ class Window:
     def metrics(self) -> dict:
         return {END_TO_END: self.units / self.elapsed}
 
+    @property
+    def report(self) -> dict:
+        """Beside the metrics, in every result line: the room the chain had
+        left at the end of the traced stretch (of the window, untraced)."""
+        return {"chain_left_blocks": self.sync.chain_left}
+
 
 def window(fx: Fixture, cfg: dict, cell: dict, seconds: float,
            patches: harness.Patches, trace: harness.DeviceTrace | None,
@@ -333,9 +356,17 @@ def window(fx: Fixture, cfg: dict, cell: dict, seconds: float,
                           trace=trace, on_close=on_close))
     w = Window(sync=s, elapsed=s.t1 - s.t0, t0=s.t0, t1=s.t1,
                units=s.height_at_close, trace=trace)
+    if trace is not None and s.ended_early:
+        raise ChainEnded(
+            f"{cell['name']}: the chain ended before the traced stretch did — its "
+            f"{fx.chain.n_blocks} blocks (`traffic.blocks` of the cell's workload file) were "
+            f"used up at {w.units / w.elapsed:.1f} blocks/s ({w.units} applied in the "
+            f"{w.elapsed:.3f}s window, store height {s.final_height} at the end): nothing "
+            f"left to trace. Lengthen `blocks`")
     say(f"blocksync window: {w.units} blocks applied in {w.elapsed:.3f}s "
         f"({'chain ended first' if s.ended_early else 'closed on time'}); "
-        f"final height {s.final_height}, peer errors {s.peer_errors}")
+        f"final height {s.final_height}, {s.chain_left} of the chain's {fx.chain.n_blocks} "
+        f"blocks left, peer errors {s.peer_errors}")
     return w
 
 
@@ -376,7 +407,7 @@ def compare(fx: Fixture, w: Window, d: dict, spans: harness.Spans) -> tuple[list
     order_faults += abs(len(s.applied) - s.final_height)
     stored_bad = sum(
         1 for h in range(1, s.final_height + 1)
-        if s.stored_hashes.get(h) != fx.chain.store.load_block_meta(h).block_id.hash)
+        if s.stored_hashes.get(h) != fx.chain.block_hash_at[h])
     want_hash = ref.kv_state_hash(
         [tx for h in range(1, s.final_height + 1) for tx in fx.chain.txs_at[h]])
     app_bad = int(s.app_hash != want_hash) + int(
@@ -385,7 +416,7 @@ def compare(fx: Fixture, w: Window, d: dict, spans: harness.Spans) -> tuple[list
              + d.get("hub.coalesced", 0.0))
 
     # warm-up: the reference refuses the corrupted commit, and only it
-    bad_block = fx.warm.store.load_block(fx.warm_bad_height + 1)
+    bad_block = fx.warm.block(fx.warm_bad_height + 1)
     forged = fixtures.commit_data(
         fx.warm.chain_id,
         fixtures.corrupt_commit(bad_block.last_commit, fx.warm_bad_index), fx.warm.vals)

@@ -47,6 +47,7 @@ from benchmark.drivers import blocksync as base
 from benchmark.harness import Check, say
 
 END_TO_END = base.END_TO_END
+FIXTURE = base.FIXTURE
 window = base.window
 release = base.release
 
@@ -57,32 +58,32 @@ _SEEN = {"nodes": [], "singles": []}
 
 @dataclass
 class Fixture:
-    chain: fixtures_churn.ChurnChain
     warm: fixtures_churn.ChurnChain
     warm_bad: dict  # kind -> height whose commit the stand-in serves corrupted
     warm_bad_index: int  # the signature the bitflip flips
+    warm_stale_commit: object  # the forged commit for warm_bad["stale_set"]
     sample_heights: list
+    chain: fixtures_churn.ChurnChain | None = None  # the window's: handed over after the rest
     observed: dict = field(default_factory=dict)
     hub: object = None
 
 
-def build(cfg: dict, cell: dict, seed: int) -> Fixture:
+def build(cfg: dict, cell: dict, seed: int):
+    """`blocksync.build`'s order and protocol: the warm-up chain, its two
+    corruptions and the stale-set commit (signed here: the private keys do
+    not leave the process that made them), then the window's chain."""
     p, v, rot = cell["traffic"], cfg["validators"], cfg["rotation"]
     for key, want in (("rotation_period", rot["period"]), ("swap_every", rot["swap_every"])):
         if p[key] != want:
             raise RuntimeError(f"cell states {key} {p[key]}, the configuration {want}")
 
-    async def both():
-        chain = await fixtures_churn.churn_chain(
-            seed, "churn", p["blocks"], v["count"], v["power"], p["txs_per_block"],
-            p["rotation_period"], p["swap_every"])
-        warm = await fixtures_churn.churn_chain(
-            seed, "cwarm", p["warmup_blocks"], v["count"], v["power"], p["txs_per_block"],
-            p["rotation_period"], p["swap_every"])
-        return chain, warm
+    def churn_chain(tag: str, blocks: int) -> fixtures_churn.ChurnChain:
+        return asyncio.run(fixtures_churn.churn_chain(
+            seed, tag, blocks, v["count"], v["power"], p["txs_per_block"],
+            p["rotation_period"], p["swap_every"]))
 
     t0 = time.perf_counter()
-    chain, warm = asyncio.run(both())
+    warm = churn_chain("cwarm", p["warmup_blocks"])
     n = p["warmup_blocks"]
     flip_h = fixtures.seeded_index(seed, "cbadh", 8, max(8, min(40, n // 2 - 4)))
     needed = ref.commit_verdict(warm.commit_data(flip_h))[1]
@@ -97,22 +98,28 @@ def build(cfg: dict, cell: dict, seed: int) -> Fixture:
         raise RuntimeError(f"seed {seed}: no power change of the warm-up chain moves a "
                            f"validator the quorum reads (first heights {firsts})")
     fx = Fixture(
-        chain=chain, warm=warm,
+        warm=warm.shed(),
         warm_bad={"bitflip": flip_h, "stale_set": stale_h},
         warm_bad_index=fixtures.seeded_index(
             seed, "cbadi", needed - max(1, needed // 10), needed - 1),
+        warm_stale_commit=warm.stale_commit(stale_h),
         sample_heights=sorted({fixtures.seeded_index(seed, f"cs{i}", 1, p["blocks"])
                                for i in range(33)}),
     )
+    say(f"churn: built {n}-block warm-up chain ({v['count']} validators, "
+        f"{p['txs_per_block']} txs a block) in {time.perf_counter() - t0:.1f}s; warm-up "
+        f"corruptions: bit {fx.warm_bad_index} of the commit for height {flip_h} ({needed} "
+        f"signatures reach > 2/3), stale-set commit for height {stale_h}")
+    yield fx
+    t0 = time.perf_counter()
+    chain = churn_chain("churn", p["blocks"]).shed()
     kinds = sorted(chain.changes.values())
-    say(f"churn: built {p['blocks']}-block chain + {n}-block warm-up chain "
-        f"({v['count']} validators, {p['txs_per_block']} txs a block) in "
-        f"{time.perf_counter() - t0:.1f}s; {kinds.count('power')} power changes and "
-        f"{kinds.count('swap')} swaps, {len({s.hash for s in chain.sets[1:]})} distinct sets, "
+    say(f"churn: built {p['blocks']}-block chain in {time.perf_counter() - t0:.1f}s, "
+        f"{sum(map(len, chain.wire.values()))} wire bytes; {kinds.count('power')} power changes "
+        f"and {kinds.count('swap')} swaps, {len({s.hash for s in chain.sets[1:]})} distinct sets, "
         f"{min(sum(s.powers) for s in chain.sets[1:])}-{max(sum(s.powers) for s in chain.sets[1:])} "
-        f"total power; warm-up corruptions: bit {fx.warm_bad_index} of the commit for height "
-        f"{flip_h} ({needed} signatures reach > 2/3), stale-set commit for height {stale_h}")
-    return fx
+        f"total power")
+    yield {"chain": chain}
 
 
 def install(patches: harness.Patches, spans: harness.Spans, traced: bool) -> None:
@@ -163,9 +170,9 @@ def _bad_wire(fx: Fixture, kind: str) -> dict:
     from tendermint_tpu.blocksync import messages as bsm
 
     h = fx.warm_bad[kind]
-    nxt = fx.warm.store.load_block(h + 1)
+    nxt = fx.warm.block(h + 1)
     forged = (fixtures.corrupt_commit(nxt.last_commit, fx.warm_bad_index)
-              if kind == "bitflip" else fx.warm.stale_commit(h))
+              if kind == "bitflip" else fx.warm_stale_commit)
     return {h + 1: bsm.encode_message(
         bsm.BlockResponse(dataclasses.replace(nxt, last_commit=forged)))}
 
@@ -241,7 +248,7 @@ def compare(fx: Fixture, w, d: dict, spans: harness.Spans) -> tuple[list[Check],
     order_faults += abs(len(s.applied) - s.final_height)
     stored_bad = sum(
         1 for h in range(1, s.final_height + 1)
-        if s.stored_hashes.get(h) != fx.chain.store.load_block_meta(h).block_id.hash)
+        if s.stored_hashes.get(h) != fx.chain.block_hash_at[h])
     want_hash = refc.kv_state_hash(
         [tx for h in range(1, s.final_height + 1) for tx in fx.chain.txs_at[h]])
     app_bad = int(s.app_hash != want_hash) + int(

@@ -42,7 +42,10 @@ class Fixture:
     observed: dict = field(default_factory=dict)
 
 
-def build(cfg: dict, cell: dict, seed: int) -> Fixture:
+def build(cfg: dict, cell: dict, seed: int):
+    """Yields the whole fixture ONCE (`harness.assemble`); built in the
+    measured process (`harness.FixtureHere`): the window walks these very
+    objects, the one `ValidatorSet` under every `LightBlock` of a chain."""
     p, v = cell["traffic"], cfg["validators"]
     chain = fixtures.light_chain(seed, "light", p["headers"], v["count"], v["power"])
     warm = fixtures.light_chain(seed, "lwarm", p["warmup_headers"], v["count"], v["power"])
@@ -62,7 +65,7 @@ def build(cfg: dict, cell: dict, seed: int) -> Fixture:
     say(f"light: built {p['headers']}-header chain + {p['warmup_headers']}-header "
         f"warm-up chain, {v['count']} validators ({needed} signatures reach > 2/3); "
         f"warm-up corruption at height {fx.warm_bad_height}, signature {fx.warm_bad_index}")
-    return fx
+    yield fx
 
 
 class _MemoryProvider:

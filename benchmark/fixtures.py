@@ -2,12 +2,17 @@
 with the program's own types (they are what the program is fed), and the
 same data read off as plain fields for the reference.
 
-The builders follow bench.py's `bench_light_client` /
-`_bench_blocksync_async` and chip_smoke.py's `_blocksync` (copied, not
-imported: the yardstick lives under benchmark/). The same seed gives the
-same bytes; a `tag` keeps the warm-up chain's chain ID and keys apart
+The builders follow chip_smoke.py's `_blocksync` and the light-client and
+block-sync benches of the monolithic bench script PR 29 deleted (copied,
+not imported: the yardstick lives under benchmark/). The same seed gives
+the same bytes; a `tag` keeps the warm-up chain's chain ID and keys apart
 from the window's, so that nothing warmed can be answered from a verdict
 cache inside the window.
+
+A block-sync run's fixture is built in a child process
+(`harness.FixtureChild`), so what a driver keeps of a chain is what crosses
+a pipe cheaply and sits in the measured process's heap as bytes: the wire
+blocks and per-height tables (`WireChain`), never a block object a height.
 """
 
 from __future__ import annotations
@@ -126,22 +131,40 @@ def with_corrupt_header(chain: LightChain, height: int, sig_index: int) -> list:
     return out
 
 
+class WireChain:
+    """What the drivers read of a block-sync chain: the wire bytes the peer
+    stand-ins serve and the per-height tables beside them."""
+
+    def block(self, height: int):
+        """Block `height`, decoded from its wire bytes as the node's router
+        decodes it on arrival."""
+        from tendermint_tpu.blocksync import messages as bsm
+
+        return bsm.decode_message(self.wire[height]).block
+
+    def commit(self, height: int):
+        """The commit FOR `height`: block height+1's LastCommit; for the
+        chain's last height, the commit its builder signed (`head_commit`:
+        no later block carries it)."""
+        if height == self.n_blocks:
+            return self.head_commit
+        return self.block(height + 1).last_commit
+
+
 @dataclasses.dataclass
-class KVChain:
+class KVChain(WireChain):
     chain_id: str
     genesis: object
     vals: object  # the (static) validator set
-    store: object  # source BlockStore, heights 1..n
     n_blocks: int
     app_hash_at: dict  # height -> app hash after executing it
     txs_at: dict  # height -> tuple of raw transactions
     wire: dict  # height -> encoded BlockResponse, as a peer would send it
+    block_hash_at: dict  # height -> the block's hash
+    head_commit: object  # the commit for height n_blocks
 
     def commit_data(self, height: int) -> ref.CommitData:
-        """The commit FOR `height` (carried by block height+1 as its
-        LastCommit, and kept by the source store as the seen commit)."""
-        commit = self.store.load_block_commit(height) or self.store.load_seen_commit(height)
-        return commit_data(self.chain_id, commit, self.vals)
+        return commit_data(self.chain_id, self.commit(height), self.vals)
 
 
 async def fresh_node(genesis):
@@ -192,7 +215,7 @@ async def kvstore_chain(
     by_addr = {k.pub_key().address(): k for k in keys}
     app, conns, store, state, ex = await fresh_node(genesis)
     vals: ValidatorSet = state.validators
-    app_hash_at, txs_at, wire = {}, {}, {}
+    app_hash_at, txs_at, wire, block_hash_at = {}, {}, {}, {}
     commit = None
     try:
         for h in range(1, n_blocks + 1):
@@ -219,9 +242,11 @@ async def kvstore_chain(
             )
             store.save_block(block, parts, commit)
             wire[h] = bsm.encode_message(bsm.BlockResponse(block))
+            block_hash_at[h] = bid.hash
     finally:
         await conns.stop()
     return KVChain(
-        chain_id=chain_id, genesis=genesis, store=store, n_blocks=n_blocks,
+        chain_id=chain_id, genesis=genesis, n_blocks=n_blocks,
         app_hash_at=app_hash_at, txs_at=txs_at, wire=wire, vals=vals,
+        block_hash_at=block_hash_at, head_commit=commit,
     )

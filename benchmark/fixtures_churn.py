@@ -24,27 +24,45 @@ from . import reference_churn as refc
 
 
 @dataclasses.dataclass
-class ChurnChain:
+class ChurnChain(fixtures.WireChain):
     chain_id: str
     genesis: object
     vals: object  # the GENESIS validator set (heights 1 .. period + 1)
-    store: object  # source BlockStore, heights 1..n
+    store: object  # the builder's source BlockStore (tier-1 tests read it); None once shed
     n_blocks: int
     app_hash_at: dict  # height -> app hash after executing it
     txs_at: dict  # height -> tuple of raw transactions, `val:` ones included
     wire: dict  # height -> encoded BlockResponse, as a peer would send it
     sets: list  # height -> reference_churn.ValSet (the REFERENCE's derivation)
     changes: dict  # height that carries a change -> "power" | "swap"
-    keys: dict  # address -> private key, of every validator that ever sat
+    keys: dict  # address -> private key, of every validator that ever sat; None once shed
     set_hash_at: dict  # height -> hash of the PROGRAM's set of that height
-    set_objs: dict  # that hash -> the program's ValidatorSet (to sign with)
+    set_objs: dict  # that hash -> the program's ValidatorSet (to sign with); None once shed
+    block_hash_at: dict = dataclasses.field(default_factory=dict)  # height -> the block's hash
+    head_commit: object = None  # the commit for height n_blocks
+
+    def __post_init__(self):
+        # a chain made BY HAND from a source store alone, as tier-1's
+        # `tests/test_blocksync_rotation.py` makes its cases (a file no
+        # benchmark PR may edit; PERF.md §7): the bytes everything here reads
+        if not self.wire and self.store is not None:
+            from tendermint_tpu.blocksync import messages as bsm
+
+            self.wire = {h: bsm.encode_message(bsm.BlockResponse(self.store.load_block(h)))
+                         for h in range(1, self.n_blocks + 1)}
+            self.head_commit = self.store.load_seen_commit(self.n_blocks)
+
+    def shed(self):
+        """Without what only the builder's process can hold or use: the
+        source store, the private keys (no pickle takes them) and the
+        program's set objects they sign under. `stale_commit` is for before."""
+        return dataclasses.replace(self, store=None, keys=None, set_objs=None)
 
     def commit_data(self, height: int, commit=None, sets=None) -> ref.CommitData:
         """The commit FOR `height` (carried by block height+1 as its
         LastCommit) beside the reference's validator set of that height."""
         if commit is None:
-            commit = (self.store.load_block_commit(height)
-                      or self.store.load_seen_commit(height))
+            commit = self.commit(height)
         vs = (sets or self.sets)[height]
         return ref.CommitData(
             chain_id=self.chain_id,
@@ -69,8 +87,7 @@ class ChurnChain:
         true one only where the two agree position for position."""
         from tendermint_tpu import testing as tt
 
-        block = self.store.load_block(height)
-        honest = self.store.load_block_commit(height)
+        block, honest = self.block(height), self.commit(height)
         stale = self.set_objs[self.set_hash_at[height - 1]]
         return tt.make_commit(
             self.chain_id, height, 0, honest.block_id, stale, self.keys,
@@ -119,7 +136,7 @@ async def churn_chain(
     by_addr = {k.pub_key().address(): k for k in keys}
     app, conns, store, state, ex = await fixtures.fresh_node(genesis)
     genesis_vals = state.validators
-    app_hash_at, txs_at, wire, changes = {}, {}, {}, {}
+    app_hash_at, txs_at, wire, changes, block_hash_at = {}, {}, {}, {}, {}
     set_hash_at, set_objs = {}, {}
     commit = None
     try:
@@ -163,6 +180,7 @@ async def churn_chain(
             )
             store.save_block(block, parts, commit)
             wire[h] = bsm.encode_message(bsm.BlockResponse(block))
+            block_hash_at[h] = bid.hash
     finally:
         await conns.stop()
     sets = refc.derive_sets(
@@ -170,6 +188,6 @@ async def churn_chain(
     return ChurnChain(
         chain_id=chain_id, genesis=genesis, vals=genesis_vals, store=store,
         n_blocks=n_blocks, app_hash_at=app_hash_at, txs_at=txs_at, wire=wire,
-        sets=sets, changes=changes, keys=by_addr, set_hash_at=set_hash_at,
+        block_hash_at=block_hash_at, head_commit=commit, sets=sets, changes=changes, keys=by_addr, set_hash_at=set_hash_at,
         set_objs=set_objs,
     )

@@ -1,21 +1,44 @@
-"""What every driver shares: the clock, the refusal of anything but a TPU,
-the program's own counters read as deltas, the spans the harness puts
-around calls into each layer, and the checks that the DEVICE served a
-window (the program re-verifies on the host after any device error, so a
-right verdict alone says nothing about the chip).
+"""What every driver shares: the clock, where the seeded fixture is built
+(`FixtureChild`, `FixtureHere`), the refusal of anything but a TPU, the
+program's own counters read as deltas, the spans the harness puts around
+calls into each layer, and the checks that the DEVICE served a window (the
+program re-verifies on the host after any device error, so a right verdict
+alone says nothing about the chip).
+
+`setup_s` = the window's opening - `T0` - `fixture_wait_s`. It reads the
+PROGRAM's start: attach, `_probe_tpu` to the end of its thread, the cell's
+own warm-up. A block-sync cell's fixture (two chains: wire bytes and tables)
+is signed by a child process on another core, off this process's heap and
+GIL; `fixture_wait_s` is the seconds this process spent BLOCKED on that
+child with nothing of its own left to run — it asks for the first part
+without blocking while the probe thread still warms its last shape, and
+blocks only once that thread has ended; for the chain, and the child's
+exit, only once the cell's warm-up is over — so every second of it delayed
+the window's opening, and it is taken out and printed beside `setup_s` in
+every result line. Reading the hand-over in is this process's own work and
+stays in `setup_s`. A light cell's fixture is built in this process
+(`FixtureHere`, and why): its seconds are in `setup_s`, as before PR 38, and
+its `fixture_wait_s` is 0.
 
 From the program this takes only the system under test and its counters
 (`crypto.backend_telemetry`, `VerifyHub.stats()`, the TPU breaker) and
-function names to put spans on. Nothing here sets a TMTPU_* knob.
+function names to put spans on. The measured process sets no TMTPU_* knob;
+the fixture child runs the program with its device switched off
+(`TMTPU_DISABLE_TPU`, `JAX_PLATFORMS=cpu`): it signs, it never verifies on
+a chip, and a chip belongs to one process.
 """
 
 from __future__ import annotations
 
 import contextlib
+import fcntl
 import functools
 import importlib.util
 import json
 import os
+import pickle
+import queue
+import subprocess
 import sys
 import threading
 import time
@@ -49,6 +72,161 @@ def load_module(path: str, name: str):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+# -- the fixture: in a child process where it crosses as bytes, else here --------
+#
+# A driver's `build(cfg, cell, seed)` is a generator. It yields the fixture
+# as soon as the cell's warm-up can start, and — where the window's own data
+# takes longer (a 4,096-block chain) — once more: a dict of the fields filled
+# since (`assemble`). A driver whose fixture is BYTES and tables (block-sync:
+# wire blocks, hashes, transactions) says `FIXTURE = "child"` and
+# `FixtureChild` runs that generator in a child process, each yield one plain
+# pickle on its stdout. Every other driver's is built by `FixtureHere`, in the
+# measured process, where it stood before PR 38: a light chain is a graph of
+# the program's own objects that the window walks, and a process that REVIVED
+# that graph from a pickle ran the light client 2-7% slower than one that
+# built it (PERF.md §6, PR 38) — a yardstick may not move what it measures.
+# The two have one interface: `take(block)`, `finish(fx)`, `close()`,
+# `waited_s`.
+
+
+def assemble(parts):
+    """The fixture from everything `parts` (a driver's `build(...)`) yields."""
+    parts = iter(parts)
+    fx = next(parts)
+    for late in parts:
+        vars(fx).update(late)
+    return fx
+
+
+class FixtureHere:
+    """`driver.build` in THIS process, when `take` is called: its seconds are
+    the measured process's own work, inside `setup_s`; nothing is waited for."""
+
+    waited_s = 0.0
+
+    def __init__(self, driver, cfg: dict, cell: dict, seed: int):
+        self._build = lambda: assemble(driver.build(cfg, cell, seed))
+
+    def take(self, block: bool = True):
+        return self._build()
+
+    def finish(self, fx) -> None:
+        pass
+
+    def close(self) -> None:
+        pass
+
+
+#: one part on the pipe: this many bytes of length, then the pickle
+PART_HEADER = 8
+#: the pipe as large as an unprivileged process may make it (Linux: 1 MiB)
+_PIPE_BYTES = 1 << 20
+
+
+def write_part(out, part) -> None:
+    """One part of a fixture onto the child's stdout: pickled whole in the
+    child's memory first, so the pickling waits for no reader."""
+    payload = pickle.dumps(part, protocol=pickle.HIGHEST_PROTOCOL)
+    out.write(len(payload).to_bytes(PART_HEADER, "big"))
+    out.write(payload)
+    out.flush()
+
+
+class FixtureChild:
+    """`driver.build` in a child process started NOW: the same seed, the same
+    builders, the program's device switched off. `take()` hands over the
+    fixture once the child has dumped it (`take(block=False)`: None where it
+    has not yet), `finish(fx)` fills in what it built after that and waits
+    for it to end; `waited_s` is how long this process was blocked in the
+    two, waiting for the child's bytes or its exit (not the unpickling: that
+    is work). `close()` ends a child that is still running.
+
+    A thread that does nothing but blocking reads drains the pipe as the child
+    writes: on the chip's host (gVisor) a pipe hands a 64 KiB buffer over in
+    ≈ 40 ms, so a 66 MB chain read only once it is asked for would cost tens
+    of seconds of blocked time (PR 38's first chip call: 21 MB, 12 s) —
+    drained beside the program's start it costs none."""
+
+    def __init__(self, root: str, workload: str, seed: int):
+        env = dict(os.environ, JAX_PLATFORMS="cpu", TMTPU_DISABLE_TPU="1")
+        # the cell's compile cache is for the chip's programs alone
+        env.pop("JAX_COMPILATION_CACHE_DIR", None)
+        self.waited_s = 0.0
+        self.proc = subprocess.Popen(
+            [sys.executable, os.path.join(HERE, "fixture_child.py"), "--root", root,
+             "--workload", workload, "--seed", str(seed), "--t0", repr(T0)],
+            stdout=subprocess.PIPE, bufsize=0, env=env)
+        try:
+            fcntl.fcntl(self.proc.stdout.fileno(), fcntl.F_SETPIPE_SZ, _PIPE_BYTES)
+        except OSError:
+            pass  # the default size: slower, the same bytes
+        self._parts: queue.SimpleQueue = queue.SimpleQueue()  # pickled parts, then None
+        self._reader = threading.Thread(target=self._drain, name="fixture-child-pipe",
+                                        daemon=True)
+        self._reader.start()
+
+    def _read(self, n: int) -> bytes | None:
+        """Exactly `n` bytes of the pipe; None at its end."""
+        buf = bytearray()
+        while len(buf) < n:
+            chunk = self.proc.stdout.read(min(n - len(buf), _PIPE_BYTES))
+            if not chunk:
+                return None
+            buf += chunk
+        return bytes(buf)
+
+    def _drain(self) -> None:
+        while (head := self._read(PART_HEADER)) is not None:
+            payload = self._read(int.from_bytes(head, "big"))
+            if payload is None:
+                break
+            self._parts.put(payload)
+        self._parts.put(None)
+
+    def _load(self):
+        """The next part, unpickled; None at the child's end."""
+        t0 = time.monotonic()
+        payload = self._parts.get()
+        self.waited_s += time.monotonic() - t0
+        if payload is None:
+            self._parts.put(None)  # the end stays the end for the next call
+            return None
+        # only bytes this program's own child wrote are unpickled
+        return pickle.loads(payload)
+
+    def take(self, block: bool = True):
+        if not block and self._parts.empty():
+            return None
+        fx = self._load()
+        if fx is None:
+            raise RuntimeError(f"the fixture child ended with code {self.proc.wait()} "
+                               "before it handed a fixture over (its lines are above)")
+        return fx
+
+    def finish(self, fx) -> None:
+        while (late := self._load()) is not None:
+            vars(fx).update(late)
+        t0 = time.monotonic()
+        rc = self.proc.wait()
+        self.waited_s += time.monotonic() - t0
+        if rc:
+            raise RuntimeError(f"the fixture child ended with code {rc}")
+
+    def close(self) -> None:
+        if self.proc.poll() is None:
+            self.proc.kill()
+        self.proc.wait()
+        self._reader.join()
+        self.proc.stdout.close()
+
+
+def fixture_builder(driver, root: str, workload: str, cfg: dict, cell: dict, seed: int):
+    """Where this driver's fixture is built (above): started now."""
+    if getattr(driver, "FIXTURE", "here") == "child":
+        return FixtureChild(root, workload, seed)
+    return FixtureHere(driver, cfg, cell, seed)
 
 
 # -- the device -------------------------------------------------------------
@@ -87,13 +265,14 @@ def attach(want_chips: int) -> dict:
 
 
 def wait_available() -> None:
-    """Wait for the program's own verdict on its device: attach, the
-    Pallas A/B probe, the floor warm-up and the measured CPU/TPU cut-off.
-    Nothing else may load the host until then: the A/B and the cut-off are
-    host-clock timings of Python-dispatched calls, and a fixture build
-    beside them flips their winners (PR 24: gemm for pallas, the XLA power
-    chain for the fused one), which changes every kernel the process then
-    traces — and with them the compile-cache keys."""
+    """Wait for the program's own verdict on its device: attach, the Pallas
+    self-test against known answers (no timing decides a formulation since
+    PR 29, no XLA twin runs beside it since PR 36), the floor warm-up and
+    the measured CPU/TPU cut-off — the one host-clock timing left
+    (`_measure_cutoff`; trap 1: `correct` never rests on where it lands).
+    THIS process does nothing else until then; a block-sync cell's fixture
+    child signs on another core meanwhile, and `MIN_TPU_BATCH` is printed
+    for every run so that a cut-off moved by that neighbour shows."""
     from tendermint_tpu.crypto import backend_telemetry as bt
     from tendermint_tpu.crypto import batch as cb
 

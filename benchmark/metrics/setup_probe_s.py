@@ -1,7 +1,7 @@
 """setup_probe_s
 
 `backend.probe`'s `available_s`: seconds from the start of the program's
-device probe (attach, Pallas A/B, floor warm-up, cut-off) until routing
+device probe (attach, the Pallas self-test, floor warm-up, cut-off) until routing
 could use the device.
 """
 

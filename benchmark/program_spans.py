@@ -195,6 +195,17 @@ def ms_per_unit(r, *keys: str):
     return per_unit_ms(window_rows(r.t0, r.t1), r.t0, r.t1, r.units, *keys)
 
 
+def ms_per_unit_less(r, key: str, less: str):
+    """ms inside spans `key` per unit, WITHOUT the time of the spans `less`
+    that ran inside them (none recorded: nothing to take out); None where
+    there is no `key` span."""
+    rows = window_rows(r.t0, r.t1)
+    whole = total_s(rows, r.t0, r.t1, key)
+    if whole is None or not r.units:
+        return None
+    return 1e3 * (whole - (total_s(rows, r.t0, r.t1, less) or 0.0)) / r.units
+
+
 def ms_per_ksig(r, attr: str, *keys: str):
     return per_ksig_ms(window_rows(r.t0, r.t1), r.t0, r.t1, attr, *keys)
 

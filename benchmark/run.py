@@ -10,12 +10,18 @@ window and comparison for one entry point) and `benchmark/metrics/<metric>.py`
 (one per-layer reader each). Adding a cell, a configuration or a per-layer
 metric adds files and entries, and edits none.
 
-Set-up (attach, the program's own device probe to the END of its thread,
-the seeded fixture beside the probe's last compile, the cell's own warm-up) is timed as `setup_s`; then the
-window runs for --seconds; then the device's peak memory is read, the plain
-reference checks what the window produced, and ONE JSON object is printed
-as the last line of stdout. Progress and every number compared go to
-stderr. No TPU, too few chips, or any exception: traceback, non-zero exit,
+A block-sync cell's seeded fixture is built by a child process started first
+of all (`harness.FixtureChild`: the program with its device off, on another
+core); a light cell's in this process, beside the probe thread's last
+warm-up (`harness.FixtureHere`). Set-up (attach, the program's own device
+probe to the END of its thread, the cell's own warm-up) is timed as
+`setup_s` = the window's opening - the process's start - `fixture_wait_s`,
+the seconds this process was blocked on that child with nothing of its own
+left to run. Then the window runs for --seconds; then the device's peak
+memory is read, the plain reference checks what the window produced, and
+ONE JSON object is printed as the last line of stdout, `fixture_wait_s` in
+it (and, from a block-sync driver, `chain_left_blocks`). Progress and every
+number compared go to stderr. No TPU, too few chips, or any exception: traceback, non-zero exit,
 no result line.
 """
 
@@ -85,30 +91,38 @@ def execute(root: str, workload: str, seed: int, seconds: float, traced: bool,
     driver = importlib.import_module(f"benchmark.drivers.{cell['driver']}")
     say(f"cell {workload} (config {cell['config']}, driver {cell['driver']}), "
         f"seed {seed}, {seconds:g}s, trace {int(traced)}")
-
-    compiles = harness.CompileCounters()
-    if device is None:
-        device = harness.attach(cell["chips"])
-    from tendermint_tpu.crypto.tpu import verify as tpuv
-
-    cache = os.environ.get("JAX_COMPILATION_CACHE_DIR") or tpuv.COMPILE_CACHE_DIR
-    say(f"compile cache: {cache} (cap {os.environ.get('JAX_COMPILATION_CACHE_MAX_SIZE', 'none')})")
-
-    # the fixture is host work: built while the probe thread warms its 8192
-    # shape (a plain compile), and only once the program's own timed
-    # measurements are over
-    harness.wait_available()
-    fx = driver.build(cfg, cell, seed)
-    harness.wait_probe_end()
-    say(f"field_mul_probe {json.dumps(tpuv.field_mul_probe)}; compiles {compiles.snapshot()}")
-
+    builder = harness.fixture_builder(driver, root, workload, cfg, cell, seed)
     spans = harness.Spans(annotate=traced)
     patches = harness.Patches()
+    fx = None
     try:
+        compiles = harness.CompileCounters()
+        if device is None:
+            device = harness.attach(cell["chips"])
+        from tendermint_tpu.crypto.tpu import verify as tpuv
+
+        cache = os.environ.get("JAX_COMPILATION_CACHE_DIR") or tpuv.COMPILE_CACHE_DIR
+        say(f"compile cache: {cache} "
+            f"(cap {os.environ.get('JAX_COMPILATION_CACHE_MAX_SIZE', 'none')})")
+
+        # the fixture is host work, beside the probe thread's 8192 warm-up (a
+        # plain compile) once the program's own timed measurement is over:
+        # built here, or what a child has handed over by now read in — never
+        # waited for while that thread still runs (the wait would hide under
+        # it and be taken out of setup_s all the same)
+        harness.wait_available()
+        fx = builder.take(block=False)
+        harness.wait_probe_end()
+        if fx is None:
+            fx = builder.take()
+        say(f"field_mul_probe {json.dumps(tpuv.field_mul_probe)}; "
+            f"compiles {compiles.snapshot()}")
+
         driver.install(patches, spans, traced)
         warmed = driver.warmup(fx, cfg, cell, spans)
         say(f"warmed shapes: {warmed}; compiles {compiles.snapshot()}")
 
+        builder.finish(fx)
         trace = harness.DeviceTrace(workload) if traced else None
         resolve_total = spans.resolve_total
         before, compiles_before, resolve_before = (
@@ -119,13 +133,17 @@ def execute(root: str, workload: str, seed: int, seconds: float, traced: bool,
             close.update(counters=harness.counters(), compiles=compiles.snapshot(),
                          resolve=resolve_total[0])
 
-        setup_s = time.monotonic() - harness.T0
-        say(f"set-up took {setup_s:.1f}s; window opens")
+        fixture_wait_s = builder.waited_s
+        setup_s = time.monotonic() - harness.T0 - fixture_wait_s
+        say(f"set-up took {setup_s:.1f}s beside {fixture_wait_s:.3f}s blocked on the "
+            f"fixture child (fixture_wait_s, not in setup_s); window opens")
         w = driver.window(fx, cfg, cell, seconds, patches, trace, spans, on_close)
         final = harness.counters()
     finally:
         patches.undo()
-        driver.release(fx)
+        if fx is not None:
+            driver.release(fx)
+        builder.close()
     # the window's own readings stop at its close; the comparison reads the
     # counters once everything in flight has landed (and, in a traced run,
     # the stretch that followed)
@@ -195,6 +213,12 @@ def execute(root: str, workload: str, seed: int, seconds: float, traced: bool,
     if reduced is not None:
         result["breakdown"] = {"device_ops": reduced["device_ops"],
                                "idle_gaps": reduced["idle_gaps"]}
+    # the harness's own numbers beside the contract's keys: the seconds taken
+    # out of setup_s, and whatever the driver says of its window's room
+    beside = {"fixture_wait_s": fixture_wait_s, **getattr(w, "report", {})}
+    result.update(beside)
+    for name, value in beside.items():
+        say(f"{name} = {value}")
     result["checks"] = {c.name: c.as_json() for c in checks}
     for name, m in metrics.items():
         say(f"metric {name} = {m['value']} {m['unit']}")

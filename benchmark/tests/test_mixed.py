@@ -162,6 +162,37 @@ def test_control_lane_answers_true_is_not_correct(tmp_path):
     assert res["checks"]["verdict_mismatches"]["ok"]  # honest traffic reads the same
 
 
+@pytest.mark.parametrize("renamed,failing", [
+    # a device kernel's route carries the scheme in its name: counted, sound
+    ("tpu-secp256k1", set()),
+    ("device-ecdsa", set()),
+    # an Edwards route: the rows are not counted as ECDSA, and `tpu` holds too many
+    ("tpu", {"ecdsa_sigs_on_host_minus_needed", "edwards_sigs_on_device_minus_range_needed"}),
+    # counted on two routes: each ECDSA sum is right, the total is not
+    ("host-ecdsa+tpu-secp256k1",
+     {"ecdsa_sigs_on_host_minus_needed", "sigs_verified_minus_needed"}),
+])
+def test_the_route_check_takes_any_counted_ecdsa_route(tmp_path, monkeypatch, renamed, failing):
+    """The lane verifies as it does; its rows are COUNTED on another route, as
+    a secp256k1 device kernel's would be (the host route's Edwards count reads
+    false by design here, so `tpu` adds nothing new to that one)."""
+    from tendermint_tpu.crypto import backend_telemetry as bt
+
+    real = bt.record_route
+
+    def record(route, n):
+        for name in (renamed.split("+") if route == "host-ecdsa" else [route]):
+            real(name, n)
+
+    monkeypatch.setattr(bt, "record_route", record)
+    res = run.execute(tiny_mixed.make_root(str(tmp_path)), tiny_mixed.CELL, 3000003215, 0.3,
+                      False, device=tiny_mixed.CPU_DEVICE)
+    always = HOST_ROUTE_CHECKS  # no device here: the Edwards rows are on route `cpu`
+    assert _failed(res) - always == failing - always
+    assert res["checks"]["ecdsa_sigs_on_host_minus_needed"]["ok"] is (
+        "ecdsa_sigs_on_host_minus_needed" not in failing)
+
+
 # -- the readers -----------------------------------------------------------------------
 
 

@@ -260,12 +260,12 @@ CPU_READS = {
         "hub_submit_ms_per_ksig.blocksync", "hub_queue_wait_ms.blocksync"},
     "tinylight.sequential": {
         "collect_ms_per_ksig.light", "fetch_ms_per_header.light", "link_ms_per_header.light",
-        "store_ms_per_header.light"},
+        "store_ms_per_header.light", "verify_ms_per_header.light"},
 }
 
 
 def test_every_new_entry_lists_its_cells():
-    assert len(NEW) == 26
+    assert len(NEW) == 31  # PR 25's twenty-six; PR 38: verify_ (three re-pointed), fill_ (two)
     for m in NEW:
         assert m["workloads"] and set(m) == {
             "name", "unit", "better", "source", "layer", "moves", "workloads"}
@@ -275,7 +275,7 @@ def test_every_new_entry_lists_its_cells():
 def test_tiny_cells_drive_every_new_reader(tmp_path, cell):
     root = tiny.make_root(str(tmp_path))
     ps._rows_cache.clear()
-    res = run.execute(root, cell, 3000002511, 0.8, True, device=tiny.CPU_DEVICE)
+    res = run.execute(root, cell, 3000002511, tiny.SECONDS, True, device=tiny.CPU_DEVICE)
     suffix = ".blocksync" if "blocksync" in cell else ".light"
     mine = {m["name"] for m in NEW if m["name"].endswith(suffix)}
     got = {n for n in res["metrics"] if n in mine}

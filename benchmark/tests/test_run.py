@@ -31,9 +31,19 @@ def _failed(result):
     ("tinyfull.blocksync", "blocksync_blocks_per_s"),
 ])
 def test_sound_run(root, cell, metric):
-    res = run.execute(root, cell, 3000000019, 0.6, False, device=tiny.CPU_DEVICE)
+    res = run.execute(root, cell, 3000000019, tiny.SECONDS, False, device=tiny.CPU_DEVICE)
     assert list(res)[-1] == "checks" and list(res)[:5] == [
         "correct", "attempted", "failed", "metrics", "device"]
+    # the seconds blocked on the fixture child: a number of its own in every line
+    assert res["fixture_wait_s"] >= 0
+    if cell == "tinyfull.blocksync":
+        # the chain outlasts its window with the real cells' room: twice what
+        # the window consumed is still left
+        used = res["checks"]["blocks_applied"]["value"]
+        assert res["chain_left_blocks"] >= 2 * used > 0
+        assert res["chain_left_blocks"] + used <= tiny.BLOCKS
+    else:
+        assert "chain_left_blocks" not in res
     assert _failed(res) == DEVICE_CHECKS and res["correct"] is False
     assert res["metrics"][metric]["value"] > 0 and res["metrics"]["setup_s"]["value"] > 0
     assert res["attempted"] > 0 and res["failed"] == 0
@@ -43,7 +53,8 @@ def test_sound_run(root, cell, metric):
 
 @pytest.mark.parametrize("cell", ["tinylight.sequential", "tinyfull.blocksync"])
 def test_traced_run_reports_layers_and_leaves_out_what_it_cannot_read(root, cell):
-    res = run.execute(root, cell, 3000000023, 0.6, True, device=tiny.CPU_DEVICE)
+    res = run.execute(root, cell, 3000000023, tiny.SECONDS, True, device=tiny.CPU_DEVICE)
+    assert res["fixture_wait_s"] >= 0 and ("chain_left_blocks" in res) == ("blocksync" in cell)
     names = set(res["metrics"])
     suffix = cell.split(".")[1].replace("sequential", "light")
     assert f"inline_compiles.{suffix}" in names and f"device_route_share.{suffix}" in names
@@ -53,6 +64,21 @@ def test_traced_run_reports_layers_and_leaves_out_what_it_cannot_read(root, cell
     assert "breakdown" in res and {"busy_s", "window_s"} <= set(res["device"])
     if cell == "tinylight.sequential":  # the metric only the throw-away cell has
         assert 0 < res["metrics"]["verify_share.tiny"]["value"] <= 100
+
+
+def test_traced_run_whose_chain_ends_says_so(tmp_path):
+    """A chain cut short on purpose: the traced run raises with the cause and
+    the workload file's `blocks`, not "the profiler wrote no trace"; the same
+    chain untraced closes its window early and reads the one block left that
+    no successor's commit vouches for."""
+    from benchmark.drivers import blocksync
+
+    short = tiny.make_root(str(tmp_path), blocks=70)
+    with pytest.raises(blocksync.ChainEnded, match=r"70 blocks \(`traffic.blocks`"):
+        run.execute(short, "tinyfull.blocksync", 3000000043, 30.0, True, device=tiny.CPU_DEVICE)
+    res = run.execute(short, "tinyfull.blocksync", 3000000043, 30.0, False,
+                      device=tiny.CPU_DEVICE)
+    assert res["chain_left_blocks"] == 1 and res["checks"]["blocks_applied"]["value"] == 69
 
 
 def test_faked_host_reverify_turns_correct_false(root, monkeypatch):
@@ -99,7 +125,7 @@ def test_blocksync_step_that_leaves_the_state_unchanged_is_caught(root, monkeypa
     from tendermint_tpu.abci import kvstore
 
     monkeypatch.setattr(kvstore, "_state_hash", lambda items: b"\x00" * 32)
-    res = run.execute(root, "tinyfull.blocksync", 3000000037, 0.6, False,
+    res = run.execute(root, "tinyfull.blocksync", 3000000037, tiny.SECONDS, False,
                       device=tiny.CPU_DEVICE)
     assert res["correct"] is False
     assert _failed(res) - DEVICE_CHECKS  # something other than the route
@@ -110,7 +136,7 @@ def test_blocksync_verify_that_checks_nothing_is_caught(root, monkeypatch):
 
     monkeypatch.setattr(reactor, "verify_commit_range", _accept_everything)
     monkeypatch.setattr(reactor, "verify_commit_light", _accept_everything)
-    res = run.execute(root, "tinyfull.blocksync", 3000000041, 0.6, False,
+    res = run.execute(root, "tinyfull.blocksync", 3000000041, tiny.SECONDS, False,
                       device=tiny.CPU_DEVICE)
     assert {"sigs_asked_minus_needed", "warmup_refusal_faults"} <= _failed(res)
 

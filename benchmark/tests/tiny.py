@@ -1,7 +1,14 @@
 """A throw-away copy of the benchmark's data files at a tiny size (10
-validators, a few dozen blocks) in a temporary directory: the way a later
+validators, a few hundred blocks) in a temporary directory: the way a later
 PR adds a cell, a configuration and a per-layer metric — new files and new
-BENCHMARK.json entries, no edit to a file that is there."""
+BENCHMARK.json entries, no edit to a file that is there.
+
+The block-sync chain keeps the real cells' room rule at its own scale: the
+sandbox's host route applies some hundreds of these blocks a second, the
+tests' windows are 0.6 s (a 64-block range is ~0.35 s) with a 0.1 s traced
+stretch, and `BLOCKS` holds several times what that consumes — a tiny run's
+chain must not end inside its window any more than a real one's
+(`chain_left_blocks`)."""
 
 from __future__ import annotations
 
@@ -14,9 +21,12 @@ BENCH = os.path.dirname(HERE)
 ROOT = os.path.dirname(BENCH)
 
 CPU_DEVICE = {"platform": "cpu", "kind": "cpu", "count": 1}
+#: the tiny block-sync chain, and the window its tests run it for
+BLOCKS = 600
+SECONDS = 0.6
 
 
-def make_root(tmp: str) -> str:
+def make_root(tmp: str, blocks: int = BLOCKS) -> str:
     """tmp/BENCHMARK.json + tmp/benchmark/{configs,workloads,metrics} with
     two tiny cells `tinylight.sequential` and `tinyfull.blocksync`, plus one
     new per-layer metric that only the throw-away cell reports."""
@@ -47,7 +57,7 @@ def make_root(tmp: str) -> str:
     dump("workloads/tinylight.sequential.json", light)
     sync = load("workloads/full150.blocksync.json")
     sync.update(name="tinyfull.blocksync", config="tinyfull")
-    sync["traffic"].update(blocks=150, warmup_blocks=40, trace_seconds=0.2)
+    sync["traffic"].update(blocks=blocks, warmup_blocks=40, trace_seconds=0.1)
     dump("workloads/tinyfull.blocksync.json", sync)
 
     with open(os.path.join(base, "metrics", "verify_share.tiny.py"), "w") as f:
@@ -75,7 +85,7 @@ def make_root(tmp: str) -> str:
     ]
     for m in bench["end_to_end"] + bench["per_layer"]:
         if "workloads" in m:
-            m["workloads"] = [cells[w] for w in m["workloads"]]
+            m["workloads"] = [cells[w] for w in m["workloads"] if w in cells]
     bench["per_layer"].append(
         {"name": "verify_share.tiny", "unit": "%", "better": "lower",
          "source": "program_span", "layer": "entry", "moves": "light_headers_per_s",
