@@ -201,8 +201,14 @@ class VerifyHubConfig:
     TMTPU_MESH_PROBE_TIMEOUT (crypto/tpu/mesh.py)."""
 
     enabled: bool = True
-    max_batch: int = 512  # per-chip dispatch target (sigs queued)
-    window_ms: float = 2.0  # micro-batch window ceiling (adaptive below it)
+    # max_batch and window_ms govern LONE requests (a vote, a proposal,
+    # an evidence vote): how many of them make a dispatch, per chip, and
+    # the ceiling of how long one lingers for company (adaptive below
+    # it). A group (verify_many: a commit's signatures, a block-sync
+    # range's) is one unit: flushed at once, never split at max_batch,
+    # and past max_batch rows dispatched at the verifier's chunk shape
+    max_batch: int = 512
+    window_ms: float = 2.0
     cache_size: int = 8192  # verified-(pubkey,msg,sig) LRU entries
     # scale batch capacity + adaptive window by the ACTIVE device-mesh
     # size, so an 8-chip mesh is fed 8× batches (and a degraded mesh

@@ -191,12 +191,12 @@ def _probe_tpu_traced(probe_span) -> None:
             # Its failure does NOT revoke availability — the floor shapes
             # are warm and perfectly usable — but it is counted
             # (backend_telemetry probe_errors), not just logged.
-            from .tpu.verify import _MAX_BUCKET
+            from .tpu.verify import _CHUNK_GROUPS, _MAX_BUCKET
 
             try:
                 t0 = _time.monotonic()
                 with trace.span("backend", "warmup", shape="max"):
-                    warmup(bucket=_MAX_BUCKET, groups=150, fallback=True)
+                    warmup(bucket=_MAX_BUCKET, groups=_CHUNK_GROUPS, fallback=True)
                 bt.record_compile("max", _time.monotonic() - t0)
             except Exception as e:  # noqa: BLE001
                 bt.record_probe_error("warmup-max", repr(e))
@@ -401,6 +401,13 @@ class AdaptiveBatchVerifier(BatchVerifier):
         #: sharded over the mesh (per-device real-signature counts);
         #: None on single-device and host routes
         self.last_dispatch = None
+        #: the device verifier runs this batch at its full chunk shape —
+        #: the program start-up warms last (`_probe_tpu`) — whatever its
+        #: row count, instead of the ladder rung: set by the VerifyHub on
+        #: a dispatch that carries a whole group, whose size is whatever
+        #: a catch-up happened to download (one program for every such
+        #: size, none compiled in the middle of a sync)
+        self.whole_chunk = False
 
     def add(self, pub_key: PubKey, msg: bytes, sig: bytes) -> None:
         self._schemes.add(pub_key.TYPE)
@@ -553,6 +560,8 @@ class AdaptiveBatchVerifier(BatchVerifier):
         inside its dispatch loop (`tpu.resolve` spans are recorded
         there)."""
         target = self._make_tpu_verifier()
+        if self.whole_chunk:
+            target.whole_chunk = True
         add_many = getattr(target, "add_many", None)
         if add_many is None:
             return self._run(target, items)

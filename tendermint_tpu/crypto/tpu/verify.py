@@ -804,10 +804,14 @@ def _group_bucket(g: int) -> int:
     return b - 1
 
 
-def prepare_batch_eq(entries: list[ResolvedSig | None], pad_to: int = 0):
+def prepare_batch_eq(
+    entries: list[ResolvedSig | None], pad_to: int = 0, groups_to: int = 0
+):
     """Host prep for the batch-equation kernel. pad_to ≥ len(entries)
     pads the signature axis with inert rows (digits 0, s_valid False);
-    the unique-key axis is padded to a group bucket. Returns (ua_bytes,
+    the unique-key axis is padded to a group bucket, the keys' own or
+    `groups_to` if that is wider (inert key rows: no signature row
+    points at them). Returns (ua_bytes,
     r_bytes, ga_digits, r_digits, zs_digits, s_valid, gidx) numpy arrays
     shaped for `_kernel_eq`.
 
@@ -857,7 +861,7 @@ def prepare_batch_eq(entries: list[ResolvedSig | None], pad_to: int = 0):
         s_valid[bad] = False
         z_np[bad] = 0
     g = len(coeffs)
-    gb = _group_bucket(g)
+    gb = max(_group_bucket(g), groups_to)
     ga_sc = _rows_u8([(c % L).to_bytes(32, "little") for c in coeffs], g, gb, 32)
     zs_digits = np.frombuffer((zs % L).to_bytes(32, "little"), np.uint8).reshape(32, 1)
     return (
@@ -912,6 +916,16 @@ def _get_sharded(devices: list):
 #: bitmaps are only synced after every chunk is in flight.
 _MAX_BUCKET = int(os.environ.get("TMTPU_MAX_BUCKET", "8192"))
 
+#: Distinct keys the full-chunk program is compiled for at start-up
+#: (`crypto/batch._probe_tpu`'s last warm-up: this many keys at
+#: `_MAX_BUCKET` rows, both kernels): a 150-validator set. A batch sent at
+#: the whole chunk (`TPUBatchVerifier.whole_chunk`) is padded to ITS group
+#: bucket too where its keys fit, so it runs the one program every start
+#: has warm — its own (gb127 for the 101 keys a 150-validator range stops
+#: at) would be a second 8,192-row program, compiled in the middle of a
+#: catch-up for ≈ 3% of a kernel
+_CHUNK_GROUPS = 150
+
 
 class _Selection:
     """One dispatch plan: the kernels, the padded bucket shape, the pad
@@ -941,8 +955,11 @@ def _plan_shape(n: int, pad_multiple: int, n_dev: int) -> tuple[bool, int, int]:
     active devices — a function of the PADDED shape alone: every raw
     count that pads to one rung of the ladder gets one plan, and that
     rung itself (what `warmup` is given) gets the same one. So a shape
-    start-up warmed is a shape dispatch selects, and the reverse."""
-    rung = _bucket(n)
+    start-up warmed is a shape dispatch selects, and the reverse. The
+    rung is the ladder's rounded up to `pad_multiple`: a batch the hub
+    sends at the whole chunk (`TPUBatchVerifier.whole_chunk`) is planned
+    as the chunk-sized batch it runs as."""
+    rung = _bucket(n, pad_multiple)
     sharded = n_dev > 1 and (
         os.environ.get("TMTPU_FORCE_SHARDED") == "1" or rung >= _SHARD_MIN_ROWS
     )
@@ -1123,6 +1140,8 @@ def _dispatch_and_collect(n: int, get_entries, pad_multiple: int) -> np.ndarray:
         f"(multiple={sel.multiple}); pad-to-bucket or CPU fallback required"
     )
     _dispatch_local.info = None
+    # a plan padded to whole chunks is the start-up's program, keys and all
+    groups_to = _group_bucket(_CHUNK_GROUPS) if sel.multiple % _MAX_BUCKET == 0 else 0
     in_flight = []
     for i in range(0, n, _MAX_BUCKET):
         chunk = get_entries(i, min(i + _MAX_BUCKET, n))
@@ -1133,7 +1152,7 @@ def _dispatch_and_collect(n: int, get_entries, pad_multiple: int) -> np.ndarray:
             with trace.span(
                 "tpu", "prep", n=len(chunk), bucket=sel.bucket, devices=n_dev
             ) as sp:
-                args = prepare_batch_eq(chunk, pad_to=sel.bucket)
+                args = prepare_batch_eq(chunk, pad_to=sel.bucket, groups_to=groups_to)
                 sp.set(groups=int(args[0].shape[0]))
             # tmtlint: allow[span-per-item] -- per chunk
             with trace.span("tpu", "dispatch", bucket=sel.bucket, devices=n_dev):
@@ -1275,6 +1294,10 @@ class TPUBatchVerifier(BatchVerifier):
 
     def __init__(self):
         self._items: list[tuple[PubKey, bytes, bytes]] = []
+        #: run at the full chunk shape — `_MAX_BUCKET` rows, the group
+        #: bucket of `_CHUNK_GROUPS` keys — whatever the row count (see
+        #: `AdaptiveBatchVerifier.whole_chunk`)
+        self.whole_chunk = False
 
     def add(self, pub_key: PubKey, msg: bytes, sig: bytes) -> None:
         self._items.append((pub_key, msg, sig))
@@ -1285,7 +1308,9 @@ class TPUBatchVerifier(BatchVerifier):
     def verify(self) -> tuple[bool, list[bool]]:
         items = self._items
         host_rows: list[int] = []
-        results = verify_batch_eq(items, host_rows=host_rows).tolist()
+        results = verify_batch_eq(
+            items, _MAX_BUCKET if self.whole_chunk else 1, host_rows
+        ).tolist()
         for i in host_rows:
             pk, msg, sig = items[i]
             results[i] = pk.verify_signature(msg, sig)

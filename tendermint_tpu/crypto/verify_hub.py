@@ -23,13 +23,31 @@ Scheduling model (one dispatcher thread + one device-runner thread):
     (gossip hands every vote to a node several times — the duplicate
     attaches its future to the pending verify instead of re-entering
     the queue); a live submission coalescing onto a queued backfill
-    entry PROMOTES it into the live lane;
+    entry PROMOTES it into the live lane (a queued backfill group goes
+    over whole);
   * a bounded LRU of already-verified ``(key_type, pubkey, sha256(msg),
     sig)`` verdicts answers repeats without any dispatch at all;
   * dispatch fires when a device-sized batch fills, when the adaptive
     micro-batch window expires, or immediately for *urgent* requests
     (the sync facade — a caller blocking the event loop must not pay a
-    coalescing tax it can never recoup);
+    coalescing tax it can never recoup). `max_batch` and `window_ms`
+    govern these LONE requests: how many of them make a dispatch, how
+    long one lingers for company;
+  * a GROUP (`verify_many`: every signature of a commit, a block-sync
+    range's thousands) is ONE unit from submit to settle: one
+    acquisition of the lock, one pass over its rows for the verdict LRU
+    and for coalescing (both still per row), one queue entry holding
+    its cold rows, one wake-up of the dispatcher, one thing to wait on.
+    It is flushed at once and never split: a dispatch takes the lone
+    requests first (live lane, then backfill, up to `max_batch`), then
+    the first queued group WHOLE whatever its size, and further groups
+    only while the dispatch stays within `max_batch` rows. A dispatch
+    of more rows than that goes to the device at the verifier's chunk
+    shape (its 8,192-row program — the one every start warms last —
+    which also cuts a larger group into chunks) and not at the ladder
+    rung of its row count: a range is as many blocks as were
+    downloaded, and a shape a size would be a compile a size in the
+    middle of a catch-up;
   * the window ADAPTS to measured occupancy: an EWMA of signatures per
     dispatch shrinks the window toward zero under light load and
     stretches it back to the configured ceiling as concurrency appears;
@@ -78,7 +96,7 @@ import logging
 import os
 import threading
 import time
-from collections import OrderedDict
+from collections import OrderedDict, deque
 from concurrent.futures import Future, ThreadPoolExecutor
 
 from ..libs import trace
@@ -120,7 +138,9 @@ class _Pending:
         self.pub_key = pub_key
         self.msg = msg
         self.sig = sig
-        self.futures: list[Future] = [fut]
+        # whoever waits for this verdict: a Future (a lone request) or a
+        # (_Group, row index) pair (a group's row that coalesced onto it)
+        self.futures: list = [fut]
         self.enqueued_at = now
         self.lane = lane
         # (ctx, joined_at): a coalesced duplicate's queue wait starts
@@ -149,6 +169,75 @@ class _Pending:
             self.traces = [entry]
         else:
             self.traces.append(entry)
+
+
+class _Row:
+    """One cold row of a group: what `_verify_batch` reads of a
+    `_Pending` (key, triple, lane) and the row's place in the caller's
+    list. Its lane is its group's: a promoted group takes its rows along."""
+
+    __slots__ = ("key", "pub_key", "msg", "sig", "group", "index")
+
+    def __init__(self, key, pub_key, msg, sig, group, index):
+        self.key = key
+        self.pub_key = pub_key
+        self.msg = msg
+        self.sig = sig
+        self.group = group
+        self.index = index
+
+    @property
+    def lane(self) -> str:
+        return self.group.lane
+
+
+class _Group:
+    """One `verify_many` call: the queue entry that holds its cold rows
+    and the one thing its caller waits on. `results` is the caller's
+    list, filled in place — cache hits at submit, the rest as their
+    dispatches (its own, or the one a coalesced row rides) settle;
+    `remaining` counts the rows still out and is only touched under the
+    hub's lock."""
+
+    __slots__ = (
+        "results", "remaining", "rows", "lane", "enqueued_at", "traces", "joiners",
+        "queued", "error", "_done",
+    )
+
+    def __init__(self, n: int, lane: str, now: float):
+        self.results: list = [None] * n
+        self.remaining = 0
+        self.rows: list[_Row] = []
+        self.lane = lane
+        self.enqueued_at = now
+        # [ctx, joined_at, rows]: the caller's trace with the group's own
+        # rows, and one entry a row another trace's group coalesced here
+        self.traces: list = []
+        # row -> whoever else waits for it (as `_Pending.futures`)
+        self.joiners: dict | None = None
+        self.queued = False
+        self.error: Exception | None = None
+        self._done = threading.Event()
+
+    def join(self, row: _Row, waiter) -> None:
+        if self.joiners is None:
+            self.joiners = {}
+        self.joiners.setdefault(row, []).append(waiter)
+
+    def wake(self) -> None:
+        self._done.set()
+
+    def fail(self, e: Exception) -> None:
+        if self.error is None:
+            self.error = e
+        self._done.set()
+
+    def wait(self, timeout: float | None) -> list[bool]:
+        if not self._done.wait(timeout):
+            raise TimeoutError(f"{self.remaining} of {len(self.results)} verdicts outstanding")
+        if self.error is not None:
+            raise self.error
+        return self.results
 
 
 def _cache_key(pub_key: PubKey, msg: bytes, sig: bytes) -> tuple:
@@ -232,6 +321,11 @@ class VerifyHub:
             lane: OrderedDict() for lane in LANES
         }
         self._inflight: dict[tuple, _Pending] = {}
+        # groups (verify_many) queue beside the lone requests of their
+        # lane, each ONE entry; every cold row of a queued or in-flight
+        # group is findable by its key, for coalescing
+        self._groups: dict[str, deque[_Group]] = {lane: deque() for lane in LANES}
+        self._group_rows: dict[tuple, _Row] = {}
         self._cache: OrderedDict[tuple, bool] = OrderedDict()
         self._urgent = False
         self._running = False
@@ -273,6 +367,10 @@ class VerifyHub:
             # multi-tenant packing (the verifyd daemon's hub): dispatches
             # whose batch mixed signatures from >1 client connection
             "cross_tenant_dispatches": 0.0,
+            # how often the group path engages: groups that entered the
+            # queue as one unit, and the cold rows they held
+            "bulk_groups": 0.0,
+            "bulk_group_sigs": 0.0,
             # where a request's latency goes before its batch runs:
             # summed submit-to-pack wait of every packed request (the
             # queue-latency histogram's sum, as a counter a reader can
@@ -329,7 +427,6 @@ class VerifyHub:
         lane: str = LANE_LIVE,
         trace_ctx=None,
         tenant=None,
-        bulk: bool = False,
     ) -> Future:
         """Enqueue one verification; returns a concurrent Future[bool].
 
@@ -339,10 +436,7 @@ class VerifyHub:
         `lane` picks the scheduler lane: live consensus is packed ahead
         of backfill in every dispatch. `trace_ctx` (libs/trace.TraceCtx)
         joins the request to an end-to-end trace: the hub records
-        hub.queue and hub.execute spans on it, one per (dispatch, trace).
-        `bulk` marks one of a group submitted in a loop (`verify_many`):
-        its cache hit is counted by the caller, once for the group,
-        instead of leaving a hub.cache_hit row per signature."""
+        hub.queue and hub.execute spans on it, one per (dispatch, trace)."""
         if lane not in self._queues:
             # a typo'd lane at a new call site must fail loudly — a
             # silent fall-through to "live" would hand bulk catch-up
@@ -357,7 +451,7 @@ class VerifyHub:
             if verdict is not None:
                 self._cache.move_to_end(key)
                 self._stats["cache_hits"] += 1
-                if trace_ctx is not None and not bulk:
+                if trace_ctx is not None:
                     # zero-width marker anchored on the TRACE clock: the
                     # trace may time on an injected chaos clock, and a
                     # SYSTEM timestamp would land at a wrong offset in
@@ -366,28 +460,27 @@ class VerifyHub:
                     trace.record(trace_ctx, "hub", "cache_hit", now, now, lane=lane)
                 fut.set_result(verdict)
                 return fut
-            pending = (
-                self._queues[LANE_LIVE].get(key)
-                or self._queues[LANE_BACKFILL].get(key)
-                or self._inflight.get(key)
-            )
+            pending = self._lone(key)
             if pending is not None:
                 pending.futures.append(fut)
                 pending.add_trace(trace_ctx)
                 pending.add_tenant(tenant)
                 self._stats["coalesced"] += 1
-                if (
-                    lane == LANE_LIVE
-                    and pending.lane == LANE_BACKFILL
-                    and pending.key in self._queues[LANE_BACKFILL]
-                ):
-                    # a live caller now waits on this triple: pull the
-                    # still-queued backfill entry into the live lane so
-                    # it stops queueing behind bulk catch-up traffic
-                    del self._queues[LANE_BACKFILL][pending.key]
-                    pending.lane = LANE_LIVE
-                    self._queues[LANE_LIVE][pending.key] = pending
-                    self._stats["lane_promotions"] += 1
+                if lane == LANE_LIVE:
+                    self._promote(pending)
+                if urgent:
+                    self._urgent = True
+                    self._cv.notify_all()
+                return fut
+            row = self._group_rows.get(key)
+            if row is not None:
+                # the triple is a row of a queued or in-flight group
+                row.group.join(row, fut)
+                if trace_ctx is not None:
+                    row.group.traces.append([trace_ctx, time.monotonic(), 1])
+                self._stats["coalesced"] += 1
+                if lane == LANE_LIVE:
+                    self._promote_group(row.group)
                 if urgent:
                     self._urgent = True
                     self._cv.notify_all()
@@ -457,23 +550,122 @@ class VerifyHub:
         *,
         lane: str = LANE_LIVE,
     ) -> list[bool]:
-        """Submit a group (e.g. every signature of a commit) and wait for
-        all verdicts. The group is flushed as one urgent dispatch — plus
-        whatever else is queued, so concurrent commit verifications from
-        different subsystems share kernel launches."""
+        """Submit a group (every signature of a commit, of a block-sync
+        range) and wait for all verdicts, in the caller's order. The
+        group is ONE unit (module docstring): its cold rows are one queue
+        entry, flushed at once and dispatched whole."""
         # the caller's open span (the commit funnel's validation.verify):
         # the whole group joins its trace ONCE — the hub records queue /
         # execute per (dispatch, trace), never per signature
         ctx = trace.current()
         with trace.span("hub", "submit", n=len(items)) as sp:
-            futs = [
-                self.submit_nowait(pk, msg, sig, lane=lane, trace_ctx=ctx, bulk=True)
-                for pk, msg, sig in items
-            ]
-            self.flush()
-            sp.set(answered=sum(1 for f in futs if f.done()))
+            group, answered = self._submit_group(items, lane, ctx)
+            sp.set(answered=answered)
         with trace.span("hub", "wait", n=len(items)):
-            return [f.result(timeout) for f in futs]
+            return group.wait(timeout)
+
+    def _submit_group(self, items, lane: str, trace_ctx) -> tuple[_Group, int]:
+        """Enter the hub ONCE for a whole group: (the group, rows already
+        answered). Per row what `submit_nowait` does — the verdict LRU,
+        then coalescing onto an identical triple queued or in flight, as
+        a lone request or as a row of a group (this one's own duplicates
+        too) — but under one acquisition of the lock, with no Future and
+        no wake-up a row: the cold rows become one queue entry."""
+        if lane not in self._queues:
+            raise ValueError(f"unknown verify lane {lane!r}; use one of {LANES}")
+        # a SHA-256 a row, outside the lock
+        keys = [_cache_key(pk, msg, sig) for pk, msg, sig in items]
+        group = _Group(len(items), lane, time.monotonic())
+        results, rows = group.results, group.rows
+        inline: list[int] = []
+        with self._cv:
+            cache, lone, group_rows = self._cache, self._lone, self._group_rows
+            # hub stopped (or a re-entrant call from a hub worker — never
+            # wait on ourselves): the cold rows verify inline below
+            queueing = self._running and threading.get_ident() not in self._worker_ids
+            hits = joined = 0
+            for i, key in enumerate(keys):
+                verdict = cache.get(key)
+                if verdict is not None:
+                    cache.move_to_end(key)
+                    results[i] = verdict
+                    hits += 1
+                    continue
+                pending = lone(key)
+                if pending is not None:
+                    pending.futures.append((group, i))
+                    pending.add_trace(trace_ctx)
+                    if lane == LANE_LIVE:
+                        self._promote(pending)
+                    joined += 1
+                    continue
+                row = group_rows.get(key)
+                if row is not None:
+                    other = row.group
+                    other.join(row, (group, i))
+                    if other is not group:
+                        if trace_ctx is not None:
+                            other.traces.append([trace_ctx, group.enqueued_at, 1])
+                        if lane == LANE_LIVE:
+                            self._promote_group(other)
+                    joined += 1
+                    continue
+                if not queueing:
+                    inline.append(i)
+                    continue
+                pk, msg, sig = items[i]
+                row = group_rows[key] = _Row(key, pk, msg, sig, group, i)
+                rows.append(row)
+            self._stats["cache_hits"] += hits
+            self._stats["coalesced"] += joined
+            group.remaining = joined + len(rows)
+            if rows:
+                if trace_ctx is not None:
+                    group.traces.append([trace_ctx, group.enqueued_at, len(rows)])
+                group.queued = True
+                self._groups[lane].append(group)
+                self._stats["submitted"] += len(rows)
+                self._stats[f"lane_{lane}_submitted"] += len(rows)
+                self._stats["bulk_groups"] += 1
+                self._stats["bulk_group_sigs"] += len(rows)
+            if group.remaining:
+                # a blocked caller: no window, whatever it waits for
+                self._urgent = True
+                self._cv.notify_all()
+        for i in inline:
+            pk, msg, sig = items[i]
+            results[i] = pk.verify_signature(msg, sig)
+        if not group.remaining:
+            group.wake()
+        return group, hits + len(inline)
+
+    def _lone(self, key: tuple) -> _Pending | None:
+        """The lone request for `key` that is queued or in flight. Caller
+        holds _cv."""
+        return (
+            self._queues[LANE_LIVE].get(key)
+            or self._queues[LANE_BACKFILL].get(key)
+            or self._inflight.get(key)
+        )
+
+    def _promote(self, pending: _Pending) -> None:
+        """A live caller now waits on this triple: pull the still-queued
+        backfill entry into the live lane so it stops queueing behind
+        bulk catch-up traffic. Caller holds _cv."""
+        if pending.lane == LANE_BACKFILL and pending.key in self._queues[LANE_BACKFILL]:
+            del self._queues[LANE_BACKFILL][pending.key]
+            pending.lane = LANE_LIVE
+            self._queues[LANE_LIVE][pending.key] = pending
+            self._stats["lane_promotions"] += 1
+
+    def _promote_group(self, group: _Group) -> None:
+        """`_promote` for a row of a still-queued backfill group: the
+        group is one unit, so it goes over whole. Caller holds _cv."""
+        if group.lane == LANE_BACKFILL and group.queued:
+            self._groups[LANE_BACKFILL].remove(group)
+            group.lane = LANE_LIVE
+            self._groups[LANE_LIVE].append(group)
+            self._stats["lane_promotions"] += 1
 
     def flush(self) -> None:
         """Dispatch everything currently queued without waiting out the
@@ -515,11 +707,9 @@ class VerifyHub:
     def stats(self) -> dict:
         with self._cv:
             s = dict(self._stats)
-            s["queued"] = float(
-                sum(len(q) for q in self._queues.values())
-            )
-            s["lane_live_queued"] = float(len(self._queues[LANE_LIVE]))
-            s["lane_backfill_queued"] = float(len(self._queues[LANE_BACKFILL]))
+            s["queued"] = float(self._queued())
+            for lane in LANES:
+                s[f"lane_{lane}_queued"] = float(self._lane_queued(lane))
             s["cache_size"] = float(len(self._cache))
             s["mean_occupancy"] = (
                 s["dispatched_sigs"] / s["dispatches"] if s["dispatches"] else 0.0
@@ -574,8 +764,12 @@ class VerifyHub:
         frac = min(1.0, (occ - 1.0) / max(self._effective_max() / 8.0, 1.0))
         return self.window_s * frac
 
+    def _lane_queued(self, lane: str) -> int:
+        """Rows queued on a lane: its lone requests and its groups' rows."""
+        return len(self._queues[lane]) + sum(len(g.rows) for g in self._groups[lane])
+
     def _queued(self) -> int:
-        return sum(len(q) for q in self._queues.values())
+        return sum(self._lane_queued(lane) for lane in LANES)
 
     def _dispatch_loop(self) -> None:
         self._worker_ids.add(threading.get_ident())
@@ -597,9 +791,8 @@ class VerifyHub:
                 # is draining for shutdown
                 if self._running:
                     oldest = min(
-                        next(iter(q.values())).enqueued_at
-                        for q in self._queues.values()
-                        if q
+                        [next(iter(q.values())).enqueued_at for q in self._queues.values() if q]
+                        + [g[0].enqueued_at for g in self._groups.values() if g]
                     )
                     deadline = oldest + self._window()
                     while (
@@ -622,7 +815,7 @@ class VerifyHub:
             t_slot = time.monotonic()
             self._slots.acquire()
             with self._cv:
-                batch = self._pack_batch()
+                batch, groups = self._pack_batch()
                 if not self._queued():
                     self._urgent = False
                 now = time.monotonic()
@@ -633,18 +826,31 @@ class VerifyHub:
                 # single vote's trace holds one request, a block-sync
                 # range's thousands
                 joined: dict[int, list] = {}
+
+                def join(ctx, at, n, lane):
+                    seen = joined.get(ctx.trace_id)
+                    if seen is None:
+                        joined[ctx.trace_id] = [ctx, at, n, lane]
+                    else:
+                        seen[1] = min(seen[1], at)
+                        seen[2] += n
+
                 for p in batch:
                     wait = now - p.enqueued_at
                     waited += wait
                     self.latency_hist.observe(wait)
                     if p.traces:
                         for ctx, at in p.traces:
-                            seen = joined.get(ctx.trace_id)
-                            if seen is None:
-                                joined[ctx.trace_id] = [ctx, at, 1, p.lane]
-                            else:
-                                seen[1] = min(seen[1], at)
-                                seen[2] += 1
+                            join(ctx, at, 1, p.lane)
+                sigs = len(batch)
+                for g in groups:
+                    # a group's wait weighs as its rows' would have
+                    wait = now - g.enqueued_at
+                    waited += wait * len(g.rows)
+                    self.latency_hist.observe(wait, len(g.rows))
+                    for ctx, at, n in g.traces:
+                        join(ctx, at, n, g.lane)
+                    sigs += len(g.rows)
                 self._stats["queue_wait_s"] += waited
                 # queue span: submit-to-pack wait, per joined trace.
                 # enqueued_at is SYSTEM-domain; the trace may time on an
@@ -659,7 +865,7 @@ class VerifyHub:
                         tc_now - max(0.0, now - at), tc_now, lane=lane, n=n,
                     )
                 self._stats["dispatches"] += 1
-                self._stats["dispatched_sigs"] += len(batch)
+                self._stats["dispatched_sigs"] += sigs
                 tenants: set = set()
                 for p in batch:
                     if p.tenants:
@@ -670,17 +876,23 @@ class VerifyHub:
                     self._stats["cross_tenant_dispatches"] += 1
                 alpha = 0.2
                 self._ewma_occupancy = (
-                    (1 - alpha) * self._ewma_occupancy + alpha * len(batch)
+                    (1 - alpha) * self._ewma_occupancy + alpha * sigs
                 )
             # hand off outside the lock; the runner's done-callback
             # frees the slot
-            fut = self._runner.submit(self._run_batch, batch, joined)
+            fut = self._runner.submit(self._run_batch, batch, groups, joined)
             fut.add_done_callback(lambda _f: self._slots.release())
 
-    def _pack_batch(self) -> list[_Pending]:
-        """Pop up to the mesh-scaled batch capacity, live lane FIRST —
-        catch-up traffic can never displace the hot path. Caller holds
-        _cv."""
+    def _pack_batch(self) -> tuple[list[_Pending], list[_Group]]:
+        """(lone requests, groups) of one dispatch. The lone requests
+        first, up to the mesh-scaled batch capacity, live lane FIRST —
+        catch-up traffic can never displace the hot path. Then groups,
+        live lane first again: the first one WHOLE whatever its size — a
+        group is never split, the verifier chunks what outgrows its
+        program — and more only while the dispatch stays within the
+        capacity (small commits still share a launch; a second range
+        waits for the next one, and a live arrival rides ahead of it).
+        Caller holds _cv."""
         cap = self._effective_max()
         batch: list[_Pending] = []
         for lane in LANES:
@@ -690,25 +902,41 @@ class VerifyHub:
                 self._inflight[p.key] = p
                 batch.append(p)
                 self._stats[f"lane_{lane}_dispatched"] += 1
-        return batch
+        groups: list[_Group] = []
+        sigs = len(batch)
+        for lane in LANES:
+            q = self._groups[lane]
+            while q and (not groups or sigs + len(q[0].rows) <= cap):
+                g = q.popleft()
+                g.queued = False
+                groups.append(g)
+                sigs += len(g.rows)
+                self._stats[f"lane_{lane}_dispatched"] += len(g.rows)
+        return batch, groups
 
-    def _run_batch(self, batch: list[_Pending], joined: dict | None = None) -> None:
-        """Verify one packed batch on a runner thread and settle its
-        futures. `joined` is the dispatcher's map of the traces the
-        batch's requests belong to. This thread inherits no context:
+    def _run_batch(
+        self, batch: list[_Pending], groups: list[_Group], joined: dict | None = None
+    ) -> None:
+        """Verify one packed dispatch on a runner thread — the lone
+        requests, then each group's rows, as ONE batch — and settle it:
+        one pass under the lock writes the verdicts into the LRU, clears
+        the in-flight entries and fills each group's list in the caller's
+        order. `joined` is the dispatcher's map of the traces the
+        dispatch's requests belong to. This thread inherits no context:
         the hub.dispatch span joins the batch's trace when there is
         exactly one (a block-sync range: always), else stands alone,
         and lists every joined trace id."""
         self._worker_ids.add(threading.get_ident())
         joined = joined or {}
         only = next(iter(joined.values()))[0] if len(joined) == 1 else None
+        rows = batch + [row for g in groups for row in g.rows] if groups else batch
         t0 = time.monotonic()
-        with trace.span("hub", "dispatch", ctx=only, sigs=len(batch)) as sp:
+        with trace.span("hub", "dispatch", ctx=only, sigs=len(rows)) as sp:
             try:
-                results = self._verify_batch(batch)
+                results = self._verify_batch(rows)
             except Exception as e:  # noqa: BLE001 — fail the batch, not the hub
                 sp.set(error=repr(e))
-                self._fail_batch(batch, e)
+                self._fail_batch(batch, groups, e)
                 return
             # where THIS batch actually ran. _verify_batch stashed the
             # route in a thread-local: the process-global batch.LAST_ROUTE
@@ -724,14 +952,32 @@ class VerifyHub:
                 # ids + real signatures per shard (tracectl --per-device)
                 sp.set(devices=disp["devices"], shards=disp["shards"])
         t1 = time.monotonic()
+        futures: list[tuple[Future, bool]] = []
         with self._cv:
+            cache = self._cache if self.cache_size else None
             for p, ok in zip(batch, results):
                 self._inflight.pop(p.key, None)
-                if self.cache_size:
-                    self._cache[p.key] = ok
-                    self._cache.move_to_end(p.key)
-                    while len(self._cache) > self.cache_size:
-                        self._cache.popitem(last=False)
+                if cache is not None:
+                    cache[p.key] = ok
+                    cache.move_to_end(p.key)
+                self._answer(p.futures, ok, futures)
+            rest = iter(results[len(batch):])
+            for g in groups:
+                verdicts = g.results
+                for row, ok in zip(g.rows, rest):
+                    del self._group_rows[row.key]
+                    if cache is not None:
+                        cache[row.key] = ok
+                    verdicts[row.index] = ok
+                g.remaining -= len(g.rows)
+                if not g.remaining:
+                    g.wake()
+                if g.joiners:
+                    for row, waiters in g.joiners.items():
+                        self._answer(waiters, verdicts[row.index], futures)
+            if cache is not None:
+                while len(cache) > self.cache_size:
+                    cache.popitem(last=False)
         # t0/t1 are SYSTEM-domain; anchor each trace's execute span
         # ending at the trace clock's now so it sits correctly among the
         # trace's other spans under an injected chaos clock
@@ -740,23 +986,51 @@ class VerifyHub:
             # tmtlint: allow[span-per-item] -- one row per (dispatch, trace), not per request
             trace.record(
                 ctx, "hub", "execute", tc_now - (t1 - t0), tc_now,
-                batch=len(batch), n=n, route=route,
+                batch=len(rows), n=n, route=route,
             )
-        for p, ok in zip(batch, results):
-            for f in p.futures:
-                if not f.done():
-                    f.set_result(ok)
+        for f, ok in futures:
+            if not f.done():
+                f.set_result(ok)
 
-    def _fail_batch(self, batch: list[_Pending], e: Exception) -> None:
+    @staticmethod
+    def _answer(waiters: list, ok: bool, futures: list) -> None:
+        """One verdict to whoever waits for it: a group's row is written
+        in place (its caller woken with its last), a lone request's
+        Future is noted for the caller to resolve once the lock is
+        dropped. Caller holds _cv."""
+        for w in waiters:
+            if type(w) is tuple:
+                group, i = w
+                group.results[i] = ok
+                group.remaining -= 1
+                if not group.remaining:
+                    group.wake()
+            else:
+                futures.append((w, ok))
+
+    def _fail_batch(self, batch: list[_Pending], groups: list[_Group], e: Exception) -> None:
+        waiters: list = []
         with self._cv:
             self._stats["verify_errors"] += 1
             for p in batch:
                 self._inflight.pop(p.key, None)
-        logger.warning("batch of %d failed to verify: %r", len(batch), e)
-        for p in batch:
-            for f in p.futures:
-                if not f.done():
-                    f.set_exception(e)
+                waiters.extend(p.futures)
+            for g in groups:
+                for row in g.rows:
+                    del self._group_rows[row.key]
+                g.fail(e)
+                if g.joiners:
+                    for others in g.joiners.values():
+                        waiters.extend(others)
+        logger.warning(
+            "batch of %d failed to verify: %r", len(batch) + sum(len(g.rows) for g in groups), e
+        )
+        for w in waiters:
+            if type(w) is tuple:
+                # a row of another group rode this dispatch: that group fails
+                w[0].fail(e)
+            elif not w.done():
+                w.set_exception(e)
 
     def _remote(self, purpose: str = "batch"):
         """The verifyd sidecar client for this hub's configured socket,
@@ -771,7 +1045,7 @@ class VerifyHub:
 
         return verifyd.client_for(self.verifyd_sock, purpose)
 
-    def _verify_batch(self, batch: list[_Pending]) -> list[bool]:
+    def _verify_batch(self, batch: list[_Pending | _Row]) -> list[bool]:
         """One batched verify per scheme per dispatch.
 
         Remote route first: when a verifyd sidecar is configured
@@ -833,6 +1107,11 @@ class VerifyHub:
                 results[idxs[0]] = p.pub_key.verify_signature(p.msg, p.sig)
                 continue
             bv = create_batch_verifier(batch[idxs[0]].pub_key)
+            if len(idxs) > self._effective_max():
+                # more rows than lone requests ever make: a group went
+                # out whole. ONE device shape for every such size, the
+                # program start-up warmed
+                bv.whole_chunk = True
             for i in idxs:
                 p = batch[i]
                 bv.add(p.pub_key, p.msg, p.sig)

@@ -68,14 +68,15 @@ class Histogram:
         # child carries e.g. ("step", "propose"))
         self.const_labels = tuple(const_labels)
 
-    def observe(self, value: float) -> None:
-        self._sum += value
-        self._count += 1
+    def observe(self, value: float, n: int = 1) -> None:
+        """`n` observations of `value` (one, unless the caller weighs)."""
+        self._sum += value * n
+        self._count += n
         for i, b in enumerate(self.buckets):
             if value <= b:
-                self._counts[i] += 1
+                self._counts[i] += n
                 return
-        self._counts[-1] += 1
+        self._counts[-1] += n
 
     def _series(self) -> list[str]:
         base = self.const_labels
@@ -466,6 +467,12 @@ class NodeMetrics:
         self.verifyhub_coalesced = r.counter(
             "verifyhub", "coalesced", "requests joined onto an in-flight verify"
         )
+        self.verifyhub_bulk_groups = r.counter(
+            "verifyhub", "bulk_groups", "groups (verify_many) queued as one unit"
+        )
+        self.verifyhub_bulk_group_sigs = r.counter(
+            "verifyhub", "bulk_group_sigs", "cold signatures those groups held"
+        )
         self.verifyhub_occupancy = r.gauge(
             "verifyhub", "batch_occupancy", "mean signatures per dispatch"
         )
@@ -726,6 +733,8 @@ class NodeMetrics:
         self.verifyhub_sigs._values[()] = s["dispatched_sigs"]
         self.verifyhub_cache_hits._values[()] = s["cache_hits"]
         self.verifyhub_coalesced._values[()] = s["coalesced"]
+        self.verifyhub_bulk_groups._values[()] = s["bulk_groups"]
+        self.verifyhub_bulk_group_sigs._values[()] = s["bulk_group_sigs"]
         self.verifyhub_occupancy.set(round(s["mean_occupancy"], 3))
         self.verifyhub_dispatch_rate.set(round(s["dispatch_rate"], 3))
         self.verifyhub_cache_hit_rate.set(round(s["cache_hit_rate"], 4))

@@ -99,7 +99,10 @@ def test_a_reactor_that_holds_a_run_to_one_set_is_caught(root, monkeypatch):
         await self._apply_sequential(run, parts, ids, 0, len(ids))
 
     monkeypatch.setattr(reactor.BlockSyncReactor, "_verify_and_apply", one_set)
-    res = run.execute(root, tiny_churn.CELL, 3000003515, 0.3, False,
+    # a window that outlasts the first run's failed batch (64 commits on the
+    # host route, ≈ 0.8 s here): at 0.3 s it closed before or after the first
+    # block was applied, as the machine's load had it
+    res = run.execute(root, tiny_churn.CELL, 3000003515, 2.0, False,
                       device=tiny_churn.CPU_DEVICE)
     assert {"sequential_blocks", "plans_minus_expected",
             "sigs_asked_minus_needed"} <= _failed(res)

@@ -390,7 +390,9 @@ class TestHubBulk:
             vh.release_hub()
         spans = recorder.dump()
         dispatches = int(s1["dispatches"] - s0["dispatches"])
-        assert 4 <= dispatches <= 60 and s1["dispatched_sigs"] - s0["dispatched_sigs"] == 500
+        # the group is ONE unit: dispatched whole, never cut at max_batch (128 here)
+        assert dispatches == 1 and s1["dispatched_sigs"] - s0["dispatched_sigs"] == 500
+        assert s1["bulk_groups"] - s0["bulk_groups"] == 1 == s2["bulk_groups"] - s0["bulk_groups"]
         by = {}
         for x in spans:
             by.setdefault(_key(x), []).append(x)

@@ -7,6 +7,7 @@ proposals, commits route through the hub), the callsite lint, and the
 import os
 import subprocess
 import sys
+import threading
 import time
 
 import pytest
@@ -145,6 +146,300 @@ class TestScheduling:
             with pytest.raises(RuntimeError):
                 f.result(10.0)
         assert hub.stats()["verify_errors"] == 1
+
+
+def _held(hub):
+    """Both in-flight slots taken: the dispatcher parks at its
+    pack-at-the-last-moment acquire, so whatever is submitted meanwhile
+    is queued TOGETHER when `release()` lets it pack."""
+    hub._slots.acquire()
+    hub._slots.acquire()
+
+    def release():
+        hub._slots.release()
+        hub._slots.release()
+
+    return release
+
+
+def _in_thread(fn):
+    """Run `fn` on a thread; returns (thread, out) — out[0] is its result
+    or the exception it raised."""
+    out = []
+
+    def run():
+        try:
+            out.append(fn())
+        except Exception as e:  # noqa: BLE001 — handed to the test
+            out.append(e)
+
+    t = threading.Thread(target=run)
+    t.start()
+    return t, out
+
+
+def _until(cond, timeout=10.0):
+    deadline = time.monotonic() + timeout
+    while not cond():
+        assert time.monotonic() < deadline, "condition not reached"
+        time.sleep(0.002)
+
+
+def _recording(hub):
+    """Every dispatch's lanes, row by row, as `_verify_batch` is handed them."""
+    batches = []
+    orig = hub._verify_batch
+
+    def record(batch):
+        batches.append([p.lane for p in batch])
+        return orig(batch)
+
+    hub._verify_batch = record
+    return batches
+
+
+class TestGroups:
+    """`verify_many`: the group is ONE unit from submit to settle."""
+
+    def test_a_thousand_rows_are_one_dispatch(self, hub):
+        items = _items(1000, b"grp")
+        pub, msg, _ = items[417]
+        items[417] = (pub, msg, items[418][2])  # forged: another row's signature
+        s0 = hub.stats()
+        res = hub.verify_many(items, lane="backfill")
+        s1 = hub.stats()
+        assert res == [i != 417 for i in range(1000)]
+        assert hub.max_batch == 8  # never cut there
+        assert s1["dispatches"] - s0["dispatches"] == 1
+        assert s1["dispatched_sigs"] - s0["dispatched_sigs"] == 1000
+        assert s1["bulk_groups"] - s0["bulk_groups"] == 1
+        assert s1["bulk_group_sigs"] - s0["bulk_group_sigs"] == 1000
+        assert s1["submitted"] == s1["lane_backfill_submitted"] == 1000
+        assert s1["lane_backfill_dispatched"] == 1000 and s1["queued"] == 0
+        # the queue-wait counter and the histogram weigh the group by its rows
+        _counts, _sum, count = hub.latency_snapshot()
+        assert count == 1000 and s1["queue_wait_s"] == pytest.approx(_sum)
+
+    def test_the_same_group_again_is_answered_from_the_lru(self):
+        h = VerifyHub(max_batch=8, window_ms=100.0, cache_size=2048, adaptive=False)
+        h.start()
+        try:
+            items = _items(1000, b"again")
+            items[3] = (*items[3][:2], b"\x05" * 64)
+            first = h.verify_many(items)
+            s1 = h.stats()
+            assert h.verify_many(items) == first and first.count(False) == 1
+            s2 = h.stats()
+        finally:
+            h.stop()
+        assert s2["cache_hits"] - s1["cache_hits"] == 1000
+        assert s2["dispatches"] == s1["dispatches"] == 1
+        assert s2["bulk_groups"] == s1["bulk_groups"] == 1 and s2["submitted"] == 1000
+
+    def test_rows_shared_with_a_group_in_flight_coalesce(self, hub):
+        gate = threading.Event()
+        orig = hub._verify_batch
+        hub._verify_batch = lambda batch: (gate.wait(10.0), orig(batch))[1]
+        a = _items(300, b"share")
+        a[7] = (*a[7][:2], b"\x06" * 64)
+        b = a[200:] + _items(50, b"share-own") + [a[7], a[250]]
+        ta, out_a = _in_thread(lambda: hub.verify_many(a, lane="backfill"))
+        _until(lambda: hub.stats()["dispatches"] == 1)  # a is in flight
+        tb, out_b = _in_thread(lambda: hub.verify_many(b, lane="backfill"))
+        _until(lambda: hub.stats()["coalesced"] == 102)
+        # a lone request for a row of the group in flight joins it too
+        lone = hub.submit_nowait(*a[7])
+        gate.set()
+        ta.join(10.0)
+        tb.join(10.0)
+        assert out_a[0] == [i != 7 for i in range(300)]
+        assert out_b[0] == [True] * 150 + [False, True]
+        assert lone.result(10.0) is False
+        s = hub.stats()
+        assert s["coalesced"] == 103 and s["submitted"] == 350
+        assert s["dispatched_sigs"] == 350 and s["bulk_groups"] == 2
+
+    def test_a_group_s_own_duplicates_and_a_queued_lone_request_coalesce(self, hub):
+        release = _held(hub)
+        lone = _items(1, b"dup-lone")[0]
+        f = hub.submit_nowait(*lone, lane="backfill")
+        items = _items(20, b"dup")
+        group = items + [items[4], lone, items[4]]
+        t, out = _in_thread(lambda: hub.verify_many(group, lane="backfill"))
+        _until(lambda: hub.stats()["coalesced"] == 3)
+        assert hub.stats()["lane_backfill_queued"] == 21
+        release()
+        t.join(10.0)
+        assert out[0] == [True] * 23 and f.result(10.0) is True
+        s = hub.stats()
+        assert s["submitted"] == 21 == s["dispatched_sigs"] and s["bulk_group_sigs"] == 20
+
+    def test_rows_asked_are_counted_once_each(self, hub):
+        """`submitted + cache_hits + coalesced` = rows asked, however they
+        came: the benchmark's `sigs_asked_minus_needed` rests on it."""
+        asked = 0
+        a, b, c = _items(40, b"cnt-a"), _items(40, b"cnt-b"), _items(5, b"cnt-c")
+        for group in (a, a[:10] + b, b + b[:3], []):
+            assert all(hub.verify_many(group, lane="backfill"))
+            asked += len(group)
+        for pk, m, sig in c + c[:2]:
+            assert hub.verify_sync(pk, m, sig) is True
+            asked += 1
+        assert all(hub.verify_many(c + a[30:] + _items(9, b"cnt-d")))
+        asked += 5 + 10 + 9
+        s = hub.stats()
+        assert s["submitted"] + s["cache_hits"] + s["coalesced"] == asked
+        assert s["submitted"] == 40 + 40 + 5 + 9 == s["dispatched_sigs"]
+        assert s["lane_live_submitted"] == 5 + 9 and s["lane_backfill_submitted"] == 80
+
+    def test_a_live_request_is_packed_ahead_of_a_waiting_group(self):
+        h = VerifyHub(max_batch=4, window_ms=5_000.0, cache_size=64, adaptive=False)
+        batches = _recording(h)
+        h.start()
+        try:
+            release = _held(h)
+            first, second = _items(30, b"bulk-1"), _items(30, b"bulk-2")
+            t1, out1 = _in_thread(lambda: h.verify_many(first, lane="backfill"))
+            _until(lambda: h.stats()["lane_backfill_queued"] == 30)
+            t2, out2 = _in_thread(lambda: h.verify_many(second, lane="backfill"))
+            _until(lambda: h.stats()["lane_backfill_queued"] == 60)
+            (pk, m, sig), = _items(1, b"vote")
+            vote = h.submit_nowait(pk, m, sig, urgent=True)
+            release()
+            assert vote.result(10.0) is True
+            t1.join(10.0)
+            t2.join(10.0)
+            assert out1[0] == [True] * 30 == out2[0]
+        finally:
+            h.stop()
+        # the vote leads the very next dispatch; the first group rides it
+        # whole (never cut at max_batch 4), the second waits for its own
+        assert batches == [["live"] + ["backfill"] * 30, ["backfill"] * 30], batches
+        s = h.stats()
+        assert s["dispatches"] == 2 and s["lane_live_dispatched"] == 1
+
+    def test_small_groups_share_a_dispatch_up_to_max_batch(self):
+        h = VerifyHub(max_batch=16, window_ms=5_000.0, cache_size=64, adaptive=False)
+        batches = _recording(h)
+        h.start()
+        try:
+            release = _held(h)
+            threads = []
+            for k in range(4):
+                items = _items(6, b"small-%d" % k)
+                threads.append(_in_thread(lambda items=items: h.verify_many(items)))
+                _until(lambda: h.stats()["lane_live_queued"] == 6 * (k + 1))
+            release()
+            for t, out in threads:
+                t.join(10.0)
+                assert out[0] == [True] * 6
+        finally:
+            h.stop()
+        assert [len(b) for b in batches] == [12, 12], batches
+
+    def test_a_live_row_promotes_the_queued_backfill_group(self):
+        h = VerifyHub(max_batch=4, window_ms=5_000.0, cache_size=64, adaptive=False)
+        batches = _recording(h)
+        h.start()
+        try:
+            release = _held(h)
+            ahead, behind = _items(10, b"ahead"), _items(10, b"behind")
+            ta, out_a = _in_thread(lambda: h.verify_many(ahead, lane="backfill"))
+            _until(lambda: h.stats()["lane_backfill_queued"] == 10)
+            tb, out_b = _in_thread(lambda: h.verify_many(behind, lane="backfill"))
+            _until(lambda: h.stats()["lane_backfill_queued"] == 20)
+            f = h.submit_nowait(*behind[3], lane="live")
+            st = h.stats()
+            assert st["lane_promotions"] == 1 and st["coalesced"] == 1
+            assert st["lane_live_queued"] == 10 == st["lane_backfill_queued"]
+            release()
+            assert f.result(10.0) is True
+            ta.join(10.0)
+            tb.join(10.0)
+            assert out_a[0] == [True] * 10 == out_b[0]
+        finally:
+            h.stop()
+        assert batches == [["live"] * 10, ["backfill"] * 10], batches
+
+    def test_stop_drains_a_queued_group(self):
+        h = VerifyHub(max_batch=4, window_ms=5_000.0, cache_size=64, adaptive=False)
+        h.start()
+        release = _held(h)
+        items = _items(50, b"drain-group")
+        t, out = _in_thread(lambda: h.verify_many(items, lane="backfill"))
+        _until(lambda: h.stats()["lane_backfill_queued"] == 50)
+        stopper, _ = _in_thread(h.stop)
+        _until(lambda: not h.is_running)
+        release()
+        stopper.join(10.0)
+        t.join(10.0)
+        assert out[0] == [True] * 50 and h.stats()["dispatches"] == 1
+        # after shutdown a group verifies inline, never hangs, counts no submission
+        late = _items(3, b"late-group")
+        late[1] = (*late[1][:2], b"\x07" * 64)
+        assert h.verify_many(late, timeout=1.0) == [True, False, True]
+        assert h.stats()["submitted"] == 50
+
+    def test_a_raising_verifier_fails_the_group_and_the_funnel_falls_back(
+        self, process_hub, monkeypatch
+    ):
+        from tendermint_tpu.types import validation
+
+        def boom(_pk):
+            raise RuntimeError("verifier construction exploded")
+
+        monkeypatch.setattr(vh, "create_batch_verifier", boom)
+        items = _items(12, b"grp-err")
+        with pytest.raises(RuntimeError, match="exploded"):
+            process_hub.verify_many(items, lane="backfill")
+        s = process_hub.stats()
+        assert s["verify_errors"] == 1 and s["queued"] == 0 and not process_hub._group_rows
+        # the commit funnel's shim: the hub's failure costs latency, not the verdict
+        items[5] = (*items[5][:2], b"\x08" * 64)
+        bv = validation._CommitVerifier(lane="backfill")
+        for it in items:
+            bv.add(*it)
+        ok, bitmap = bv.verify()
+        assert not ok and bitmap == [i != 5 for i in range(12)] and bv.via == "local"
+        assert process_hub.stats()["verify_errors"] == 2
+
+    def test_unknown_lane_rejected(self, hub):
+        with pytest.raises(ValueError, match="unknown verify lane"):
+            hub.verify_many(_items(2, b"lane"), lane="backfil")
+
+    @pytest.mark.parametrize(
+        "rows, bucket, groups",
+        [(70, 128, 63), (100, 128, 63), (101, 512, 255), (150, 512, 255), (400, 512, 255),
+         (1100, 512, 255)],
+    )
+    def test_a_dispatch_past_max_batch_goes_out_at_the_chunk_shape(
+        self, monkeypatch, rows, bucket, groups
+    ):
+        """≤ max_batch rows: the ladder rung of the row count and the
+        keys' own group bucket, as lone requests always went. More: ONE
+        shape, the program start-up warms — the verifier's chunk (512
+        rows here; a group beyond it is cut there by the verifier) at the
+        group bucket of `verify._CHUNK_GROUPS` keys."""
+        from tendermint_tpu.crypto import backend_telemetry as bt
+        from tests import stub_dispatch
+
+        log, shapes = [], []
+        eq, _sig = stub_dispatch.install_kernels(monkeypatch, log, max_bucket=512)
+        V = stub_dispatch.V
+        monkeypatch.setattr(
+            V, "_get_kernel_eq",
+            lambda: lambda *a: (shapes.append((a[1].shape[0], a[0].shape[0])), eq(*a))[1],
+        )
+        stub_dispatch.install_device_route(monkeypatch)
+        h = VerifyHub(max_batch=100, window_ms=1.0, cache_size=0)
+        h.start()
+        try:
+            assert all(h.verify_many(_items(rows, b"shape-%d" % rows), lane="backfill"))
+            assert h.stats()["dispatches"] == 1
+        finally:
+            h.stop()
+            bt.reset()
+        assert shapes == [(bucket, groups)] * -(-rows // 512), shapes
 
 
 class TestFallbackIdentity:
