@@ -1,0 +1,15 @@
+"""exec_ms_per_block.mixedsync
+
+`state.validate` + `state.exec` + `state.commit` over blocks applied: `.blocksync`'s twin.
+"""
+
+from benchmark import mixedsync_readers
+
+LAYER = "apply and stores"
+UNIT = "ms/block"
+SOURCE = "program_span"
+MOVES = "blocksync_blocks_per_s"
+
+
+def read(r):
+    return mixedsync_readers.ms_per_unit(r, "state.validate", "state.exec", "state.commit")

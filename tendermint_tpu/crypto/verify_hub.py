@@ -6,10 +6,14 @@ verify_commit* funnel) submits ``(pubkey, sign_bytes, sig)`` to the hub
 and awaits a per-item verdict. The hub coalesces concurrent requests
 into hardware-sized batches — the shared-verification-engine shape the
 committee-consensus (arXiv:2302.00418) and FPGA-ECDSA (arXiv:2112.02229)
-measurements point at — and runs one batched verify per dispatch through
-the existing `create_batch_verifier` machinery, so the TPU circuit
-breaker, CPU re-verify fallback, and measured routing cutoff all apply
-unchanged.
+measurements point at — and hands each dispatch, whatever its key types,
+to ONE `crypto.batch.AdaptiveBatchVerifier`, so the TPU circuit breaker,
+CPU re-verify fallback and measured routing cutoff all apply unchanged.
+The hub does not partition by scheme itself: the verifier does (Edwards
+rows in one MSM dispatch, BLS on the pairing path, rows of a key type
+with no batch kernel — secp256k1 — on its host lane, pool tasks started
+before the device partitions and joined after them, each partition
+counted under its own route), exactly as for a caller without a hub.
 
 Scheduling model (one dispatcher thread + one device-runner thread):
 
@@ -96,13 +100,13 @@ import logging
 import os
 import threading
 import time
-from collections import OrderedDict, deque
+from collections import Counter, OrderedDict, deque
 from concurrent.futures import Future, ThreadPoolExecutor
 
 from ..libs import trace
 from ..libs.metrics import Histogram
 from . import PubKey
-from .batch import create_batch_verifier, supports_batch_verifier
+from .batch import AdaptiveBatchVerifier, rows_by_lane
 from .hashes import sha256
 
 logger = logging.getLogger("crypto.verify_hub")
@@ -359,11 +363,14 @@ class VerifyHub:
             "lane_live_dispatched": 0.0,
             "lane_backfill_dispatched": 0.0,
             "lane_promotions": 0.0,  # backfill entries pulled into live
-            # per-scheme dispatch accounting (micro-batches partition by
-            # scheme: ed25519/sr25519 share the Edwards kernel, BLS runs
-            # the pairing path — rendered as verifyhub_scheme_sigs{scheme=})
+            # dispatched rows by the lane the verifier gives them (it
+            # partitions by scheme: ed25519/sr25519 share the Edwards
+            # kernel, BLS runs the pairing path, a key type with no batch
+            # kernel — secp256k1 — its host lane; rendered as
+            # verifyhub_scheme_sigs{scheme=})
             "scheme_edwards_sigs": 0.0,
             "scheme_bls_sigs": 0.0,
+            "scheme_host_sigs": 0.0,
             # multi-tenant packing (the verifyd daemon's hub): dispatches
             # whose batch mixed signatures from >1 client connection
             "cross_tenant_dispatches": 0.0,
@@ -944,7 +951,11 @@ class VerifyHub:
             # validation funnel builds its own)
             route = getattr(self._route_local, "route", "cpu")
             disp = getattr(self._route_local, "dispatch", None)
+            host_rows = getattr(self._route_local, "host_rows", 0)
             sp.set(route=route)
+            if host_rows:
+                # rows that took the verifier's host lane (no batch kernel)
+                sp.set(host_rows=host_rows)
             if joined:
                 sp.set(traces=list(joined))
             if disp:
@@ -1046,7 +1057,7 @@ class VerifyHub:
         return verifyd.client_for(self.verifyd_sock, purpose)
 
     def _verify_batch(self, batch: list[_Pending | _Row]) -> list[bool]:
-        """One batched verify per scheme per dispatch.
+        """One verifier call per dispatch, whatever its key types.
 
         Remote route first: when a verifyd sidecar is configured
         (`verifyd_sock`), the whole packed batch ships over the UDS and
@@ -1057,13 +1068,24 @@ class VerifyHub:
         client and the batch falls through to the local path below: the
         sidecar can never be a correctness or liveness event.
 
-        Local path: batchable key types are PARTITIONED by scheme —
-        ed25519/sr25519 share the Edwards MSM kernel, bls12381 runs the
-        pairing kernel / pure path — so a mixed-scheme micro-batch
-        never packs both into one kernel dispatch. Each partition gets
-        its own AdaptiveBatchVerifier (TPU/CPU routing, breaker, and
-        identical-result fallback live there); anything unbatchable
-        verifies on the host individually."""
+        Local path: a dispatch of ONE row verifies directly; every other
+        goes to ONE AdaptiveBatchVerifier with every row in it, and the
+        partition by scheme is the verifier's (crypto/batch): rows with
+        no batch kernel (secp256k1) on its host lane — pool tasks started
+        BEFORE the device partitions are routed and joined AFTER them,
+        counted under their own route — BLS on the pairing path, the
+        Edwards rows in one MSM dispatch; TPU/CPU routing, the breaker
+        and the identical-result fallback live there too."""
+        # rows by the verifier's own lanes (its rule, not a copy of it)
+        edwards, host = rows_by_lane(Counter(p.pub_key.TYPE for p in batch))
+        with self._cv:
+            self._stats["scheme_edwards_sigs"] += edwards
+            self._stats["scheme_bls_sigs"] += len(batch) - edwards - host
+            self._stats["scheme_host_sigs"] += host
+        # where this batch ran, for the dispatch/execute spans: set per
+        # worker thread (concurrent _run_batch calls must not race)
+        local = self._route_local
+        local.route, local.dispatch, local.host_rows = "cpu", None, host
         remote = self._remote()
         if remote is not None:
             verdicts = remote.remote_verify_batch(
@@ -1072,59 +1094,22 @@ class VerifyHub:
             if verdicts is not None:
                 # stamp the route for the hub.dispatch span: tracectl
                 # can then attribute socket RTT vs local device time
-                self._route_local.route = "verifyd"
-                self._route_local.dispatch = None
-                with self._cv:
-                    for p in batch:
-                        scheme = (
-                            "bls" if p.pub_key.TYPE == "bls12381" else "edwards"
-                        )
-                        if supports_batch_verifier(p.pub_key):
-                            self._stats[f"scheme_{scheme}_sigs"] += 1
+                local.route = "verifyd"
                 return verdicts
-        results = [False] * len(batch)
-        # scheme partitions in deterministic order (dict preserves
-        # first-seen insertion; verdicts are order-independent anyway)
-        groups: dict[str, list[int]] = {}
-        for i, p in enumerate(batch):
-            if supports_batch_verifier(p.pub_key):
-                scheme = "bls" if p.pub_key.TYPE == "bls12381" else "edwards"
-                groups.setdefault(scheme, []).append(i)
-            else:
-                results[i] = p.pub_key.verify_signature(p.msg, p.sig)
-        # where this batch ran, for the dispatch/execute spans: set per
-        # worker thread (concurrent _run_batch calls must not race), and
-        # "cpu" on the host-side paths where no AdaptiveBatchVerifier runs
-        self._route_local.route = "cpu"
-        self._route_local.dispatch = None
-        if groups:
-            with self._cv:
-                for scheme, idxs in groups.items():
-                    self._stats[f"scheme_{scheme}_sigs"] += len(idxs)
-        for scheme, idxs in groups.items():
-            if len(idxs) == 1:
-                p = batch[idxs[0]]
-                results[idxs[0]] = p.pub_key.verify_signature(p.msg, p.sig)
-                continue
-            bv = create_batch_verifier(batch[idxs[0]].pub_key)
-            if len(idxs) > self._effective_max():
-                # more rows than lone requests ever make: a group went
-                # out whole. ONE device shape for every such size, the
-                # program start-up warmed
-                bv.whole_chunk = True
-            for i in idxs:
-                p = batch[i]
-                bv.add(p.pub_key, p.msg, p.sig)
-            _ok, bitmap = bv.verify()
-            route = getattr(bv, "last_route", "cpu")
-            if route != "cpu" or len(groups) == 1:
-                # prefer the device partition's tag on the span: a mixed
-                # dispatch that reached the device should read as such
-                self._route_local.route = route
-                self._route_local.dispatch = getattr(bv, "last_dispatch", None)
-            for i, good in zip(idxs, bitmap):
-                results[i] = bool(good)
-        return results
+        if len(batch) == 1:
+            p = batch[0]
+            return [p.pub_key.verify_signature(p.msg, p.sig)]
+        bv = AdaptiveBatchVerifier()
+        if len(batch) > self._effective_max():
+            # more rows than lone requests ever make: a group went out
+            # whole. ONE device shape for every such size, the program
+            # start-up warmed
+            bv.whole_chunk = True
+        bv.add_many([(p.pub_key, p.msg, p.sig) for p in batch])
+        _ok, bitmap = bv.verify()
+        # "mixed" where the verifier's partitions took different routes
+        local.route, local.dispatch = bv.last_route, bv.last_dispatch
+        return [bool(good) for good in bitmap]
 
 
 # -- process-wide hub ------------------------------------------------------

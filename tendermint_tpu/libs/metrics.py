@@ -498,8 +498,9 @@ class NodeMetrics:
             "lane_promotions",
             "queued backfill entries pulled into the live lane by a live coalesce",
         )
-        # scheme-partitioned dispatch (ed25519/sr25519 share the Edwards
-        # kernel; bls12381 runs the pairing path — never one dispatch)
+        # dispatched rows by the verifier's partition (ed25519/sr25519
+        # share the Edwards kernel; bls12381 runs the pairing path; a key
+        # type with no batch kernel — secp256k1 — takes the host lane)
         self.verifyhub_scheme_sigs = r.counter(
             "verifyhub",
             "scheme_sigs",
@@ -747,7 +748,7 @@ class NodeMetrics:
             ]
             self.verifyhub_lane_queued.set(s[f"lane_{lane}_queued"], lane=lane)
         self.verifyhub_lane_promotions._values[()] = s["lane_promotions"]
-        for scheme in ("edwards", "bls"):
+        for scheme in ("edwards", "bls", "host"):
             self.verifyhub_scheme_sigs._values[(("scheme", scheme),)] = s[
                 f"scheme_{scheme}_sigs"
             ]

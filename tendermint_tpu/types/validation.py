@@ -49,7 +49,7 @@ class _CommitVerifier:
     launches. `lane` picks the hub scheduler lane: block-sync /
     state-sync / light-client callers submit as "backfill" so bulk
     catch-up ranges never starve live consensus. Rows of any key type:
-    the hub and the AdaptiveBatchVerifier both partition by scheme."""
+    the AdaptiveBatchVerifier partitions by scheme, behind the hub too."""
 
     def __init__(self, lane: str = "live"):
         self._lane = lane

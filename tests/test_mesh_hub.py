@@ -306,13 +306,13 @@ def test_hub_dispatch_span_carries_shards(monkeypatch):
         def __init__(self):
             self._items = []
 
-        def add(self, pk, msg, sig):
-            self._items.append((pk, msg, sig))
+        def add_many(self, items):
+            self._items.extend(items)
 
         def verify(self):
             return True, [True] * len(self._items)
 
-    monkeypatch.setattr(vh, "create_batch_verifier", lambda pk: FakeBV())
+    monkeypatch.setattr(vh, "AdaptiveBatchVerifier", FakeBV)
     old = trace.RECORDER.enabled
     trace.RECORDER.enabled = True
     trace.RECORDER.clear()

@@ -310,6 +310,60 @@ class TestBlockSyncTree:
         assert after["slot_wait_s"] >= before["slot_wait_s"]
 
     @pytest.mark.asyncio
+    async def test_a_mixed_range_puts_the_host_lane_under_its_dispatch(self, recorder):
+        """A 4 ed25519 + 3 secp256k1 committee behind the hub: the range's
+        ONE dispatch holds both key types, goes to one verifier and reads
+        `mixed`; the lane's two rows sit under `hub.dispatch`, around
+        `batch.route`, and `tracectl` prints them there."""
+        from benchmark import fixtures_mixedfull
+
+        chain = await fixtures_mixedfull.kvstore_chain(
+            SEED, "trmixed", 70, 7, POWER, 2, ("ed25519", "secp256k1"))
+        cell = {"traffic": {"peers": 4, "window": 64, "trace_seconds": 0.1}}
+        trace.RECORDER.clear()
+        hub = vh.acquire_hub(max_batch=512, window_ms=2.0, cache_size=8192)
+        try:
+            s = await bs_driver._sync(chain, cell, 60.0, harness.Spans())
+            stats = hub.stats()
+        finally:
+            vh.release_hub()
+        assert s.final_height >= 64 and not s.refused
+        spans = recorder.dump()
+        root = next(x for x in spans if _key(x) == "blocksync.range")
+        mine = [x for x in spans if x["trace_id"] == root["trace_id"]]
+        ids = _by_id(mine)
+        tree = dict(RANGE_TREE, **{"batch.host_lane": "hub.dispatch",
+                                   "batch.host_lane_wait": "hub.dispatch"})
+        assert {_key(x) for x in mine} == set(tree) | {"blocksync.range"}
+        for x in mine:
+            if x is not root:
+                assert _key(ids[x["parent_id"]]) == tree[_key(x)], _key(x)
+        n = root["attrs"]["n"]
+        one = {k: next(x for x in mine if _key(x) == k) for k in (
+            "validation.collect", "hub.dispatch", "hub.execute", "batch.route",
+            "batch.host_lane", "batch.host_lane_wait")}
+        assert one["validation.collect"]["attrs"]["edwards"] == 2 * n  # the quorum: 2 + 3
+        assert one["validation.collect"]["attrs"]["host"] == 3 * n
+        assert one["hub.dispatch"]["attrs"]["sigs"] == 5 * n
+        assert one["hub.dispatch"]["attrs"]["host_rows"] == 3 * n
+        assert one["hub.dispatch"]["attrs"]["route"] == one["hub.execute"]["attrs"]["route"] == "mixed"
+        assert one["batch.route"]["attrs"]["n"] == 2 * n
+        assert one["batch.route"]["attrs"]["partitions"] == 2
+        assert one["batch.host_lane"]["attrs"] == {
+            "n": 3 * n, "scheme": "secp256k1", "workers": one["batch.host_lane"]["attrs"]["workers"]}
+        assert one["batch.host_lane"]["start_s"] <= one["batch.route"]["start_s"]
+        assert _inside(one["batch.host_lane_wait"], one["batch.host_lane"])
+        # a group's rows are counted once, under the lane they were submitted on
+        assert stats["lane_backfill_dispatched"] == stats["dispatched_sigs"] == stats["submitted"]
+        assert stats["scheme_host_sigs"] * 5 == stats["dispatched_sigs"] * 3
+        out = _tracectl().render_trace(spans, root["trace_id"]).splitlines()
+        at = {k: next(i for i, ln in enumerate(out) if k in ln) for k in (
+            "hub.dispatch", "batch.route", "batch.host_lane ", "batch.host_lane_wait")}
+        col = {k: out[i].index(k) for k, i in at.items()}  # a child is indented under its parent
+        assert all(at[k] > at["hub.dispatch"] and col[k] > col["hub.dispatch"]
+                   for k in at if k != "hub.dispatch")
+
+    @pytest.mark.asyncio
     async def test_same_seed_sync_identical_with_tracing_on_vs_off(self, recorder):
         _chain, on = await _tiny_sync(40)
         recorder.enabled = False

@@ -136,10 +136,10 @@ class TestScheduling:
         assert h.submit_nowait(pub, msg, sig).result(1.0) is True
 
     def test_verifier_exception_fails_batch_futures(self, hub, monkeypatch):
-        def boom(_pk):
+        def boom():
             raise RuntimeError("verifier construction exploded")
 
-        monkeypatch.setattr(vh, "create_batch_verifier", boom)
+        monkeypatch.setattr(vh, "AdaptiveBatchVerifier", boom)
         futs = [hub.submit_nowait(pk, m, s) for pk, m, s in _items(3, b"err")]
         hub.flush()
         for f in futs:
@@ -385,10 +385,10 @@ class TestGroups:
     ):
         from tendermint_tpu.types import validation
 
-        def boom(_pk):
+        def boom():
             raise RuntimeError("verifier construction exploded")
 
-        monkeypatch.setattr(vh, "create_batch_verifier", boom)
+        monkeypatch.setattr(vh, "AdaptiveBatchVerifier", boom)
         items = _items(12, b"grp-err")
         with pytest.raises(RuntimeError, match="exploded"):
             process_hub.verify_many(items, lane="backfill")
@@ -408,12 +408,15 @@ class TestGroups:
             hub.verify_many(_items(2, b"lane"), lane="backfil")
 
     @pytest.mark.parametrize(
-        "rows, bucket, groups",
-        [(70, 128, 63), (100, 128, 63), (101, 512, 255), (150, 512, 255), (400, 512, 255),
-         (1100, 512, 255)],
+        "rows, bucket, groups, ecdsa",
+        [(70, 128, 63, 0), (100, 128, 63, 0), (101, 512, 255, 0), (150, 512, 255, 0),
+         (400, 512, 255, 0), (1100, 512, 255, 0),
+         # the DISPATCH is over max_batch, whatever its key types: the Edwards
+         # rows of a mixed group go out at the chunk shape beside the host lane
+         (70, 512, 255, 40), (60, 64, 63, 30)],
     )
     def test_a_dispatch_past_max_batch_goes_out_at_the_chunk_shape(
-        self, monkeypatch, rows, bucket, groups
+        self, monkeypatch, rows, bucket, groups, ecdsa
     ):
         """≤ max_batch rows: the ladder rung of the row count and the
         keys' own group bucket, as lone requests always went. More: ONE
@@ -434,12 +437,17 @@ class TestGroups:
         h = VerifyHub(max_batch=100, window_ms=1.0, cache_size=0)
         h.start()
         try:
-            assert all(h.verify_many(_items(rows, b"shape-%d" % rows), lane="backfill"))
+            items = _items(rows, b"shape-%d" % rows) + _mixed_rows(
+                "s" * ecdsa, b"shape-%d" % rows)
+            assert all(h.verify_many(items, lane="backfill"))
             assert h.stats()["dispatches"] == 1
+            assert h.stats()["scheme_host_sigs"] == ecdsa
+            routed = {k: v[1] for k, v in bt.ROUTES.items() if v[1]}
         finally:
             h.stop()
             bt.reset()
         assert shapes == [(bucket, groups)] * -(-rows // 512), shapes
+        assert routed == {"tpu": rows, **({"host-ecdsa": ecdsa} if ecdsa else {})}
 
 
 class TestFallbackIdentity:
@@ -578,3 +586,121 @@ class TestLiveConsensusCacheHits:
         finally:
             await net.stop()
         assert vh.running_hub() is None  # last node released the hub
+
+
+# -- dispatches that mix key types: ONE verifier a dispatch, its own partition ----
+
+
+def _mixed_rows(spec, tag):
+    """Rows from a spec string: `e` an ed25519 row, `s` a secp256k1 row, an
+    upper-case letter the same with one signature bit flipped, a digit a
+    repeat of the row at that index (the same triple again)."""
+    from tendermint_tpu.crypto.secp256k1 import Secp256k1PrivKey
+
+    keys = {"e": Ed25519PrivKey(b"\x21" * 32), "s": Secp256k1PrivKey(b"\x22" * 32)}
+    rows = []
+    for i, c in enumerate(spec):
+        if c.isdigit():
+            rows.append(rows[int(c)])
+            continue
+        priv = keys[c.lower()]
+        msg = b"%s-%d" % (tag, i)
+        sig = priv.sign(msg)
+        if c.isupper():
+            sig = sig[:7] + bytes([sig[7] ^ 0x10]) + sig[8:]
+        rows.append((priv.pub_key(), msg, sig))
+    return rows
+
+
+MIXED_SHAPES = {
+    # name: (rows, how they are submitted, ECDSA rows the verifier's lane takes)
+    "one ecdsa row alone": ("s", "group", 0),  # a dispatch of ONE row verifies directly
+    "one edwards and one ecdsa": ("es", "group", 1),
+    "lone requests under max_batch": ("esesse", "lone", 3),
+    "a group over max_batch": ("es" * 10, "group", 10),
+    "all ecdsa": ("s" * 12, "group", 12),
+    "a corrupted edwards row": ("esEsse", "group", 3),
+    "a corrupted ecdsa row": ("eseSse", "group", 3),
+    "a duplicate triple of each scheme": ("esse01es", "group", 3),
+}
+
+
+class TestMixedDispatch:
+    @pytest.mark.parametrize("shape", sorted(MIXED_SHAPES))
+    def test_a_mixed_dispatch_goes_to_one_verifier_with_its_host_lane(self, shape, monkeypatch):
+        from tendermint_tpu.crypto import backend_telemetry as bt
+        from tendermint_tpu.crypto.secp256k1 import Secp256k1PubKey
+        from tendermint_tpu.libs import trace
+
+        spec, how, lane_rows = MIXED_SHAPES[shape]
+        items = _mixed_rows(spec, shape.replace(" ", "_").encode())
+        want = [pk.verify_signature(m, s) for pk, m, s in items]
+        assert want == [not c.isupper() for c in spec.replace("0", "e").replace("1", "s")]
+        cold_ecdsa = len({it for it in items if it[0].TYPE == "secp256k1"})
+        verifies = []
+        real = Secp256k1PubKey.verify_signature
+        monkeypatch.setattr(
+            Secp256k1PubKey, "verify_signature",
+            lambda self, m, s: (verifies.append(1), real(self, m, s))[1])
+        old = trace.RECORDER.enabled
+        trace.RECORDER.enabled = True
+        trace.RECORDER.clear()
+        bt.reset()
+        h = VerifyHub(max_batch=8, window_ms=100.0, cache_size=256, adaptive=False)
+        h.start()
+        try:
+            if how == "lone":
+                got = [f.result(10.0) for f in [h.submit_nowait(*it) for it in items]]
+            else:
+                got = h.verify_many(items, lane="backfill")
+            s = h.stats()
+            spans = trace.RECORDER.dump()
+            # the verdict LRU answers the same rows again: no second verify
+            assert h.verify_many(items) == want and len(verifies) == cold_ecdsa
+            assert h.stats()["dispatches"] == s["dispatches"] == 1
+            lane_routed = bt.ROUTES.get("host-ecdsa", [0, 0])[1]
+        finally:
+            h.stop()
+            trace.RECORDER.enabled = old
+            bt.reset()
+        assert got == want  # row by row, in the caller's order
+        assert len(verifies) == cold_ecdsa  # a repeated triple coalesced onto its first
+        assert s["scheme_host_sigs"] == cold_ecdsa and s["scheme_bls_sigs"] == 0
+        assert s["scheme_edwards_sigs"] == s["dispatched_sigs"] - cold_ecdsa
+        assert s["dispatched_sigs"] == len(set(items)) == s["lane_backfill_dispatched"] + (
+            s["lane_live_dispatched"])
+        assert lane_routed == lane_rows
+        by = {}
+        for x in spans:
+            by.setdefault(f"{x['subsystem']}.{x['name']}", []).append(x)
+        (dispatch,) = by["hub.dispatch"]
+        assert dispatch["attrs"].get("host_rows", 0) == cold_ecdsa
+        if not lane_rows:
+            assert "batch.host_lane" not in by
+            return
+        (lane,), (wait,) = by["batch.host_lane"], by["batch.host_lane_wait"]
+        assert lane["attrs"]["n"] == wait["attrs"]["n"] == lane_rows
+        assert lane["parent_id"] == wait["parent_id"] == dispatch["span_id"]
+        end = lambda x: x["start_s"] + x["duration_ms"] / 1e3  # noqa: E731
+        if len(set(items)) > lane_rows:
+            # started before the Edwards partition is routed, joined after it
+            (route,) = by["batch.route"]
+            assert route["parent_id"] == dispatch["span_id"]
+            assert lane["start_s"] <= route["start_s"] and end(lane) >= end(route)
+            assert wait["start_s"] >= end(route) - 1e-6
+            assert dispatch["attrs"]["route"] == "mixed"
+        else:
+            assert "batch.route" not in by and dispatch["attrs"]["route"] == "host-ecdsa"
+
+    @pytest.mark.parametrize("how", ["stopped", "re-entrant"])
+    def test_a_stopped_hub_and_a_re_entrant_call_verify_inline(self, how):
+        items = _mixed_rows("esSe", how.encode())
+        h = VerifyHub(max_batch=8, window_ms=1.0, cache_size=0)
+        if how == "re-entrant":
+            h.start()
+            h._worker_ids.add(threading.get_ident())  # as if called from the runner
+        try:
+            assert h.verify_many(items, timeout=5.0) == [True, True, False, True]
+            assert h.stats()["dispatches"] == 0
+        finally:
+            h.stop()
