@@ -25,9 +25,15 @@ class PubKey(abc.ABC):
     def verify_signature(self, msg: bytes, sig: bytes) -> bool: ...
 
     def address(self) -> bytes:
-        from .hashes import address
+        """SHA-256(key bytes)[:20], derived on first use and kept on the
+        key: a key's bytes are set once, and validator sets share key
+        objects across copies, so a set pays one derivation a key."""
+        addr = self.__dict__.get("_address")
+        if addr is None:
+            from .hashes import address
 
-        return address(self.bytes())
+            addr = self.__dict__["_address"] = address(self.bytes())
+        return addr
 
     def __eq__(self, other) -> bool:
         return (

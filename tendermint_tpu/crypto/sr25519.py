@@ -37,7 +37,6 @@ import os
 
 from . import ed25519_math as em
 from . import PubKey, PrivKey, register_pubkey_type
-from .hashes import sha256
 
 KEY_TYPE = "sr25519"
 
@@ -391,9 +390,6 @@ class Sr25519PubKey(PubKey):
 
     def bytes(self) -> bytes:
         return self._data
-
-    def address(self) -> bytes:
-        return sha256(self._data)[:20]
 
     def verify_signature(self, msg: bytes, sig: bytes) -> bool:
         return verify(self._data, msg, sig)

@@ -184,8 +184,7 @@ class BlockExecutor:
         with trace.span("state", "validate"):
             self.validate_block(state, block, commit_verified=commit_verified)
 
-        with trace.span("state", "exec", txs=len(block.txs)):
-            responses = await self._exec_block(state, block)
+        responses = await self._exec_block(state, block)
         # crash points 4-5 mirror execution.go:170-217's fail.Fail sites
         fail.fail_point(4)  # block executed, before persisting responses
         with trace.span("state", "save_responses"):
@@ -229,42 +228,55 @@ class BlockExecutor:
 
     async def _exec_block(self, state: State, block: Block) -> ABCIResponses:
         """BeginBlock → DeliverTx×N → EndBlock (reference
-        execBlockOnProxyApp execution.go:293)."""
-        last_vals = None
-        if block.header.height > state.initial_height:
-            # prefer the historical set from the store: during handshake
-            # replay `state` is the tip state, whose last_validators need
-            # not be the set that signed this block's LastCommit
-            last_vals = self.state_store.load_validators(block.header.height - 1)
-            if last_vals is None:
-                last_vals = state.last_validators
-        res_begin = await self.app.begin_block(
-            abci.RequestBeginBlock(
-                hash=block.hash(),
-                header=block.header,
-                last_commit_info=build_last_commit_info(
-                    block, last_vals, state.initial_height
-                ),
-                byzantine_validators=evidence_to_misbehavior(
-                    block.evidence, block.header.time_ns
-                ),
+        execBlockOnProxyApp execution.go:293), under the flight recorder's
+        `state.exec` [txs, last_vals]."""
+        with trace.span("state", "exec", txs=len(block.txs)) as span:
+            last_vals = None
+            if block.header.height > state.initial_height:
+                # The set that signed this block's LastCommit: the state's
+                # last_validators when the state is one height behind the
+                # block (apply: consensus, block-sync, replay from
+                # genesis); the store's otherwise — during handshake
+                # replay `state` is the tip state, whose last_validators
+                # is a later set.
+                if state.last_block_height == block.header.height - 1:
+                    last_vals = state.last_validators
+                    span.set(last_vals="state")
+                else:
+                    last_vals = self.state_store.load_validators(
+                        block.header.height - 1
+                    )
+                    span.set(last_vals="store")
+                    if last_vals is None:
+                        last_vals = state.last_validators
+            res_begin = await self.app.begin_block(
+                abci.RequestBeginBlock(
+                    hash=block.hash(),
+                    header=block.header,
+                    last_commit_info=build_last_commit_info(
+                        block, last_vals, state.initial_height
+                    ),
+                    byzantine_validators=evidence_to_misbehavior(
+                        block.evidence, block.header.time_ns
+                    ),
+                )
             )
-        )
-        deliver: list[abci.ResponseDeliverTx] = []
-        invalid = 0
-        for tx in block.txs:
-            res = await self.app.deliver_tx(abci.RequestDeliverTx(tx))
-            if not res.is_ok():
-                invalid += 1
-            deliver.append(res)
-        res_end = await self.app.end_block(
-            abci.RequestEndBlock(block.header.height)
-        )
-        if invalid:
-            self.logger.info(
-                "executed block height=%d invalid_txs=%d", block.header.height, invalid
+            deliver: list[abci.ResponseDeliverTx] = []
+            invalid = 0
+            for tx in block.txs:
+                res = await self.app.deliver_tx(abci.RequestDeliverTx(tx))
+                if not res.is_ok():
+                    invalid += 1
+                deliver.append(res)
+            res_end = await self.app.end_block(
+                abci.RequestEndBlock(block.header.height)
             )
-        return ABCIResponses(tuple(deliver), res_end, res_begin)
+            if invalid:
+                self.logger.info(
+                    "executed block height=%d invalid_txs=%d",
+                    block.header.height, invalid,
+                )
+            return ABCIResponses(tuple(deliver), res_end, res_begin)
 
     def _update_state(
         self,

@@ -96,6 +96,10 @@ class BlockStore:
         return self._height
 
     def save_block(self, block: Block, part_set: PartSet, seen_commit: Commit) -> None:
+        """Persist a block as its parts, its meta, its seen commit and the
+        canonical commit of the height before. The block's size in the
+        meta is the length of `block.encode()`, which the block keeps: the
+        bytes `part_set` was cut from, not a second serialisation."""
         height = block.header.height
         with self._lock:
             if self._height and height != self._height + 1:

@@ -260,30 +260,51 @@ class Commit:
         feeding the TPU batch verifier."""
         return self.sign_bytes(chain_id)(idx)
 
+    def _sig_encodings(self) -> tuple[bytes, ...]:
+        """`CommitSig.encode()` of every signature, made once per Commit
+        (memoized on the frozen instance like Header.hash()): hash()
+        merkles them and encode() frames them, and one block's apply asks
+        for both (the block's part set, the block store's seen and
+        canonical commits, validate_basic's last_commit_hash)."""
+        cached = self.__dict__.get("_sig_bytes")
+        if cached is None:
+            cached = tuple(cs.encode() for cs in self.signatures)
+            self.__dict__["_sig_bytes"] = cached
+        return cached
+
     def hash(self) -> bytes:
-        leaves = [cs.encode() for cs in self.signatures]
-        if self.agg_sig:
-            # the aggregate is commit content: two commits differing
-            # only in agg_sig must hash differently
-            leaves.append(self.agg_sig)
-        return merkle.hash_from_byte_slices(leaves)
+        cached = self.__dict__.get("_hash")
+        if cached is None:
+            leaves = self._sig_encodings()
+            if self.agg_sig:
+                # the aggregate is commit content: two commits differing
+                # only in agg_sig must hash differently
+                leaves = (*leaves, self.agg_sig)
+            cached = self.__dict__["_hash"] = merkle.hash_from_byte_slices(leaves)
+        return cached
 
     def size(self) -> int:
         return len(self.signatures)
 
     def encode(self) -> bytes:
+        """Wire bytes, memoized on the frozen instance: the fields can't
+        change, so the bytes can't (dataclasses.replace builds a new
+        Commit and with it a new memo)."""
+        cached = self.__dict__.get("_encoded")
+        if cached is not None:
+            return cached
         parts = [
             pe.sfixed64_field(1, self.height),
             pe.sfixed64_field(2, self.round),
             pe.message_field(3, self.block_id.encode()),
         ]
         add = parts.append
-        for cs in self.signatures:
-            e = cs.encode()
+        for e in self._sig_encodings():
             add(_COMMIT_SIG + _uvarint(len(e)) + e)
         if self.agg_sig:
             add(pe.bytes_field(5, self.agg_sig))
-        return b"".join(parts)
+        cached = self.__dict__["_encoded"] = b"".join(parts)
+        return cached
 
     @classmethod
     def decode(cls, data: bytes) -> "Commit":
@@ -567,6 +588,12 @@ class Block:
         return PartSet.from_data(self.encode(), part_size or BLOCK_PART_SIZE)
 
     def encode(self) -> bytes:
+        """Wire bytes, memoized on the frozen block like txs_hash(): the
+        part set is cut from them and the block store takes the block's
+        size from them."""
+        cached = self.__dict__.get("_encoded")
+        if cached is not None:
+            return cached
         parts = [pe.message_field(1, self.header.encode())]
         add = parts.append
         for tx in self.txs:
@@ -575,7 +602,8 @@ class Block:
             add(pe.message_field(3, self.last_commit.encode()))
         for ev in self.evidence:
             add(pe.message_field(4, ev.encode()))
-        return b"".join(parts)
+        cached = self.__dict__["_encoded"] = b"".join(parts)
+        return cached
 
     @classmethod
     def decode(cls, data: bytes) -> "Block":

@@ -229,6 +229,9 @@ def _commit(tag, n, height=7, round_=1, agg=False, flags=None) -> Commit:
 MIXED = (BLOCK_ID_FLAG_COMMIT, BLOCK_ID_FLAG_COMMIT, BLOCK_ID_FLAG_NIL,
          BLOCK_ID_FLAG_COMMIT, BLOCK_ID_FLAG_ABSENT)
 
+#: what a Commit keeps of its own serialisation since ISSUE 42
+COMMIT_MEMOS = {"_sig_bytes", "_encoded", "_hash"}
+
 COMMITS = {
     "no-sigs": _commit("c0", 0),
     "one-sig": _commit("c1", 1),
@@ -555,10 +558,11 @@ def test_commit_sign_bytes_builds_a_template_per_flag_when_first_needed(monkeypa
     for idx in range(150):
         sign_bytes(idx)
     assert len(made) == 2
-    # nothing is kept on the commit: the next loop builds its own
+    # nothing of it is kept on the commit: the next loop builds its own
+    # (what a commit does keep since ISSUE 42 is its own serialisation)
     c.sign_bytes("test-chain")(0)
     assert len(made) == 3
-    assert set(vars(c)) == {"height", "round", "block_id", "signatures", "agg_sig"}
+    assert set(vars(c)) - COMMIT_MEMOS == {"height", "round", "block_id", "signatures", "agg_sig"}
 
 
 # -- the collect loops of types/validation -----------------------------------------
@@ -797,10 +801,13 @@ def _light_block(n_vals=150) -> LightBlock:
     return LightBlock(SignedHeader(header, commit), vals)
 
 
-def test_a_fresh_light_block_saved_twice_costs_the_same(monkeypatch):
+def test_a_light_block_keeps_its_commit_s_bytes_and_a_fresh_one_pays_again(monkeypatch):
+    """Since ISSUE 42 a Commit OBJECT keeps its serialisation (a block's
+    apply asks for it five times); nothing is keyed by value, so the next
+    answer a provider sends — the same bytes, decoded into new objects —
+    is serialised in full again, and a validator set keeps nothing."""
     lb = _light_block()
-    fresh = LightBlock.decode(lb.encode())  # what a provider's answer decodes to
-    assert fresh is not lb and fresh.encode() == lb.encode()
+    raw = lb.encode()
     calls = {"sig": 0, "val": 0}
     real_sig, real_val = CommitSig.encode, Validator.encode
 
@@ -818,23 +825,30 @@ def test_a_fresh_light_block_saved_twice_costs_the_same(monkeypatch):
     def state(obj):
         return dict(vars(obj))
 
-    commit = fresh.signed_header.commit
-    before = (state(fresh), state(commit), state(fresh.validators),
-              [state(cs) for cs in commit.signatures],
-              [state(v) for v in fresh.validators.validators])
     store = TrustedStore()
-    per_save = []
     for _ in range(2):
-        calls.update(sig=0, val=0)
-        store.save(fresh)
-        per_save.append(dict(calls))
-    # every element encoded again on the second save: no memo of the bytes
-    assert per_save == [{"sig": 150, "val": 150}] * 2
-    after = (state(fresh), state(commit), state(fresh.validators),
-             [state(cs) for cs in commit.signatures],
-             [state(v) for v in fresh.validators.validators])
-    assert after == before  # and nothing hung on any object
-    assert store.get(7).encode() == lb.encode()
+        fresh = LightBlock.decode(raw)  # what a provider's answer decodes to
+        commit = fresh.signed_header.commit
+        before = (state(fresh), state(commit), state(fresh.validators),
+                  [state(cs) for cs in commit.signatures],
+                  [state(v) for v in fresh.validators.validators])
+        per_save = []
+        for _ in range(2):
+            calls.update(sig=0, val=0)
+            store.save(fresh)
+            per_save.append(dict(calls))
+        # the first save of a fresh answer pays for every element; the second
+        # finds the commit's bytes kept, the set's encoded again
+        assert per_save == [{"sig": 150, "val": 150}, {"sig": 0, "val": 150}]
+        after = (state(fresh), state(commit), state(fresh.validators),
+                 [state(cs) for cs in commit.signatures],
+                 [state(v) for v in fresh.validators.validators])
+        # nothing hung on any object but the commit's own serialisation
+        kept = after[1]
+        assert set(kept) - set(before[1]) <= COMMIT_MEMOS
+        assert after[0] == before[0] and after[2:] == before[2:]
+        assert {k: kept[k] for k in before[1]} == before[1]
+        assert store.get(7).encode() == raw
 
 
 def test_the_encoders_keep_no_cache_by_value():
