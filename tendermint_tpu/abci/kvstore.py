@@ -8,7 +8,17 @@ validator-set change at EndBlock (power 0 removes; bls12381 joins must
 carry a valid proof of possession or the tx is rejected). App hash is
 the SHA-256 of the deterministic encoding of the full kv state, so two
 replicas agree iff their states agree. Snapshots serialize the state into
-fixed-size chunks keyed by (height, format, chunk)."""
+fixed-size chunks keyed by (height, format, chunk).
+
+On its DB the app keeps WHAT A BLOCK CHANGED (as the reference's
+persistent_kvstore does): a row per pair under `kv:`, a row per validator
+under `val:`, and one small state record (height, app hash, size). A
+commit writes the block's staged pairs, its validator changes and the
+record in ONE unsynced batch — bytes that do not grow with the state. A
+crash may take the last commits back; the handshake's replay
+(`consensus/replay.Handshaker`) brings the app up to the block store again.
+A DB written by the earlier whole-state-as-one-JSON-value `_save` is still
+read, and rewritten in rows at once."""
 
 from __future__ import annotations
 
@@ -24,6 +34,10 @@ SNAPSHOT_CHUNK_SIZE = 65536
 SNAPSHOT_FORMAT = 1
 
 _STATE_KEY = b"__kvstore_state__"
+_KV = b"kv:"
+_VAL = b"val:"
+#: the first key after every key that starts with `prefix` (`:` + 1 = `;`)
+_KV_END, _VAL_END = b"kv;", b"val;"
 
 
 def _sorted_leaves(items: dict[bytes, bytes]) -> list[bytes]:
@@ -76,25 +90,58 @@ class KVStoreApp(BaseApplication):
         if raw is None:
             return
         d = json.loads(raw)
-        self.items = {bytes.fromhex(k): bytes.fromhex(v) for k, v in d["items"].items()}
         self.height = d["height"]
         self.app_hash = bytes.fromhex(d["app_hash"])
+        if "items" in d:  # the earlier format: the whole state in the record
+            self.items = {
+                bytes.fromhex(k): bytes.fromhex(v) for k, v in d["items"].items()
+            }
+            self.validators = {
+                bytes.fromhex(k): p for k, p in d.get("validators", {}).items()
+            }
+            self._save()
+            return
+        self.items = {k[len(_KV):]: v for k, v in self.db.iterate(_KV, _KV_END)}
         self.validators = {
-            bytes.fromhex(k): p for k, p in d.get("validators", {}).items()
+            k[len(_VAL):]: int(v) for k, v in self.db.iterate(_VAL, _VAL_END)
         }
 
+    def _state_record(self) -> tuple[bytes, bytes]:
+        return _STATE_KEY, json.dumps(
+            {
+                "height": self.height,
+                "app_hash": self.app_hash.hex(),
+                "size": len(self.items),
+            }
+        ).encode()
+
     def _save(self) -> None:
-        self.db.set(
-            _STATE_KEY,
-            json.dumps(
-                {
-                    "items": {k.hex(): v.hex() for k, v in self.items.items()},
-                    "height": self.height,
-                    "app_hash": self.app_hash.hex(),
-                    "validators": {k.hex(): p for k, p in self.validators.items()},
-                }
-            ).encode(),
-        )
+        """The whole state, in rows: at InitChain, after a snapshot
+        restore, and once over a DB in the earlier format. Rows the state
+        no longer holds go in the same batch."""
+        sets = [(_KV + k, v) for k, v in self.items.items()]
+        sets += [(_VAL + k, str(p).encode()) for k, p in self.validators.items()]
+        sets.append(self._state_record())
+        keep = {k for k, _v in sets}
+        stale = [
+            k
+            for lo, hi in ((_KV, _KV_END), (_VAL, _VAL_END))
+            for k, _v in self.db.iterate(lo, hi)
+            if k not in keep
+        ]
+        self.db.write_batch(sets, stale)
+
+    def _save_changes(self) -> None:
+        """What this block changed, and the record: one batch a commit."""
+        sets = [(_KV + k, v) for k, v in self._staged.items()]
+        deletes = []
+        for pk in {vu.pub_key for vu in self._val_updates}:
+            if pk in self.validators:
+                sets.append((_VAL + pk, str(self.validators[pk]).encode()))
+            else:
+                deletes.append(_VAL + pk)
+        sets.append(self._state_record())
+        self.db.write_batch(sets, deletes)
 
     # -- info/query -------------------------------------------------------
 
@@ -201,10 +248,11 @@ class KVStoreApp(BaseApplication):
 
     def commit(self):
         self.items.update(self._staged)
-        self._staged = {}
         self._proof_cache = None
         self.app_hash = _state_hash(self.items)
-        self._save()
+        self._save_changes()
+        self._staged = {}
+        self._val_updates = []
         self._take_snapshot()
         retain = 0
         if self.retain_blocks and self.height >= self.retain_blocks:

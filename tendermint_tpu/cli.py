@@ -102,7 +102,7 @@ def _build_node(home: str):
     from .node import Node, NodeConfig
     from .p2p.tcp import TCPTransport
     from .statesync.reactor import SyncConfig
-    from .store.db import SQLiteDB
+    from .store.db import SQLiteDB, open_node_stores
 
     p = _paths(home)
     with open(p["config_toml"]) as f:
@@ -116,8 +116,9 @@ def _build_node(home: str):
         if os.path.exists(p["pv_key"])
         else None
     )
+    stores = open_node_stores(p["data"], app=cfg.proxy_app == "kvstore")
     if cfg.proxy_app == "kvstore":
-        app = KVStoreApp(SQLiteDB(os.path.join(p["data"], "app.db")))
+        app = KVStoreApp(stores.app_db)
     elif cfg.proxy_app.startswith(("tcp://", "grpc://")):
         # out-of-process app (reference config proxy_app semantics:
         # tcp://host:port = socket ABCI, grpc://host:port = gRPC ABCI)
@@ -188,8 +189,8 @@ def _build_node(home: str):
         node_key,
         [transport],
         priv_validator=pv,
-        block_db=SQLiteDB(os.path.join(p["data"], "blockstore.db")),
-        state_db=SQLiteDB(os.path.join(p["data"], "state.db")),
+        block_db=stores.block_db,
+        state_db=stores.state_db,
         evidence_db=SQLiteDB(os.path.join(p["data"], "evidence.db")),
         index_db=SQLiteDB(os.path.join(p["data"], "tx_index.db")),
     )
@@ -256,17 +257,16 @@ def cmd_replay(args) -> int:
         from .state.state import state_from_genesis
         from .state.store import StateStore
         from .store.blockstore import BlockStore
-        from .store.db import MemDB, SQLiteDB
+        from .store.db import MemDB, open_node_stores
         from .types.genesis import GenesisDoc
 
         p = _paths(_home(args))
         # tmtlint: allow[blocking-in-async] -- one-shot CLI startup read; nothing else is on the loop yet
         with open(p["genesis"]) as f:
             genesis = GenesisDoc.from_json(f.read())
-        block_store = BlockStore(SQLiteDB(os.path.join(p["data"], "blockstore.db")))
-        stored = StateStore(
-            SQLiteDB(os.path.join(p["data"], "state.db"))
-        ).load()
+        stores = open_node_stores(p["data"], app=False)
+        block_store = BlockStore(stores.block_db)
+        stored = StateStore(stores.state_db).load()
         # re-execute from GENESIS state (height 0) against a fresh
         # in-memory app AND a scratch state store: the replay rebuilds the
         # whole state chain from the block store without ever writing to

@@ -14,7 +14,7 @@ import logging
 from ..abci import types as abci
 from ..proxy import AppConns
 from ..state.execution import BlockExecutor, validator_updates_to_validators
-from ..state.state import State
+from ..state.state import State, state_from_genesis
 from ..state.store import StateStore
 from ..store.blockstore import BlockStore
 from ..types.genesis import GenesisDoc
@@ -90,8 +90,15 @@ class Handshaker:
         store_base = self.block_store.base()
         state_height = state.last_block_height
 
-        # 1. fresh chain → InitChain (reference replay.go:285 region)
-        if app_height == 0 and state_height == 0:
+        # 1. an app at height 0 gets InitChain (reference replay.go:285
+        #    region) — a fresh chain, or an app that lost every commit it
+        #    ever made (its writes are unsynced: store/db.py) under a node
+        #    whose stores did not; only a state still at genesis takes the
+        #    response's updates
+        if app_height == 0:
+            genesis_state = (
+                state if state_height == 0 else state_from_genesis(self.genesis_doc)
+            )
             # carry genesis proofs of possession into the InitChain
             # updates: an app that echoes the request's validator set
             # back must round-trip the PoPs, or the bls12381 rogue-key
@@ -107,36 +114,39 @@ class Handshaker:
                     v.voting_power,
                     pops.get(v.pub_key.bytes(), b""),
                 )
-                for v in state.validators.validators
+                for v in genesis_state.validators.validators
             ]
             res = await app_conns.consensus.init_chain(
                 abci.RequestInitChain(
                     time_ns=self.genesis_doc.genesis_time_ns,
                     chain_id=self.genesis_doc.chain_id,
-                    consensus_params=state.consensus_params,
+                    consensus_params=genesis_state.consensus_params,
                     validators=tuple(validators),
                     app_state_bytes=self.genesis_doc.app_state,
                     initial_height=self.genesis_doc.initial_height,
                 )
             )
-            updates = {}
-            if res.app_hash:
-                updates["app_hash"] = res.app_hash
-            if res.consensus_params is not None:
-                updates["consensus_params"] = res.consensus_params
-            if res.validators:
-                vals = ValidatorSet(
-                    validator_updates_to_validators(
-                        res.validators,
-                        updates.get("consensus_params", state.consensus_params),
+            if state_height == 0:
+                updates = {}
+                if res.app_hash:
+                    updates["app_hash"] = res.app_hash
+                if res.consensus_params is not None:
+                    updates["consensus_params"] = res.consensus_params
+                if res.validators:
+                    vals = ValidatorSet(
+                        validator_updates_to_validators(
+                            res.validators,
+                            updates.get("consensus_params", state.consensus_params),
+                        )
                     )
-                )
-                updates["validators"] = vals
-                updates["next_validators"] = vals.copy_increment_proposer_priority(1)
-            if updates:
-                state = state.copy(**updates)
-            self.state_store.save(state)
-            app_hash = state.app_hash
+                    updates["validators"] = vals
+                    updates["next_validators"] = vals.copy_increment_proposer_priority(1)
+                if updates:
+                    state = state.copy(**updates)
+                self.state_store.save(state)
+                app_hash = state.app_hash
+            else:
+                app_hash = res.app_hash or genesis_state.app_hash
 
         if store_height == 0:
             self._assert_app_hash(state, app_hash)

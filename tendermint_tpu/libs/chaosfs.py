@@ -31,7 +31,15 @@ any post-crash file back to a replayable state.
 
 `ChaosDB` applies the ENOSPC/bit-rot classes to any `store.db.DB`
 (SQLite batches are atomic, so torn DB writes cannot happen by
-construction — the WAL is where torn writes live).
+construction — the WAL is where torn writes live), and the DB side of
+the crash: `ChaosDB.simulate_crash()` takes back every write made since
+the DB's last SYNCED one (`set(..., sync=True)` / `write_batch(...,
+sync=True)`, `store/db.py`) — whole writes, newest first, so the DB reads
+as it did right after that synced write. A synced write, and everything
+written before it, always survives. That is the most a real store may
+lose (SQLite in WAL mode keeps an unsynced commit only until the next
+fsync of its WAL; goleveldb's `Write` without `Sync` likewise), and what
+`consensus/replay.Handshaker` must be able to recover from.
 
 Env mirror (`config.ChaosFSConfig`): TMTPU_CHAOS_FS_SEED, _TORN,
 _TORN_OFFSET, _LOST_FSYNC, _ENOSPC, _ENOSPC_AT, _BITROT.
@@ -193,6 +201,7 @@ class ChaosFS(FS):
         self.faults: dict[str, int] = {
             "torn_write": 0, "lost_fsync": 0, "enospc": 0, "bitrot": 0,
             "crash_lost_bytes": 0, "db_enospc": 0, "db_bitrot": 0,
+            "db_crash_lost_writes": 0,
         }
 
     # -- FS interface ----------------------------------------------------
@@ -311,12 +320,41 @@ class ChaosFS(FS):
 
 
 class ChaosDB(DB):
-    """ENOSPC + bit-rot injection over any DB. Batches stay atomic (the
-    real engines guarantee that); a failed batch applies nothing."""
+    """ENOSPC + bit-rot injection over any DB, and the crash that drops
+    its unsynced tail (`simulate_crash`). Batches stay atomic (the real
+    engines guarantee that); a failed batch applies nothing."""
 
     def __init__(self, fs: ChaosFS, inner: DB):
         self.fs = fs
         self.inner = inner
+        # one entry per write since the last synced one: what each key it
+        # touched held before ([(key, old value | None)])
+        self._unsynced: list[list[tuple[bytes, bytes | None]]] = []
+
+    def _note(self, keys, sync: bool) -> None:
+        """Called BEFORE a write lands: a synced write makes everything
+        before it durable too; any other is remembered by what it
+        overwrites."""
+        if sync:
+            self._unsynced.clear()
+        else:
+            self._unsynced.append([(k, self.inner.get(k)) for k in keys])
+
+    def simulate_crash(self) -> int:
+        """Drop every write since the last synced one (module docstring);
+        returns how many writes were taken back. The DB stays open: the
+        restarted node reads what a real one would find on disk."""
+        lost = len(self._unsynced)
+        while self._unsynced:
+            sets, deletes = [], []
+            for k, old in self._unsynced.pop():
+                if old is None:
+                    deletes.append(k)
+                else:
+                    sets.append((k, old))
+            self.inner.write_batch(sets, deletes)
+        self.fs.faults["db_crash_lost_writes"] += lost
+        return lost
 
     def _roll_enospc(self) -> None:
         cfg = self.fs.config
@@ -338,11 +376,13 @@ class ChaosDB(DB):
     def get(self, key: bytes) -> bytes | None:
         return self._rot(self.inner.get(key))
 
-    def set(self, key: bytes, value: bytes) -> None:
+    def set(self, key: bytes, value: bytes, sync: bool = False) -> None:
         self._roll_enospc()
-        self.inner.set(key, value)
+        self._note([key], sync)
+        self.inner.set(key, value, sync)
 
     def delete(self, key: bytes) -> None:
+        self._note([key], False)
         self.inner.delete(key)
 
     def iterate(
@@ -351,9 +391,11 @@ class ChaosDB(DB):
         for k, v in self.inner.iterate(start, end, reverse):
             yield k, self._rot(v)
 
-    def write_batch(self, sets, deletes=()):
+    def write_batch(self, sets, deletes=(), sync: bool = False):
         self._roll_enospc()
-        self.inner.write_batch(sets, deletes)
+        sets = list(sets)
+        self._note([k for k, _v in sets] + list(deletes), sync)
+        self.inner.write_batch(sets, deletes, sync)
 
     def close(self) -> None:
         self.inner.close()

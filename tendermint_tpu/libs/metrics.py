@@ -453,6 +453,18 @@ class NodeMetrics:
         self.wal_truncated_bytes = r.counter(
             "wal", "truncated_bytes", "damaged WAL bytes rotated aside by repair"
         )
+        # on-disk stores (store/db.py SQLiteDB, by the name a DB was opened
+        # under: block/state/app; folded from store.db.COUNTERS at render)
+        self.db_sync_commits = r.counter(
+            "db", "sync_commits_total",
+            "commits made durable with an fsync (set/write_batch sync=True)",
+        )
+        self.db_bytes_written = r.counter(
+            "db", "bytes_written_total", "key and value bytes of the rows written"
+        )
+        self.db_gets = r.counter(
+            "db", "gets_total", "point reads and range scans of the DB"
+        )
         # verify hub (crypto/verify_hub.py — process-wide scheduler,
         # folded in at render time like the resilience events)
         self.verifyhub_dispatches = r.counter(
@@ -723,6 +735,15 @@ class NodeMetrics:
             "abci", "connection_latency_seconds", "app call latency"
         )
 
+    def _fold_db(self) -> None:
+        from ..store.db import COUNTERS
+
+        for name, c in COUNTERS.items():
+            label = (("db", name),)
+            self.db_sync_commits._values[label] = c["sync_commits"]
+            self.db_bytes_written._values[label] = c["bytes_written"]
+            self.db_gets._values[label] = c["gets"]
+
     def _fold_verify_hub(self) -> None:
         from ..crypto.verify_hub import running_hub
 
@@ -978,6 +999,7 @@ class NodeMetrics:
         self.wal_corrupt_records._values[()] = STORAGE["wal_corrupt_records"]
         self.wal_repairs._values[()] = STORAGE["wal_repairs"]
         self.wal_truncated_bytes._values[()] = STORAGE["wal_truncated_bytes"]
+        self._fold_db()
         self._fold_verify_hub()
         self._fold_verifyd()
         self._fold_ingest()

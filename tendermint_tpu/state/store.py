@@ -134,7 +134,9 @@ class StateStore:
     def save(self, state: State) -> None:
         """Persist state; indexes the *next* validators at the height they
         become active (reference store.go save: nextValidators at
-        lastBlockHeight+2, genesis seeds heights initial and initial+1)."""
+        lastBlockHeight+2, genesis seeds heights initial and initial+1).
+        One synced batch, as the reference's `batch.WriteSync()`: a saved
+        state survives a crash."""
         sets: list[tuple[bytes, bytes]] = [(_STATE_KEY, state.encode())]
         next_height = state.last_block_height + 1
         if state.last_block_height == 0:  # genesis bootstrap
@@ -155,7 +157,7 @@ class StateStore:
                 (_hkey(_VALS, next_height + 1), state.next_validators.encode())
             )
             sets.append((_hkey(_PARAMS, next_height), state.consensus_params.encode()))
-        self.db.write_batch(sets)
+        self.db.write_batch(sets, sync=True)
 
     def bootstrap(self, state: State) -> None:
         """Seed the store from an out-of-band state (statesync restore)."""
@@ -189,7 +191,9 @@ class StateStore:
         return None
 
     def save_abci_responses(self, height: int, responses: ABCIResponses) -> None:
-        self.db.set(_hkey(_ABCI, height), responses.encode())
+        """Synced (the reference's SaveABCIResponses is a `SetSync`): a
+        height's responses are on disk before its app Commit begins."""
+        self.db.set(_hkey(_ABCI, height), responses.encode(), sync=True)
 
     def load_abci_responses(self, height: int) -> ABCIResponses | None:
         raw = self.db.get(_hkey(_ABCI, height))

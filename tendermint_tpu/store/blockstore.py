@@ -97,7 +97,9 @@ class BlockStore:
 
     def save_block(self, block: Block, part_set: PartSet, seen_commit: Commit) -> None:
         """Persist a block as its parts, its meta, its seen commit and the
-        canonical commit of the height before. The block's size in the
+        canonical commit of the height before, in ONE synced batch (the
+        reference's SaveBlock ends in `batch.WriteSync()`): when this
+        returns the block survives a crash. The block's size in the
         meta is the length of `block.encode()`, which the block keeps: the
         bytes `part_set` was cut from, not a second serialisation."""
         height = block.header.height
@@ -123,7 +125,7 @@ class BlockStore:
             if self._base == 0:
                 self._base = height
             self._save_state(sets)
-            self.db.write_batch(sets)
+            self.db.write_batch(sets, sync=True)
 
     def save_seen_commit(self, height: int, commit: Commit) -> None:
         self.db.set(_hkey(_SEEN, height), commit.encode())
