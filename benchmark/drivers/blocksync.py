@@ -270,10 +270,10 @@ def _bad_wire(chain: fixtures.KVChain, height: int, sig_index: int) -> dict:
 
 
 def _warm_shapes(fx: Fixture) -> list[str]:
-    """Every (bucket, gb127) pair a hub dispatch can take: a range goes
-    out as <=512-row dispatches, and the first and the last of a range
-    are whatever the 2 ms window and the remainder leave — any size, so
-    every bucket from the measured cut-off up to 512."""
+    """Every (bucket, gb127) pair a SHORT range can take: since PR 39 a whole
+    range is ONE hub group and one dispatch at the start-up's 8192/gb255; only
+    one of <= `max_batch` rows (<= 5 commits: the re-fetch after a refusal, the
+    chain's tail) takes its own rung, from the measured cut-off up to 512."""
     from tendermint_tpu.crypto import batch as cb
 
     items = []

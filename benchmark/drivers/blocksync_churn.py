@@ -178,9 +178,9 @@ def _bad_wire(fx: Fixture, kind: str) -> dict:
 
 
 def warmup(fx: Fixture, cfg: dict, cell: dict, spans: harness.Spans) -> list[str]:
-    """Acquire the hub and warm every dispatch shape the cut-off lets a plan
-    reach (`blocksync`'s: 512, 256, 128, 64 at gb127 — a plan of 15-17
-    commits goes out as 512-row dispatches and a tail of 80-491 rows), then
+    """Acquire the hub and warm every dispatch shape the cut-off lets a SHORT
+    plan reach (`blocksync`'s 512, 256, 128, 64 at gb127; since PR 39 a plan of
+    15-17 commits is ONE hub group and one 8192/gb255 dispatch), then
     drive the warm-up chain (other chain ID and keys, the same schedule)
     through the reactor twice, one stand-in serving one corrupted commit each
     time (a sync of its own each: a refusal drops the blocks its two

@@ -1,7 +1,8 @@
 """hub_submit_ms_per_ksig.blocksync
 
-`hub.submit` (VerifyHub.verify_many's submit_nowait loop and flush) over
-thousands of signatures submitted.
+`hub.submit` (since PR 39 `VerifyHub.verify_many`'s `_submit_group`: a SHA-256
+a row for the cache keys, then one pass over the group under one acquisition
+of the hub's lock) over thousands of signatures submitted.
 """
 
 from benchmark import program_spans

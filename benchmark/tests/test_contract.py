@@ -100,3 +100,17 @@ def test_files_under_paths_are_named_lexically():
             for f in files:
                 rel = os.path.relpath(os.path.join(d, f), ROOT)
                 assert ok.match(rel) and len(rel) <= 200, rel
+
+
+@pytest.mark.parametrize("cell", [w["name"] for w in BENCH["workloads"]
+                                  if w["traffic"] == "blocksync"])
+def test_a_block_sync_chain_outlasts_its_window_up_to_160_blocks_a_second(cell):
+    """A traced run whose chain ends raises `blocksync.ChainEnded` and gives no
+    result: a chain too short for a gain a later PR brings is a refused PR.
+    The window and the traced stretch that follows it use the chain up only
+    above (blocks - window) / (run_seconds + trace_seconds) blocks/s; the
+    fastest block-sync cell reads about half of 160 (PERF.md §5)."""
+    p = harness.load_json(
+        os.path.join(ROOT, BENCH["paths"][0], "workloads", f"{cell}.json"))["traffic"]
+    ceiling = (p["blocks"] - p["window"]) / (BENCH["run_seconds"] + p["trace_seconds"])
+    assert ceiling >= 160, f"{cell}: the chain ends at {ceiling:.1f} blocks/s"
